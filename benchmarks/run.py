@@ -1011,14 +1011,15 @@ def bench_serve_sharded_throughput():
       overhead (shard_map dispatch, spec normalization, donation).
       main() exits nonzero if the median paired ratio drops below
       0.95x, or if the token streams differ at all.
-    - *2-way arm* (subprocess — XLA locks the host device count at
-      first jax init): a (1, 2) model-parallel mesh must reproduce the
-      1-device token streams exactly while each shard holds exactly
-      half the KV pool bytes at equal tokens-in-flight.
+    - *2-way arm* (in-process, on a (1, 2) mesh of this process's own
+      devices — a child process could not reach a chip this process
+      already holds): the mesh must reproduce the 1-device token streams
+      exactly while each shard holds exactly half the KV pool bytes at
+      equal tokens-in-flight.  With one visible device the arm is
+      skipped and said so (on CPU:
+      ``XLA_FLAGS=--xla_force_host_platform_device_count=2``).
     """
     import dataclasses
-    import subprocess
-    import sys
     import threading
     from repro import configs
     from repro.configs.base import smoke_variant
@@ -1045,80 +1046,40 @@ def bench_serve_sharded_throughput():
         prod.start()
         bat.run(len(reqs))
         prod.join()
-        return [drain(r) for r in reqs], time.perf_counter() - t0
+        return [drain(r) for r in reqs], time.perf_counter() - t0, bat
 
     arm(pcfg)                        # compile both programs untimed
     arm(mcfg)
     ratios, best = [], float("inf")
     for _ in range(trials):
-        out_u, dt_u = arm(pcfg)
-        out_s, dt_s = arm(mcfg)
+        out_u, dt_u, _ = arm(pcfg)
+        out_s, dt_s, _ = arm(mcfg)
         assert out_s == out_u, "(1, 1) mesh diverged from unsharded"
         ratios.append(dt_u / dt_s)
         best = min(best, dt_s)
     mesh_ratio = float(np.median(ratios))
 
-    code = (
-        "import dataclasses, time, threading\n"
-        "import numpy as np\n"
-        "from repro import configs\n"
-        "from repro.configs.base import smoke_variant\n"
-        "from repro.models import registry\n"
-        "from repro.serve.batching import ContinuousBatcher, Request, "
-        "drain\n"
-        "cfg = dataclasses.replace(smoke_variant("
-        "configs.get('minitron-4b')), kv_page_size=8, prefill_chunk=8)\n"
-        "params = registry.init(cfg, 0)\n"
-        "rng = np.random.default_rng(11)\n"
-        "prompts = [rng.integers(0, cfg.vocab_size, "
-        "int(rng.integers(8, 15))).astype(np.int32) for _ in range(4)]\n"
-        f"MN = {max_new}\n"
-        "def arm(acfg):\n"
-        "    bat = ContinuousBatcher(acfg, params, n_slots=4, "
-        "max_seq=128)\n"
-        "    reqs = [Request(rid=i, prompt=p.copy(), max_new=MN) "
-        "for i, p in enumerate(prompts)]\n"
-        "    prod = threading.Thread("
-        "target=lambda: [bat.submit(r) for r in reqs])\n"
-        "    t0 = time.perf_counter()\n"
-        "    prod.start()\n"
-        "    bat.run(len(reqs))\n"
-        "    prod.join()\n"
-        "    return [drain(r) for r in reqs], "
-        "time.perf_counter() - t0, bat\n"
-        "u, _, _ = arm(cfg)\n"
-        "s, dt, bat = arm(dataclasses.replace(cfg, mesh_shape=(1, 2)))\n"
-        "assert s == u, '2-way token streams diverged from 1-device'\n"
-        "m = bat.stats()['mesh']\n"
-        "assert 2 * m['pool_bytes_per_shard'] == m['pool_bytes_total']"
-        ", m\n"
-        "print('TP2', 4 * MN / dt, m['pool_bytes_per_shard'], "
-        "m['pool_bytes_total'])\n")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    env["PYTHONPATH"] = os.path.abspath(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "..",
-        "src")) + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=900)
-    assert r.returncode == 0, \
-        f"2-way mesh subprocess failed:\n{r.stdout}\n{r.stderr}"
-    tp2 = [ln for ln in r.stdout.splitlines()
-           if ln.startswith("TP2")][0].split()
-    tp2_tok_s, shard_b, total_b = (float(tp2[1]), int(tp2[2]),
-                                   int(tp2[3]))
-
     tok_s = 4 * max_new / best
+    derived = (f"tok_per_s_1dev_mesh={tok_s:.0f};"
+               f"mesh_ratio={mesh_ratio:.2f};")
+    tp2 = {}
+    if len(jax.devices()) >= 2:
+        out_2, dt_2, bat2 = arm(dataclasses.replace(pcfg, mesh_shape=(1, 2)))
+        assert out_2 == out_u, "2-way token streams diverged from 1-device"
+        m = bat2.stats()["mesh"]
+        tp2 = {"tp2_pool_bytes_per_shard": m["pool_bytes_per_shard"],
+               "tp2_pool_bytes_total": m["pool_bytes_total"]}
+        derived += f"tp2_tok_per_s={4 * max_new / dt_2:.0f};" + "".join(
+            f"{k}={v};" for k, v in tp2.items())
+    else:
+        print("# serve_sharded_throughput: 2-way arm skipped (1 device "
+              "visible)", flush=True)
     row("serve_sharded_throughput", best / (4 * max_new) * 1e6,
-        f"tok_per_s_1dev_mesh={tok_s:.0f};mesh_ratio={mesh_ratio:.2f};"
-        f"tp2_tok_per_s={tp2_tok_s:.0f};"
-        f"tp2_pool_bytes_per_shard={shard_b};"
-        f"tp2_pool_bytes_total={total_b};tokens_equal=1")
+        derived + "tokens_equal=1")
     res = RESULTS["serve_sharded_throughput"]
     res["mesh_ratio"] = round(mesh_ratio, 3)
     res["tokens_equal"] = 1
-    res["tp2_pool_bytes_per_shard"] = shard_b
-    res["tp2_pool_bytes_total"] = total_b
+    res.update(tp2)
 
 
 def bench_telemetry_overhead():
@@ -1212,6 +1173,8 @@ def main(argv=None) -> None:
                     help="serve-path benches only")
     args = ap.parse_args(argv)
     SMOKE = args.smoke
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     if not args.serve:
@@ -1374,9 +1337,9 @@ def main(argv=None) -> None:
     # 10. the shard_map serving wrapper must be free when it does
     #     nothing: a (1, 1) mesh runs the identical program through the
     #     sharded path on the same single device, so any median paired
-    #     ratio below 0.95x is pure wrapper overhead.  The 2-way arm
-    #     (asserted inside the bench) must halve per-shard KV pool
-    #     bytes at equal tokens-in-flight with identical token streams.
+    #     ratio below 0.95x is pure wrapper overhead.  The 2-way arm,
+    #     where it ran (token equality asserted inside the bench), must
+    #     halve per-shard KV pool bytes at equal tokens-in-flight.
     sh = RESULTS.get("serve_sharded_throughput", {})
     if sh:
         if sh.get("tokens_equal") != 1:
@@ -1388,8 +1351,9 @@ def main(argv=None) -> None:
                   f"{sh.get('mesh_ratio')}x < 0.95x of the unsharded "
                   f"batcher — the wrapper is not free", flush=True)
             raise SystemExit(1)
-        if (sh.get("tp2_pool_bytes_per_shard", 0) * 2
-                != sh.get("tp2_pool_bytes_total", -1)):
+        if ("tp2_pool_bytes_total" in sh
+                and sh["tp2_pool_bytes_per_shard"] * 2
+                != sh["tp2_pool_bytes_total"]):
             print(f"FATAL: 2-way mesh per-shard KV pool bytes "
                   f"({sh.get('tp2_pool_bytes_per_shard')}) are not half "
                   f"of the total ({sh.get('tp2_pool_bytes_total')}) — "

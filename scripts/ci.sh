@@ -6,10 +6,12 @@ cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-# The full suite runs clean on the pinned jax==0.4.37: repro.compat
-# installs the jax>=0.5 API shims (jax.sharding.AxisType / set_mesh,
-# jax.shard_map, lax.axis_size) the distributed/roofline tests target.
-python -m pytest -x -q
+# The suite runs on the CPU backend: Pallas kernels are interpreted
+# there (kernels/platform.py), multi-device tests simulate their meshes
+# in subprocesses, and tests/test_tpu_compile.py compiles the main-path
+# kernels for a described v5e chip.  On a TPU host the program is
+# checked by `python chip_smoke.py` (one chip; `--chips 4` for four).
+JAX_PLATFORMS=cpu python -m pytest -x -q
 
 # Serving fast-path benches (smoke): writes benchmarks/BENCH_serve_smoke.json
 # so every CI run leaves a machine-readable perf snapshot behind without
@@ -33,21 +35,22 @@ python -m pytest -x -q
 # or bit-identity of the speculative token streams vs plain decode —
 # or if mesh-sharded serving (serve_sharded_throughput) regresses: the
 # tp=1 shard_map wrapper must stay within 0.95x of the unsharded
-# batcher (paired-median ratio), and the 2-way mesh arm (subprocess
-# with 2 simulated host devices) must reproduce the 1-device token
+# batcher (paired-median ratio), and the 2-way mesh arm (in-process,
+# run when 2 devices are visible) must reproduce the 1-device token
 # streams exactly while halving per-shard KV pool bytes — or if
 # telemetry stops being near-free (telemetry_overhead): decode
 # throughput with lifecycle tracing + the metrics registry enabled must
 # stay >= 0.97x (0.85x in smoke) of the bare batcher on paired medians,
 # with the trace's token events matching the streamed tokens exactly.
-python -m benchmarks.run --smoke --serve
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \
+    python -m benchmarks.run --smoke --serve
 
 # Metrics-endpoint smoke (serve.telemetry): serve a couple of requests
 # through a fully instrumented paged batcher, scrape the live
 # /metrics HTTP endpoint the way Prometheus would, and validate the
 # exposition — TYPE lines before samples, cumulative histogram buckets,
 # +Inf bucket == _count — plus the presence of the core serving series.
-python - <<'PY'
+JAX_PLATFORMS=cpu python - <<'PY'
 import dataclasses, threading, urllib.request
 import numpy as np
 from repro import configs
@@ -101,4 +104,5 @@ PY
 # already ran this file once with the default seed; this stage pins the
 # seeded draws explicitly so the chaos matrix is reproducible even if
 # the default ever changes.)
-REPRO_FAULT_SEED=0 python -m pytest -x -q tests/test_resilience.py
+REPRO_FAULT_SEED=0 JAX_PLATFORMS=cpu python -m pytest -x -q \
+    tests/test_resilience.py

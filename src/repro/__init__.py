@@ -1,9 +1,1 @@
-"""repro — hlslib-style library abstractions on jax/Pallas.
-
-Importing any ``repro.*`` module installs the jax-0.4.x forward-compat
-shims (``repro.compat``): tests and library code target the jax >= 0.5
-API surface (``jax.sharding.AxisType`` / ``set_mesh``, top-level
-``jax.shard_map``) and the shims keep the pinned 0.4.37 runnable.
-"""
-
-from . import compat as _compat  # noqa: F401  (side effect: install())
+"""repro — hlslib-style library abstractions on jax/Pallas."""

@@ -10,8 +10,8 @@ touching model source — hlslib's portability story for distribution.
 Serving integration (see docs/serving.md "Mesh-sharded serving"): the
 paged decode/prefill/verify steps in ``serve.serve_loop`` run their
 bodies under ``jax.shard_map`` on the mesh named by
-``cfg.mesh_shape``/``cfg.tp_axis``.  Inside a shard_map body there is no
-global mesh context, so every ``constrain()`` in the model code is a
+``cfg.mesh_shape``/``cfg.tp_axis``.  Inside a shard_map body every mesh
+axis is ``Manual``, so every ``constrain()`` in the model code is a
 no-op there; instead the body enters ``manual_axis(cfg.tp_axis)`` and
 the model inserts explicit collectives through ``psum_parts`` /
 ``gather_parts`` at the attention / FF output projections (partial-sum
@@ -81,20 +81,8 @@ def use_rules(overrides: Optional[Rules] = None, **kw):
         _rules_var.reset(token)
 
 
-def _thread_local_mesh() -> Optional[Mesh]:
-    """Fallback for jax versions without ``jax.sharding.get_abstract_mesh``
-    (absent in 0.4.x): the ``with Mesh(...)`` context manager stores the
-    active mesh in jax's thread-local resource env."""
-    try:
-        from jax._src import mesh as _jmesh
-        return _jmesh.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-
-
 def current_mesh() -> Optional[Mesh]:
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    m = getter() if getter is not None else _thread_local_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or m.empty:
         return None
     return m
@@ -142,17 +130,19 @@ def spec_for(axes: Sequence[Optional[str]],
 
 
 def constrain(x, *axes: Optional[str]):
-    """with_sharding_constraint by logical axes; no-op without a mesh."""
+    """with_sharding_constraint by logical axes; no-op without a mesh and
+    inside a shard_map body (its mesh axes are ``Manual``, which a
+    sharding constraint may not name)."""
     mesh = current_mesh()
-    if mesh is None:
+    if mesh is None or mesh.manual_axes:
         return x
     return jax.lax.with_sharding_constraint(x, spec_for(axes, mesh))
 
 
 # -- manual (shard_map) collectives ------------------------------------------------
 #
-# shard_map bodies trace with per-shard shapes and NO global mesh
-# context (current_mesh() is None there), so `constrain` can't express
+# shard_map bodies trace with per-shard shapes on a mesh whose axes are
+# all Manual (`constrain` is a no-op there), so `constrain` can't express
 # the cross-shard reductions tensor parallelism needs.  The serving
 # steps instead enter `manual_axis(tp_axis)` around the model call and
 # the model inserts explicit collectives via the helpers below — all of

@@ -41,6 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import datapack
+from .platform import interpret_mode
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -115,7 +116,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """q: (b, hq, sq, d); k, v: (b, hkv, sk, d).  Returns (b, hq, sq, d).
 
     Decode-style calls (sq < sk) align queries to the end of the kv
@@ -169,7 +170,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q4, k4, v4)
 
     out = out.reshape(b, hq, sq_pad, d)
@@ -281,8 +282,6 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError("ring layout requires a window size")
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     block_k = min(block_k, S)
     S_pad = datapack.round_up(S, block_k)
@@ -323,7 +322,7 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, group, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(pos_arr, q3, k3, v3)
 
     return out.reshape(b, hq, d)[:, :, None, :]
@@ -467,8 +466,6 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         raise ValueError("k/v scale pages must be passed together")
     group = hq // hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     has_base = page_base is not None
     quantized = k_scale_pages is not None
 
@@ -524,7 +521,7 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(*prefetch, *inputs)
 
     return (out.reshape(b, hkv, sq, group, d).transpose(0, 1, 3, 2, 4)

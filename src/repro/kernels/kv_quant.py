@@ -22,12 +22,14 @@ block-table gather (see ``models/cache_layouts`` and
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import datapack
+from .platform import interpret_mode
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, eps: float):
@@ -45,7 +47,7 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
 
 
 def kv_quantize(x: jnp.ndarray, block_rows: int = 256, eps: float = 1e-6,
-                interpret: bool = False):
+                interpret: Optional[bool] = None):
     """x: (rows, d) -> (int8 (rows, d), bf16 scales (rows, 1))."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)
@@ -60,13 +62,13 @@ def kv_quantize(x: jnp.ndarray, block_rows: int = 256, eps: float = 1e-6,
                    pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rp, d), jnp.int8),
                    jax.ShapeDtypeStruct((rp, 1), jnp.bfloat16)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)
     return q[:rows], s[:rows]
 
 
 def kv_dequantize(q: jnp.ndarray, s: jnp.ndarray, dtype=jnp.bfloat16,
-                  block_rows: int = 256, interpret: bool = False):
+                  block_rows: int = 256, interpret: Optional[bool] = None):
     rows, d = q.shape
     block_rows = min(block_rows, rows)
     rp = datapack.round_up(rows, block_rows)
@@ -80,6 +82,6 @@ def kv_dequantize(q: jnp.ndarray, s: jnp.ndarray, dtype=jnp.bfloat16,
                   pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, d), dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, s)
     return out[:rows]

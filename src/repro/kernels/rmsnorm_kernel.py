@@ -10,12 +10,14 @@ the oracle — gemma-style zero-centered gains).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import datapack
+from .platform import interpret_mode
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -27,7 +29,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = False) -> jnp.ndarray:
+            block_rows: int = 256, interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (rows, d); w: (d,).  Returns normalized x in x.dtype."""
     rows, d = x.shape
     block_rows = min(block_rows, rows)
@@ -41,6 +43,6 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6,
                   pl.BlockSpec((d,), lambda i: (0,))],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, w)
     return out[:rows]

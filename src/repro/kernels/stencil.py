@@ -18,12 +18,14 @@ is the software-emulation twin used by the dataflow example.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import datapack
+from .platform import interpret_mode
 
 
 def _stencil_kernel(prev_ref, cur_ref, next_ref, o_ref, *, block_rows: int,
@@ -55,7 +57,7 @@ def _stencil_kernel(prev_ref, cur_ref, next_ref, o_ref, *, block_rows: int,
 
 
 def stencil2d(x: jnp.ndarray, block_rows: int = 128,
-              interpret: bool = False) -> jnp.ndarray:
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """One Jacobi sweep of the 4-point stencil; zero boundary."""
     H, W = x.shape
     block_rows = min(block_rows, H)
@@ -78,15 +80,17 @@ def stencil2d(x: jnp.ndarray, block_rows: int = 128,
         ],
         out_specs=pl.BlockSpec((block_rows, W), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Hp, W), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, x, x)
     return out[:H]
 
 
 def stencil2d_iterated(x: jnp.ndarray, iters: int, block_rows: int = 128,
-                       interpret: bool = False) -> jnp.ndarray:
+                       interpret: Optional[bool] = None) -> jnp.ndarray:
     """Iterated sweeps — the cyclic-dataflow workload of paper §II-C (same
     memory read and written every iteration)."""
+    interpret = interpret_mode(interpret)
+
     def body(_, x):
         return stencil2d(x, block_rows=block_rows, interpret=interpret)
     return jax.lax.fori_loop(0, iters, body, x) if not interpret else \

@@ -11,12 +11,14 @@ against both the oracle and ``repro.core.treereduce.tree_reduce``).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core import datapack
+from .platform import interpret_mode
 from ..core import treereduce as tr
 
 
@@ -37,7 +39,7 @@ def _tree_kernel(x_ref, o_ref, *, op: str, n_logical: int):
 
 
 def tree_row_reduce(x: jnp.ndarray, op: str = "add", block_rows: int = 256,
-                    interpret: bool = False) -> jnp.ndarray:
+                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Reduce the last axis of (rows, N) with a guaranteed balanced tree.
 
     Output is (rows,).  N is padded to a power of two with the operator
@@ -60,6 +62,6 @@ def tree_row_reduce(x: jnp.ndarray, op: str = "add", block_rows: int = 256,
         in_specs=[pl.BlockSpec((block_rows, p2), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, 1), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)
     return out[:rows, 0]

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU simulation: never the chip
 
 """Multi-pod dry-run (assignment contract).
 
@@ -8,7 +9,7 @@ production meshes — 16×16 single-pod and 2×16×16 multi-pod — using
 ShapeDtypeStruct inputs (no allocation), prints memory/cost analysis,
 and records roofline terms to JSON for EXPERIMENTS.md.
 
-The two lines above MUST precede any jax import (device count locks at
+The lines above MUST precede any jax import (device count locks at
 first init); this env var is deliberately NOT set anywhere global.
 
 Usage::
@@ -33,7 +34,7 @@ from ..models import params as PP
 from ..roofline import analysis as RA
 from ..train import train_loop as TL
 from ..serve import serve_loop as SL
-from .mesh import make_production_mesh
+from .mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 
 
 def cells(only_arch=None, only_shape=None):
@@ -86,7 +87,8 @@ def run_cell(cfg, shape, multi_pod: bool, extra_cfg=None, verbose=True):
     mesh_name = "2x16x16" if multi_pod else "16x16"
     compiled, dt = lower_cell(cfg, shape, mesh, extra_cfg)
     roof = RA.analyze(compiled, cfg, shape, mesh_name, n_chips,
-                      registry.num_active_params(cfg))
+                      registry.num_active_params(cfg),
+                      device_kind=PRODUCTION_DEVICE_KIND)
     rec = roof.to_dict(n_chips)
     rec["compile_seconds"] = dt
     if verbose:

@@ -11,6 +11,10 @@ from typing import Optional, Tuple
 import jax
 
 
+# the chip the production meshes are made of (roofline.hw.PEAKS key).
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")

@@ -12,10 +12,27 @@ import numpy as np
 from ..configs import get as get_arch
 from ..configs.base import smoke_variant
 from ..core.dataflow import DataflowContext
-from ..models import registry
 from ..serve.batching import ContinuousBatcher, Request, drain
 from ..serve.resilience import RequestFailed, ServeSupervisor
+from ..serve.serve_loop import serving_params
 from ..serve.telemetry import MetricsServer, ServeTelemetry
+from . import compile_cache
+
+
+def build_batcher(cfg, *, seed: int = 0, params=None, n_slots: int,
+                  max_seq: int, n_pages=None, schedule=None, overload=None,
+                  queue_depth=None, faults=None, telemetry=None
+                  ) -> ContinuousBatcher:
+    """The serving batcher as ``main`` builds it: random weights from
+    ``seed`` held in ``cfg.dtype`` on the devices that use them
+    (``serve_loop.serving_params``; pass ``params`` to reuse weights
+    already built), then a ``ContinuousBatcher`` over them."""
+    if params is None:
+        params = serving_params(cfg, seed)
+    return ContinuousBatcher(cfg, params, n_slots=n_slots, max_seq=max_seq,
+                             n_pages=n_pages, schedule=schedule,
+                             overload=overload, queue_depth=queue_depth,
+                             faults=faults, telemetry=telemetry)
 
 
 def main(argv=None):
@@ -155,7 +172,7 @@ def main(argv=None):
         except ValueError as e:
             ap.error(str(e))
         cfg = dataclasses.replace(cfg, mesh_shape=shape)
-    params = registry.init(cfg, args.seed)
+    compile_cache.enable()
     rng = np.random.default_rng(args.seed)
 
     telemetry = None
@@ -163,14 +180,14 @@ def main(argv=None):
     if args.metrics_port >= 0 or args.trace_out or args.profile:
         telemetry = ServeTelemetry(trace=bool(args.trace_out),
                                    profile=args.profile)
-    batcher = ContinuousBatcher(cfg, params, n_slots=args.slots,
-                                max_seq=args.max_seq,
-                                n_pages=args.pages or None,
-                                schedule=args.schedule,
-                                overload=args.overload,
-                                queue_depth=args.queue_depth or None,
-                                faults=args.faults or None,
-                                telemetry=telemetry)
+    batcher = build_batcher(cfg, seed=args.seed, n_slots=args.slots,
+                            max_seq=args.max_seq,
+                            n_pages=args.pages or None,
+                            schedule=args.schedule,
+                            overload=args.overload,
+                            queue_depth=args.queue_depth or None,
+                            faults=args.faults or None,
+                            telemetry=telemetry)
     if args.metrics_port >= 0:
         metrics_server = MetricsServer(telemetry,
                                        port=args.metrics_port).start()

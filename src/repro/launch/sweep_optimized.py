@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU simulation: never the chip
 
 """Optimized-configuration sweep: every (arch × shape) cell with the
 §Perf levers that transferred (EXPERIMENTS.md):

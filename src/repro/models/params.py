@@ -4,7 +4,8 @@ specs, and abstract (dry-run) shapes.
 Model code declares each tensor once as a ``Decl`` (shape + logical axes
 + init).  Three interpreters consume the same tree:
 
-* ``init_params``      -> concrete fp32 arrays (deterministic per path)
+* ``init_params``      -> concrete arrays (deterministic per path), built
+                          on the devices that hold them
 * ``abstract_params``  -> ShapeDtypeStructs (the dry-run's no-allocation path)
 * ``param_specs``      -> PartitionSpecs via the logical-axis rules
 
@@ -15,6 +16,7 @@ lives in the rule table, not the model definition.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 from typing import Any, Optional, Tuple
 
@@ -63,25 +65,56 @@ def _fan_in(shape: Tuple[int, ...]) -> int:
     return shape[-1]
 
 
-def init_params(decls, seed: int = 0):
+@functools.partial(jax.jit,
+                   static_argnames=("shape", "dtype", "init", "sharding"))
+def _init_leaf(key, std, *, shape, dtype, init, sharding):
+    if init == "zeros":
+        x = jnp.zeros(shape, dtype)
+    elif init == "ones":
+        x = jnp.ones(shape, dtype)
+    else:
+        x = jax.random.normal(key, shape, jnp.float32)
+        if dtype == jnp.float32:
+            # XLA would fold ``std`` into the draw's own constant multiply
+            # and move the last bit; the barrier keeps float32 leaves equal
+            # to the plain eager draw.  Narrower dtypes fuse the draw into
+            # the cast, so no float32 temporary of the leaf is ever held.
+            x = jax.lax.optimization_barrier(x)
+        x = (x * std).astype(dtype)
+    if sharding is not None:
+        # partitions the generation too: each device draws only its shard.
+        x = jax.lax.with_sharding_constraint(x, sharding)
+    return x
+
+
+def init_params(decls, seed: int = 0, *, dtype=None, shardings=None):
     """Deterministic init: every leaf's key derives from its tree path, so
     adding/removing parameters never silently reshuffles others (a
-    checkpoint-compat property the fault-tolerance layer relies on)."""
+    checkpoint-compat property the fault-tolerance layer relies on).
 
-    def leaf(path, d: Decl):
+    Each leaf is built by one jitted initializer on the devices that hold
+    it.  ``shardings`` (a tree matching ``decls`` of ``Sharding``s, or
+    None for the default device) gives each device only its shard, so a
+    sharded model never exists whole on one device.  ``dtype`` is the
+    stored dtype of floating leaves (None keeps the declared one):
+    serving passes the compute dtype, training keeps float32 masters.
+    The values drawn do not depend on ``shardings``."""
+
+    def leaf(path, d: Decl, sharding=None):
         h = int.from_bytes(
             hashlib.sha256(f"{seed}:{_path_str(path)}".encode()).digest()[:8],
             "little")
-        key = jax.random.key(h % (2 ** 63))
-        if d.init == "zeros":
-            return jnp.zeros(d.shape, d.dtype)
-        if d.init == "ones":
-            return jnp.ones(d.shape, d.dtype)
+        floating = jnp.issubdtype(d.dtype, jnp.floating)
+        out = jnp.dtype(dtype if dtype is not None and floating else d.dtype)
         std = d.std if d.std is not None else 1.0 / np.sqrt(_fan_in(d.shape))
-        return (jax.random.normal(key, d.shape, jnp.float32) * std
-                ).astype(d.dtype)
+        return _init_leaf(jax.random.key(h % (2 ** 63)), np.float32(std),
+                          shape=tuple(d.shape), dtype=out, init=d.init,
+                          sharding=sharding)
 
-    return jax.tree_util.tree_map_with_path(leaf, decls,
+    if shardings is None:
+        return jax.tree_util.tree_map_with_path(leaf, decls,
+                                                is_leaf=_is_decl)
+    return jax.tree_util.tree_map_with_path(leaf, decls, shardings,
                                             is_leaf=_is_decl)
 
 

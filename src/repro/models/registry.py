@@ -24,8 +24,10 @@ def decls(cfg: ModelConfig):
     return T.model_decls(cfg)
 
 
-def init(cfg: ModelConfig, seed: int = 0):
-    return P.init_params(decls(cfg), seed)
+def init(cfg: ModelConfig, seed: int = 0, *, dtype=None, shardings=None):
+    """Parameters from ``seed``: float32 masters by default; see
+    ``params.init_params`` for ``dtype`` and ``shardings``."""
+    return P.init_params(decls(cfg), seed, dtype=dtype, shardings=shardings)
 
 
 def abstract(cfg: ModelConfig):

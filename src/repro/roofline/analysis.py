@@ -4,6 +4,8 @@
     memory    = HLO_bytes(per device) / HBM_bw
     collective = collective_bytes(per device) / link_bw
 
+The peaks are those of the target chip (``hw.peaks(device_kind)``).
+
 FLOPs/bytes come from ``compiled.cost_analysis()`` (the post-SPMD
 per-device module, so no ×chips needed).  Collective bytes are parsed
 from the optimized HLO text: the summed result-shape bytes of every
@@ -86,22 +88,27 @@ class Roofline:
     collectives: Dict[str, int]
     model_flops_global: float
     n_active_params: int
+    device_kind: str
     peak_memory_per_device: Optional[float] = None
     scopes_flops: Dict[str, float] = dataclasses.field(default_factory=dict)
     scopes_bytes: Dict[str, float] = dataclasses.field(default_factory=dict)
     scopes_coll: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
+    def peaks(self) -> hw.ChipPeaks:
+        return hw.peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops_per_device / hw.PEAK_BF16_FLOPS
+        return self.flops_per_device / self.peaks.bf16_flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / hw.HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / hw.ICI_BW
+        return self.collective_bytes_per_device / self.peaks.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -120,12 +127,13 @@ class Roofline:
     def mfu_bound(self, n_chips: int) -> float:
         """Model-FLOPs utilization if the dominant term were the wall
         clock: MODEL_FLOPS / (t_bound · chips · peak)."""
-        denom = self.t_bound * n_chips * hw.PEAK_BF16_FLOPS
+        denom = self.t_bound * n_chips * self.peaks.bf16_flops
         return self.model_flops_global / denom if denom else 0.0
 
     def to_dict(self, n_chips: int) -> Dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
+            "device_kind": self.device_kind,
             "flops_per_device": self.flops_per_device,
             "bytes_per_device": self.bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,
@@ -158,9 +166,11 @@ def model_flops(cfg, shape, n_active: int) -> float:
 
 
 def analyze(compiled, cfg, shape, mesh_name: str, n_chips: int,
-            n_active: int) -> Roofline:
+            n_active: int, *, device_kind: str) -> Roofline:
     """Roofline terms via the trip-count-aware HLO walker (see
-    ``hlo_walk``; raw ``cost_analysis`` counts while bodies once)."""
+    ``hlo_walk``; raw ``cost_analysis`` counts while bodies once), over
+    the peaks of ``device_kind`` (the chip the program targets)."""
+    hw.peaks(device_kind)
     from . import hlo_walk
     try:
         hlo = compiled.as_text()
@@ -189,7 +199,7 @@ def analyze(compiled, cfg, shape, mesh_name: str, n_chips: int,
         collective_bytes_per_device=float(coll["total"]),
         collectives={k: int(v) for k, v in coll.items()},
         model_flops_global=model_flops(cfg, shape, n_active),
-        n_active_params=n_active,
+        n_active_params=n_active, device_kind=device_kind,
         peak_memory_per_device=peak_mem)
     roof.scopes_flops = dict(hlo_walk.top_scopes(costs.by_scope_flops))
     roof.scopes_bytes = dict(hlo_walk.top_scopes(costs.by_scope_bytes))

@@ -167,8 +167,8 @@ from .resilience import (BatcherFault, FaultPlan, InjectedFault, StallFault,
                          TerminalEvent, class_rank)
 from .telemetry import ServeTelemetry, _NULLCTX
 from .serve_loop import (make_chunk_prefill_step, make_paged_decode_step,
-                         make_spec_verify_step, paged_sharding_specs,
-                         serving_mesh_for)
+                         make_spec_verify_step, named_shardings,
+                         paged_sharding_specs, serving_mesh_for)
 
 _MIN_BUCKET = 8            # smallest prefill bucket (pad-to-power-of-two)
 _MIN_CHUNK = 16            # smallest auto-selected prefill chunk
@@ -557,9 +557,6 @@ class ContinuousBatcher:
             # decode steps left to replay with output pushes suppressed
             # (recompute-mode resume re-emits already-delivered tokens).
             self._replay_skip = [0] * n_slots
-            self.pools = PP.init_params(
-                registry.paged_cache_decls(cfg, self.n_pages,
-                                           self.page_size))
             # invalid page id == n_pages[group]: reads clamp (and are
             # masked), writes scatter-drop.
             self.block_tab = {
@@ -570,16 +567,12 @@ class ContinuousBatcher:
             if self.mesh is not None:
                 p_specs, pool_specs = paged_sharding_specs(
                     cfg, self.page_size, self.mesh)
-                self._pool_ns = jax.tree.map(
-                    lambda s: NamedSharding(self.mesh, s), pool_specs,
-                    is_leaf=lambda x: isinstance(x, P_spec))
+                self._pool_ns = named_shardings(self.mesh, pool_specs)
                 self._rep_ns = NamedSharding(self.mesh, P_spec())
+                # a no-op for weights already built on the mesh
+                # (serve_loop.serving_params).
                 self.params = jax.device_put(
-                    self.params,
-                    jax.tree.map(lambda s: NamedSharding(self.mesh, s),
-                                 p_specs,
-                                 is_leaf=lambda x: isinstance(x, P_spec)))
-                self.pools = jax.device_put(self.pools, self._pool_ns)
+                    self.params, named_shardings(self.mesh, p_specs))
                 self.block_tab = jax.device_put(self.block_tab,
                                                 self._rep_ns)
                 self.last_tok = jax.device_put(self.last_tok, self._rep_ns)
@@ -587,6 +580,11 @@ class ContinuousBatcher:
                 self.remaining = jax.device_put(self.remaining,
                                                 self._rep_ns)
                 self.active = jax.device_put(self.active, self._rep_ns)
+            # each pool shard is built on the device that holds it.
+            self.pools = PP.init_params(
+                registry.paged_cache_decls(cfg, self.n_pages,
+                                           self.page_size),
+                shardings=self._pool_ns)
             # host mirrors of per-slot decode state (drive lazy growth
             # and preemption without device readbacks).
             self._host_pos = [0] * n_slots
@@ -1652,9 +1650,10 @@ class ContinuousBatcher:
             self._prefix = PrefixIndex(
                 [g.name for g in self.layout.groups],
                 self.page_size, self.prefix_block)
-        self.pools = self._pinned(PP.init_params(
+        self.pools = PP.init_params(
             registry.paged_cache_decls(self.cfg, self.n_pages,
-                                       self.page_size)))
+                                       self.page_size),
+            shardings=self._pool_ns)
         self.block_tab = {
             name: jnp.full((n_slots, self.n_blocks[name]),
                            self.n_pages[name], i32)

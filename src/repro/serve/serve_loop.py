@@ -122,6 +122,26 @@ def paged_sharding_specs(cfg: ModelConfig, page_size: int, mesh):
     return p_specs, pool_specs
 
 
+def named_shardings(mesh, specs):
+    """A PartitionSpec tree as NamedShardings on ``mesh``."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def serving_params(cfg: ModelConfig, seed: int = 0):
+    """Random weights from ``seed`` for serving, stored in ``cfg.dtype``
+    (the dtype the forward computes in, so no step re-casts them) and
+    built where they live: each shard on its own device of the serving
+    mesh when ``cfg.mesh_shape`` names one, else on the default device.
+    Training keeps ``registry.init``'s float32 master weights."""
+    mesh, _ = serving_mesh_for(cfg)
+    shardings = None
+    if mesh is not None:
+        p_specs, _ = paged_sharding_specs(cfg, cfg.kv_page_size, mesh)
+        shardings = named_shardings(mesh, p_specs)
+    return registry.init(cfg, seed, dtype=cfg.dtype, shardings=shardings)
+
+
 def make_serve_steps(cfg: ModelConfig, batch: int, max_seq: int,
                      mesh: Optional[Mesh] = None):
     decls = registry.decls(cfg)
@@ -145,8 +165,7 @@ def make_serve_steps(cfg: ModelConfig, batch: int, max_seq: int,
         return (jax.jit(prefill), jax.jit(decode, donate_argnums=(1,)),
                 ab_cache, None)
 
-    ns = lambda tree: jax.tree.map(lambda s: NamedSharding(mesh, s), tree,
-                                   is_leaf=lambda x: isinstance(x, P))
+    ns = functools.partial(named_shardings, mesh)
     batch_axes = []
     prod = 1
     for a in ("pod", "data"):
@@ -239,9 +258,10 @@ def _shard_wrap(cfg: ModelConfig, page_size: int, fn, n_extra_in: int,
     every other input/output (block tables, slot vectors, token
     payloads) is replicated (``P()`` works as a pytree prefix over the
     per-group dicts).  ``check_vma=False``: the body's outputs are made
-    replicated by explicit psum/gather collectives, which 0.4.x's
-    replication checker cannot see through.  Donation carries over
-    unchanged — donated leaves are resharded in place."""
+    replicated by explicit psum/gather collectives inside helpers that
+    degrade to identity off the mesh, so the body carries no
+    varying-axis annotations for the checker to follow.  Donation
+    carries over unchanged — donated leaves are resharded in place."""
     mesh, axis = serving_mesh_for(cfg)
     if mesh is None:
         return jax.jit(fn, donate_argnums=donate)
@@ -259,15 +279,15 @@ def _shard_wrap(cfg: ModelConfig, page_size: int, fn, n_extra_in: int,
     return jax.jit(sm, donate_argnums=donate)
 
 
-@functools.lru_cache(maxsize=32)
-def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
-    """Jitted batched decode over paged KV: advances all slots at once."""
+def _paged_decode_forward(cfg: ModelConfig, page_size: int):
+    """The decode-step forward shared by the decode and logits steps:
+    inactive slots' table rows are masked to the invalid page id, then
+    one batched forward returns (logits (n_slots, 1, Vp), new pools)."""
     from ..models.cache_layouts import get_layout
     layout = get_layout(cfg, page_size)
     fcfg = shard_local_cfg(cfg)
-    i32 = jnp.int32
 
-    def step_fn(params, pools, block_tab, last_tok, pos, remaining, active):
+    def forward(params, pools, block_tab, last_tok, pos, active):
         bt = {}
         for g in layout.groups:
             n_pages = jax.tree.leaves(pools[g.name])[0].shape[
@@ -275,9 +295,22 @@ def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
             bt[g.name] = jnp.where(active[:, None], block_tab[g.name],
                                    n_pages)
         cache = {"pages": pools, "block_tab": bt}
-        logits, new_pools = registry.forward(
+        return registry.forward(
             fcfg, params, {"tokens": last_tok[:, None]}, mode="decode",
             cache=cache, pos=pos)
+
+    return forward
+
+
+@functools.lru_cache(maxsize=32)
+def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
+    """Jitted batched decode over paged KV: advances all slots at once."""
+    forward = _paged_decode_forward(cfg, page_size)
+    i32 = jnp.int32
+
+    def step_fn(params, pools, block_tab, last_tok, pos, remaining, active):
+        logits, new_pools = forward(params, pools, block_tab, last_tok, pos,
+                                    active)
         nxt = jnp.argmax(logits[:, -1], -1).astype(i32)
         nxt = jnp.where(active, nxt, last_tok)
         pos = jnp.where(active, pos + 1, pos)
@@ -289,6 +322,24 @@ def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
 
     return _shard_wrap(cfg, page_size, step_fn, n_extra_in=5,
                        n_extra_out=5, donate=(1, 3, 4, 5, 6))
+
+
+@functools.lru_cache(maxsize=32)
+def make_paged_logits_step(cfg: ModelConfig, page_size: int):
+    """The next decode step's logits, (n_slots, Vp) float32, from a
+    batcher's paged state (``params, pools, block_tab, last_tok, pos,
+    active``) without advancing it: the decode step's own forward, for
+    comparing read paths or a mesh against one device.  Returns
+    (pools with the step's K/V written, logits); nothing is donated."""
+    forward = _paged_decode_forward(cfg, page_size)
+
+    def logits_fn(params, pools, block_tab, last_tok, pos, active):
+        logits, new_pools = forward(params, pools, block_tab, last_tok, pos,
+                                    active)
+        return new_pools, logits[:, -1].astype(jnp.float32)
+
+    return _shard_wrap(cfg, page_size, logits_fn, n_extra_in=4,
+                       n_extra_out=1, donate=())
 
 
 @functools.lru_cache(maxsize=32)

@@ -24,7 +24,7 @@ from repro.kernels.flash_attention import flash_attention_decode_paged
 from repro.models import registry
 from repro.serve.batching import (ContinuousBatcher, PageAllocator, Request,
                                   drain)
-from repro.serve.serve_loop import greedy_generate
+from repro.serve.serve_loop import greedy_generate, make_paged_logits_step
 
 
 @pytest.fixture(scope="module")
@@ -480,6 +480,30 @@ def test_decode_flash_batcher_equivalence_gqa_window_ring(model):
             params, prompts, max_news)
         assert bat.paged
         assert paged == gold, f"paged decode_flash mismatch ({variant})"
+
+
+def test_paged_logits_step_matches_decode_step_and_kernel(model):
+    """The logits step reads a batcher's prefilled state without
+    advancing it: its argmax is the token the decode step then emits,
+    and the Pallas paged kernel gives the gather path's logits."""
+    cfg, params = model
+    pcfg = dataclasses.replace(cfg, kv_page_size=8)
+    bat = ContinuousBatcher(pcfg, params, n_slots=2, max_seq=48)
+    for i, p in enumerate(_prompts(pcfg, [9, 14])):
+        bat.submit(Request(rid=i, prompt=p, max_new=4))
+    bat.admit()
+    while bat._admitting:
+        bat._prefill_step()
+    state = (bat.params, bat.pools, bat.block_tab, bat.last_tok, bat.pos,
+             bat.active)
+    _, gath = make_paged_logits_step(pcfg, 8)(*state)
+    _, kern = make_paged_logits_step(
+        dataclasses.replace(pcfg, decode_flash=True), 8)(*state)
+    assert gath.shape == (2, cfg.padded_vocab) and gath.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(kern), np.asarray(gath),
+                               rtol=1e-5, atol=1e-5)
+    bat.step()
+    assert bat._host_last_tok == np.argmax(np.asarray(gath), -1).tolist()
 
 
 def test_gqa_paged_matches_dense():

@@ -126,12 +126,23 @@ def test_roofline_terms_and_bottleneck():
         flops_per_device=197e12, bytes_per_device=819e9,
         collective_bytes_per_device=100e9,
         collectives={"total": int(100e9)},
-        model_flops_global=197e12 * 256, n_active_params=1)
+        model_flops_global=197e12 * 256, n_active_params=1,
+        device_kind="TPU v5 lite")
     assert r.t_compute == pytest.approx(1.0)
     assert r.t_memory == pytest.approx(1.0)
     assert r.t_collective == pytest.approx(2.0)
     assert r.bottleneck == "collective"
     assert r.mfu_bound(256) == pytest.approx(0.5)
+
+
+def test_unknown_device_kind_has_no_peaks():
+    r = RA.Roofline(
+        arch="x", shape="train_4k", mesh="1", flops_per_device=1.0,
+        bytes_per_device=1.0, collective_bytes_per_device=0.0,
+        collectives={"total": 0}, model_flops_global=1.0,
+        n_active_params=1, device_kind="cpu")
+    with pytest.raises(KeyError, match="no published peaks"):
+        r.t_compute
 
 
 def test_collective_parse_kinds():

@@ -252,6 +252,50 @@ print("STREAMS-MATCH")
 
 
 @pytest.mark.multidevice
+def test_serving_weights_and_pools_built_sharded_in_serving_dtype():
+    """build_batcher on a mesh: every weight and pool leaf is created
+    with its PartitionSpec (each device holds its shard only), weights
+    in cfg.dtype, and the next-step logits on the mesh agree with one
+    device's on the same weights."""
+    code = _PRE + '''
+import jax, jax.numpy as jnp
+from repro.launch.serve import build_batcher
+from repro.serve.serve_loop import make_paged_logits_step, paged_sharding_specs
+
+def logits(b, prompts):
+    for i, p in enumerate(prompts):
+        b.submit(Request(rid=i, prompt=np.asarray(p, np.int32), max_new=4))
+    b.admit()
+    while b._admitting:
+        b._prefill_step()
+    return np.asarray(make_paged_logits_step(b.cfg, b.page_size)(
+        b.params, b.pools, b.block_tab, b.last_tok, b.pos, b.active)[1])
+
+mcfg = dataclasses.replace(smoke("minitron-4b"), dtype="bfloat16",
+                           mesh_shape=(1, 2))
+b = build_batcher(mcfg, seed=0, n_slots=2, max_seq=48)
+p_specs, pool_specs = paged_sharding_specs(mcfg, 8, b.mesh)
+for tree, specs in ((b.params, p_specs), (b.pools, pool_specs)):
+    for a, spec in zip(jax.tree.leaves(tree), jax.tree.leaves(
+            specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))):
+        want = jax.sharding.NamedSharding(b.mesh, spec)
+        assert a.sharding.is_equivalent_to(want, a.ndim), (a.shape, spec)
+assert all(a.dtype == jnp.bfloat16 for a in jax.tree.leaves(b.params))
+wq = b.params["blocks"]["attn"]["wq"]
+assert {s.data.shape[-1] for s in wq.addressable_shards} == {wq.shape[-1] // 2}
+sharded = logits(b, PROMPTS[:2])
+whole = jax.device_put(b.params, jax.devices()[0])
+one = build_batcher(dataclasses.replace(mcfg, mesh_shape=()), params=whole,
+                    n_slots=2, max_seq=48)
+ref = logits(one, PROMPTS[:2])
+err = np.linalg.norm(sharded - ref) / np.linalg.norm(ref)
+assert err < 5e-2, err
+print("BUILT-SHARDED", err)
+'''
+    assert "BUILT-SHARDED" in check_mesh(code, (1, 2))
+
+
+@pytest.mark.multidevice
 def test_launch_cli_mesh_banner():
     """--mesh end to end through the CLI: the banner surfaces the mesh
     shape, per-shard pool bytes, and the collective counts."""

@@ -57,6 +57,18 @@ def _repetitive_prompts(plens):
     return [np.tile(motif, L // 4 + 1)[:L].astype(np.int32) for L in plens]
 
 
+def _oracle_drafter(bat, plain):
+    """Drafter stub that proposes the next tokens of each request's plain
+    greedy stream: verification accepts every draft by construction, so
+    verify rounds run whatever the random weights decode to."""
+    def draft(slot):
+        r = bat._slot_req[slot]
+        done = len(bat._history[slot]) - len(r.prompt)
+        cap = min(bat.speculate_k, bat._host_remaining[slot] - 1)
+        return [int(t) for t in plain[r.rid][done:done + max(cap, 0)]]
+    return draft
+
+
 def _random_prompts(cfg, plens):
     return [np.asarray(registry.make_batch(cfg, "prefill", 1, L,
                                            seed=L)["tokens"][0])
@@ -223,6 +235,7 @@ def test_spec_deadline_expiry_frees_everything(model):
     scfg = _spec_cfg(cfg)
     bat = ContinuousBatcher(scfg, params, n_slots=2, max_seq=MAX_SEQ,
                             clock=lambda: fake[0])
+    bat._draft = _oracle_drafter(bat, plain)
     live = Request(rid=0, prompt=prompts[0], max_new=16)
     dying = Request(rid=1, prompt=prompts[1], max_new=16,
                     deadline_ms=500.0)
@@ -285,11 +298,14 @@ def test_verify_fault_unwinds_scratch_before_dying(model):
     block-table entries first, leaving the allocator consistent for
     fail_inflight."""
     cfg, params = model
+    prompts = _repetitive_prompts([9, 12])
+    plain, _ = _run(_plain_cfg(cfg), params, prompts, [12, 12])
     scfg = _spec_cfg(cfg)
     bat = ContinuousBatcher(scfg, params, n_slots=2, max_seq=MAX_SEQ,
                             faults="verify:2")
+    bat._draft = _oracle_drafter(bat, plain)
     reqs = [Request(rid=i, prompt=p, max_new=12)
-            for i, p in enumerate(_repetitive_prompts([9, 12]))]
+            for i, p in enumerate(prompts)]
     for r in reqs:
         bat.submit(r)
     with pytest.raises(BatcherFault):
@@ -297,6 +313,7 @@ def test_verify_fault_unwinds_scratch_before_dying(model):
     for r in reqs:
         with pytest.raises(RequestErrored):
             drain(r, timeout=2.0)
+    assert bat.stats()["speculation"]["accepted"] > 0
     _check_allocators(bat)
 
 
@@ -311,6 +328,7 @@ def test_supervised_recovery_from_verify_fault_is_bit_identical(model):
     scfg = _spec_cfg(cfg)
     bat = ContinuousBatcher(scfg, params, n_slots=2, max_seq=MAX_SEQ,
                             faults="verify:2")
+    bat._draft = _oracle_drafter(bat, plain)
     sup = ServeSupervisor(bat)
     reqs = [Request(rid=i, prompt=p, max_new=12)
             for i, p in enumerate(prompts)]
@@ -319,4 +337,5 @@ def test_supervised_recovery_from_verify_fault_is_bit_identical(model):
     sup.run(len(reqs))
     assert [drain(r) for r in reqs] == plain
     assert sup.report.restarts >= 1
+    assert bat.stats()["speculation"]["accepted"] > 0
     _check_allocators(bat)

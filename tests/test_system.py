@@ -67,7 +67,8 @@ mesh = jax.make_mesh((4, 2), ("data", "model"),
                      axis_types=(jax.sharding.AxisType.Auto,) * 2)
 compiled, dt = dryrun.lower_cell(cfg, shape, mesh)
 roof = RA.analyze(compiled, cfg, shape, "4x2", 8,
-                  registry.num_active_params(cfg))
+                  registry.num_active_params(cfg),
+                  device_kind="TPU v5 lite")
 rec = roof.to_dict(8)
 assert rec["flops_per_device"] > 0
 assert rec["bottleneck"] in ("compute", "memory", "collective")

@@ -57,6 +57,15 @@ def _motif_prompt(n):
     return np.tile(motif, n // 4 + 1)[:n].astype(np.int32)
 
 
+def _repeat_drafter(bat):
+    """Drafter stub: proposes the slot's last token repeated, so verify
+    rounds run whatever the random weights decode the prompt into."""
+    def draft(slot):
+        cap = min(bat.speculate_k, bat._host_remaining[slot] - 1)
+        return [bat._history[slot][-1]] * max(cap, 0)
+    return draft
+
+
 def _tick_clock(start=100.0, dt=1e-3):
     """Deterministic auto-advancing clock: every read moves time forward
     by ``dt``, so spans always have nonzero width and every stamp is
@@ -228,6 +237,7 @@ def test_full_lifecycle_trace_reconstruction(model):
     tel = ServeTelemetry(clock=clk)
     bat = ContinuousBatcher(lcfg, params, n_slots=2, max_seq=64,
                             queue_depth=8, clock=clk, telemetry=tel)
+    bat._draft = _repeat_drafter(bat)
     assert tel.clock is bat._clock          # bind_clock adopted it
 
     # phase 1: warm the prefix index (rid 0, served alone).

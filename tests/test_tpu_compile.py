@@ -1,0 +1,102 @@
+"""The main-path Pallas kernels compile for a TPU v5e at published widths.
+
+Nothing runs: each kernel is lowered for a described ``v5e:2x2`` chip and
+handed to the TPU compiler, which refuses what interpret mode accepts
+(misaligned tiles, too much VMEM or SMEM).  The topology is described
+inside a fixture, so importing or collecting this file never loads the
+TPU library, and where no topology can be described every test skips.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import (flash_attention_decode,
+                                           flash_attention_decode_paged)
+from repro.kernels.ssd_scan import ssd_scan
+from repro.models.cache_layouts import ring_blocks
+
+SLOTS, MAX_SEQ, PAGE = 4, 2048, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but never read back without one: keep it out.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "the kernel was not compiled for TPU"
+
+
+def _paged(one_chip, hq, hkv, d, kv_dtype, *, window=None, ring=False,
+           scales=False):
+    n_blocks = (ring_blocks(window, PAGE) if ring
+                else MAX_SEQ // PAGE)
+    n_pages = SLOTS * n_blocks
+    pool = (n_pages, hkv, PAGE, d)
+    shapes = [((SLOTS, hq, 1, d), jnp.bfloat16), (pool, kv_dtype),
+              (pool, kv_dtype), ((SLOTS, n_blocks), jnp.int32),
+              ((SLOTS,), jnp.int32)]
+    if ring:
+        shapes.append(((SLOTS, n_blocks), jnp.int32))
+    if scales:
+        shapes += [((n_pages, hkv, PAGE, 1), jnp.bfloat16)] * 2
+
+    def fn(q, kp, vp, bt, pos, *rest):
+        base = rest[0] if ring else None
+        ks, vs = rest[-2:] if scales else (None, None)
+        return flash_attention_decode_paged(
+            q, kp, vp, bt, pos, window=window, page_base=base,
+            k_scale_pages=ks, v_scale_pages=vs, interpret=False)
+
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_compiles_at_minitron_widths(one_chip, kv):
+    """minitron-4b: 24 query heads over 8 KV heads (group 3), d 128."""
+    if kv == "bf16":
+        _paged(one_chip, 24, 8, 128, jnp.bfloat16)
+    else:
+        _paged(one_chip, 24, 8, 128, jnp.int8, scales=True)
+
+
+def test_paged_decode_compiles_at_gemma3_widths_with_ring(one_chip):
+    """gemma3-12b local layers: 16 over 8 heads, d 256, 1024-token
+    window on a ring of pages with per-entry logical bases."""
+    _paged(one_chip, 16, 8, 256, jnp.bfloat16, window=1024, ring=True)
+
+
+def test_dense_decode_compiles_at_minitron_widths(one_chip):
+    fn = functools.partial(flash_attention_decode, interpret=False)
+    cache = ((SLOTS, 8, MAX_SEQ, 128), jnp.bfloat16)
+    _compile(fn, one_chip, ((SLOTS, 24, 1, 128), jnp.bfloat16), cache,
+             cache, ((SLOTS,), jnp.int32))
+
+
+def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
+    """mamba2-1p3b: d_inner 4096 as 64 heads of 64, state 128, chunk 256."""
+    b, s, h, dh, ds = 1, 1024, 64, 64, 128
+    fn = functools.partial(ssd_scan, chunk=256, interpret=False)
+    _compile(fn, one_chip, ((b, s, h, dh), jnp.float32),
+             ((b, s, h), jnp.float32), ((h,), jnp.float32),
+             ((b, s, ds), jnp.float32), ((b, s, ds), jnp.float32))
