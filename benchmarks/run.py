@@ -994,8 +994,8 @@ def bench_spec_decode_throughput():
     row("spec_decode_throughput", 4 * max_new / stats["rep"][0] * 1e6,
         f"rep_tok_per_s={stats['rep'][0]:.0f};"
         f"rep_speedup={rep_speedup:.2f};adv_ratio={adv_ratio:.2f};"
-        f"acceptance={st['accepted'] / max(st['drafted'], 1):.2f};"
-        f"verify_steps={st['verify_steps']};k=8;tokens_equal=1")
+        f"acceptance={st['acceptance_rate']:.2f};"
+        f"verify_steps={st['verify_rounds']};k=8;tokens_equal=1")
     RESULTS["spec_decode_throughput"]["rep_speedup"] = round(rep_speedup, 3)
     RESULTS["spec_decode_throughput"]["adv_ratio"] = round(adv_ratio, 3)
     RESULTS["spec_decode_throughput"]["tokens_equal"] = 1

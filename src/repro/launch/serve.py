@@ -112,9 +112,10 @@ def main(argv=None):
                          "exit: .json => Chrome chrome://tracing "
                          "format, anything else => JSONL events")
     ap.add_argument("--profile", action="store_true",
-                    help="wrap the jitted serve steps in jax.profiler "
-                         "TraceAnnotation/StepTraceAnnotation so device "
-                         "profiles line up with the host trace spans")
+                    help="write every serve.* program span into a "
+                         "running jax.profiler trace as an annotation "
+                         "carrying its trace stamp, so device profiles "
+                         "line up with the host trace spans")
     ap.add_argument("--mesh", default="",
                     help="device mesh shape, e.g. '2' (2-way tensor "
                          "parallel) or '1x2' (data x model); the last "
@@ -195,13 +196,10 @@ def main(argv=None):
     supervisor = ServeSupervisor(batcher) if args.supervise else None
     if batcher.mesh is not None:
         m = batcher.stats()["mesh"]
-        co = m["collectives_per_decode_step"]
         print(f"mesh: {'x'.join(map(str, m['shape']))} over axes "
               f"({','.join(m['axes'])}), tp={m['tp']}, kv pool "
               f"{m['pool_bytes_per_shard']}B/shard of "
-              f"{m['pool_bytes_total']}B total, "
-              f"{co['psum']} psum + {co['all_gather']} all_gather "
-              f"per decode step")
+              f"{m['pool_bytes_total']}B total")
     sysp = rng.integers(0, cfg.vocab_size,
                         min(args.shared_prefix,
                             args.prompt_len)).astype(np.int32)
@@ -260,11 +258,12 @@ def main(argv=None):
                   f"pools {st['pools']}")
         sp = st.get("speculation", {})
         if sp.get("k"):
-            print(f"speculation: k={sp['k']}, drafted {sp['drafted']}, "
-                  f"accepted {sp['accepted']} "
+            print(f"speculation: k={sp['k']}, drafted "
+                  f"{sp['tokens_drafted']}, accepted "
+                  f"{sp['tokens_accepted']} "
                   f"(rate {sp['acceptance_rate']:.2f}), "
-                  f"rolled back {sp['rolled_back']}, "
-                  f"verify steps {sp['verify_steps']}, "
+                  f"rolled back {sp['tokens_rolled_back']}, "
+                  f"verify rounds {sp['verify_rounds']}, "
                   f"decode steps saved {sp['decode_steps_saved']}")
         if "tiers" in st:
             t = st["tiers"]
@@ -276,8 +275,8 @@ def main(argv=None):
                   f"rehits {t['rehits']} ({t['rehit_tokens']} tokens "
                   f"restored), recomputes {t['recomputes']}, "
                   f"recompute-resumes {t['recompute_resumes']}, "
-                  f"transfers {t['staged_gathers']}G/"
-                  f"{t['staged_scatters']}S "
+                  f"transfers {t['gathers']}G/"
+                  f"{t['scatters']}S "
                   f"({t['d2h_bytes']}B down, {t['h2d_bytes']}B up)")
         if batcher._tiers is not None and batcher.tier_snapshot:
             n = batcher.save_tier_snapshot()

@@ -558,7 +558,8 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
     theta = theta if theta is not None else cfg.rope_theta
     b, s, d = x.shape
     h = rmsnorm(x, p["norm"])
-    q, k, v = _qkv(cfg, p, h)                        # (b, s, h*, hd)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _qkv(cfg, p, h)                    # (b, s, h*, hd)
     positions = pos[:, None] + jnp.arange(s)         # (b, s)
     pos_h = positions[:, :, None]                    # broadcast over heads
     q = rope(q, pos_h, theta).transpose(0, 2, 1, 3)
@@ -571,110 +572,117 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
     n_blocks = block_tab.shape[1]
 
     # --- append: scatter the chunk's K/V into the pool -------------------------
-    logical = positions // page                                     # (b, s)
-    if ring:
-        tab_idx = logical % n_blocks
-        # only pages still live at the end of the true chunk are
-        # written; an in-chunk wrap must not clobber pages the NEXT
-        # positions still need.
-        end = pos + (last_idx if last_idx is not None
-                     else jnp.full((b,), s - 1, jnp.int32))         # (b,)
-        keep = logical > (end // page)[:, None] - n_blocks
-    else:
-        tab_idx = jnp.minimum(logical, n_blocks - 1)
-        keep = logical < n_blocks
-    if last_idx is not None:
-        keep &= jnp.arange(s)[None, :] <= last_idx[:, None]
-    if cache_offset is not None:
-        keep &= positions >= cache_offset[:, None]
-    wp = jnp.take_along_axis(block_tab, tab_idx, axis=1)
-    wp = jnp.where(keep, wp, n_pages)                # invalid id -> dropped
-    wo = positions % page
+    with jax.named_scope("kv_write"):
+        logical = positions // page                             # (b, s)
+        if ring:
+            tab_idx = logical % n_blocks
+            # only pages still live at the end of the true chunk are
+            # written; an in-chunk wrap must not clobber pages the NEXT
+            # positions still need.
+            end = pos + (last_idx if last_idx is not None
+                         else jnp.full((b,), s - 1, jnp.int32))  # (b,)
+            keep = logical > (end // page)[:, None] - n_blocks
+        else:
+            tab_idx = jnp.minimum(logical, n_blocks - 1)
+            keep = logical < n_blocks
+        if last_idx is not None:
+            keep &= jnp.arange(s)[None, :] <= last_idx[:, None]
+        if cache_offset is not None:
+            keep &= positions >= cache_offset[:, None]
+        wp = jnp.take_along_axis(block_tab, tab_idx, axis=1)
+        wp = jnp.where(keep, wp, n_pages)            # invalid id -> dropped
+        wo = positions % page
 
-    kc = k.transpose(0, 2, 1, 3)                     # (b, s, hkv, hd)
-    vc = v.transpose(0, 2, 1, 3)
-    new_pages = dict(pages)
-    if quantized:
-        kq, ks = _kv_quantize(kc)                    # int8 + (b,s,hkv,1) scale
-        vq, vs = _kv_quantize(vc)
-        new_pages["k"] = pk.at[wp, :, wo].set(kq, mode="drop")
-        new_pages["v"] = pv.at[wp, :, wo].set(vq, mode="drop")
-        new_pages["k_scale"] = pages["k_scale"].at[wp, :, wo].set(
-            ks, mode="drop")
-        new_pages["v_scale"] = pages["v_scale"].at[wp, :, wo].set(
-            vs, mode="drop")
-    else:
-        new_pages["k"] = pk.at[wp, :, wo].set(kc.astype(pk.dtype),
-                                              mode="drop")
-        new_pages["v"] = pv.at[wp, :, wo].set(vc.astype(pv.dtype),
-                                              mode="drop")
+        kc = k.transpose(0, 2, 1, 3)                     # (b, s, hkv, hd)
+        vc = v.transpose(0, 2, 1, 3)
+        new_pages = dict(pages)
+        if quantized:
+            kq, ks = _kv_quantize(kc)             # int8 + (b,s,hkv,1) scale
+            vq, vs = _kv_quantize(vc)
+            new_pages["k"] = pk.at[wp, :, wo].set(kq, mode="drop")
+            new_pages["v"] = pv.at[wp, :, wo].set(vq, mode="drop")
+            new_pages["k_scale"] = pages["k_scale"].at[wp, :, wo].set(
+                ks, mode="drop")
+            new_pages["v_scale"] = pages["v_scale"].at[wp, :, wo].set(
+                vs, mode="drop")
+        else:
+            new_pages["k"] = pk.at[wp, :, wo].set(kc.astype(pk.dtype),
+                                                  mode="drop")
+            new_pages["v"] = pv.at[wp, :, wo].set(vc.astype(pv.dtype),
+                                                  mode="drop")
 
     # --- read ------------------------------------------------------------------
-    page_base = _ring_page_base(pos, page, n_blocks) if ring else None
-    if cfg.decode_flash and (s == 1 or verify) and cache_offset is None:
-        # write-then-read through the block-table kernel.  The verify
-        # span's writes land before the read, so ring bases key off the
-        # span END — entries the span wrote hold NEW logical pages (the
-        # ring table width is padded by speculate_k, so every clobbered
-        # old page is strictly out-of-window for every row).  At s == 1
-        # this reduces to the plain base-from-pos.
-        from ..kernels.flash_attention import flash_attention_decode_paged
-        flash_base = (_ring_page_base(pos + (s - 1), page, n_blocks)
-                      if ring else None)
-        o = flash_attention_decode_paged(
-            q, new_pages["k"], new_pages["v"], block_tab, pos,
-            window=window, page_base=flash_base,
-            k_scale_pages=new_pages.get("k_scale"),
-            v_scale_pages=new_pages.get("v_scale"))
-    else:
-        # gather the PRE-write pool state + overlay the chunk's own K/V.
-        bt = jnp.minimum(block_tab, n_pages - 1)
-        S = n_blocks * page
-
-        def gather(pool):
-            g = pool[bt]                             # (b, nb, hkv, page, X)
-            return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, S, -1)
-
-        if quantized:
-            kd = _kv_dequantize(gather(pages["k"]), gather(pages["k_scale"]),
-                                q.dtype)
-            vd = _kv_dequantize(gather(pages["v"]), gather(pages["v_scale"]),
-                                q.dtype)
-            if s == 1 or verify:                     # pool-rounded own k/v
-                kl = _kv_dequantize(kq, ks, q.dtype).transpose(0, 2, 1, 3)
-                vl = _kv_dequantize(vq, vs, q.dtype).transpose(0, 2, 1, 3)
-            else:
-                kl, vl = k, v
+    with jax.named_scope("attn_read"):
+        page_base = _ring_page_base(pos, page, n_blocks) if ring else None
+        if cfg.decode_flash and (s == 1 or verify) and cache_offset is None:
+            # write-then-read through the block-table kernel.  The
+            # verify span's writes land before the read, so ring bases key
+            # off the span END — entries the span wrote hold NEW logical
+            # pages (the ring table width is padded by speculate_k, so
+            # every clobbered old page is strictly out-of-window for every
+            # row).  At s == 1 this reduces to the plain base-from-pos.
+            from ..kernels.flash_attention import (
+                flash_attention_decode_paged)
+            flash_base = (_ring_page_base(pos + (s - 1), page, n_blocks)
+                          if ring else None)
+            o = flash_attention_decode_paged(
+                q, new_pages["k"], new_pages["v"], block_tab, pos,
+                window=window, page_base=flash_base,
+                k_scale_pages=new_pages.get("k_scale"),
+                v_scale_pages=new_pages.get("v_scale"))
         else:
-            kd = gather(pages["k"]).astype(q.dtype)
-            vd = gather(pages["v"]).astype(q.dtype)
-            if s == 1 or verify:
-                kl = k.astype(pk.dtype).astype(q.dtype)
-                vl = v.astype(pv.dtype).astype(q.dtype)
+            # gather the PRE-write pool state + overlay the chunk's own K/V.
+            bt = jnp.minimum(block_tab, n_pages - 1)
+            S = n_blocks * page
+
+            def gather(pool):
+                g = pool[bt]                         # (b, nb, hkv, page, X)
+                return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, S, -1)
+
+            if quantized:
+                kd = _kv_dequantize(gather(pages["k"]),
+                                    gather(pages["k_scale"]), q.dtype)
+                vd = _kv_dequantize(gather(pages["v"]),
+                                    gather(pages["v_scale"]), q.dtype)
+                if s == 1 or verify:                     # pool-rounded own k/v
+                    kl = _kv_dequantize(kq, ks, q.dtype).transpose(
+                        0, 2, 1, 3)
+                    vl = _kv_dequantize(vq, vs, q.dtype).transpose(
+                        0, 2, 1, 3)
+                else:
+                    kl, vl = k, v
             else:
-                kl, vl = k, v
-        if ring:
-            kpos = (page_base[:, :, None]
-                    + jnp.arange(page)[None, None, :]).reshape(b, S)
-        else:
-            kpos = jnp.broadcast_to(jnp.arange(S)[None, :], (b, S))
-        K = jnp.concatenate([kd, kl], axis=2)        # (b, hkv, S + s, hd)
-        V = jnp.concatenate([vd, vl], axis=2)
-        kpos_cat = jnp.concatenate([kpos, positions], axis=1)   # (b, S+s)
-        # gathered entries are only valid STRICTLY before the chunk
-        # (stale/ring-relabeled slots carry kpos >= pos); own entries
-        # cover [pos, pos+s).
-        pre_ok = jnp.concatenate(
-            [(kpos >= 0) & (kpos < pos[:, None]),
-             jnp.ones((b, s), bool)], axis=1)        # (b, S+s)
-        mask = (kpos_cat[:, None, :] <= positions[:, :, None]) \
-            & pre_ok[:, None, :]
-        if window is not None:
-            mask &= kpos_cat[:, None, :] > positions[:, :, None] - window
-        o = attention_masked(q, K, V, mask)
+                kd = gather(pages["k"]).astype(q.dtype)
+                vd = gather(pages["v"]).astype(q.dtype)
+                if s == 1 or verify:
+                    kl = k.astype(pk.dtype).astype(q.dtype)
+                    vl = v.astype(pv.dtype).astype(q.dtype)
+                else:
+                    kl, vl = k, v
+            if ring:
+                kpos = (page_base[:, :, None]
+                        + jnp.arange(page)[None, None, :]).reshape(b, S)
+            else:
+                kpos = jnp.broadcast_to(jnp.arange(S)[None, :], (b, S))
+            K = jnp.concatenate([kd, kl], axis=2)    # (b, hkv, S + s, hd)
+            V = jnp.concatenate([vd, vl], axis=2)
+            kpos_cat = jnp.concatenate([kpos, positions], axis=1)  # (b, S+s)
+            # gathered entries are only valid STRICTLY before the chunk
+            # (stale/ring-relabeled slots carry kpos >= pos); own entries
+            # cover [pos, pos+s).
+            pre_ok = jnp.concatenate(
+                [(kpos >= 0) & (kpos < pos[:, None]),
+                 jnp.ones((b, s), bool)], axis=1)        # (b, S+s)
+            mask = (kpos_cat[:, None, :] <= positions[:, :, None]) \
+                & pre_ok[:, None, :]
+            if window is not None:
+                mask &= (kpos_cat[:, None, :]
+                         > positions[:, :, None] - window)
+            o = attention_masked(q, K, V, mask)
     # under shard_map TP the heads are column-sharded and wo row-sharded:
     # each shard holds a partial sum over its heads — reduce it here.
-    y = psum_parts(o.transpose(0, 2, 1, 3).reshape(b, s, -1) @ p["wo"])
+    with jax.named_scope("attn_proj"):
+        y = psum_parts(o.transpose(0, 2, 1, 3).reshape(b, s, -1) @ p["wo"])
     y = constrain(y, "batch", None, "embed")
     return x + y, new_pages
 
@@ -953,6 +961,7 @@ def mlp_decls(cfg, stack: Tuple[int, ...] = (), d_ff: Optional[int] = None
     return decls
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg, p, x):
     h = rmsnorm(x, p["norm"])
     h = constrain(h, "batch", None, "embed")

@@ -694,16 +694,17 @@ def forward(cfg, params, batch, mode: str = "train",
             x = jnp.take_along_axis(x, idx, axis=1)
         else:
             x = x[:, -1:]
-    if cfg.family == "audio":
-        logits = jnp.einsum("bsd,kdv->bskv", x, params["unembed"])
-    else:
-        logits = x @ params["unembed"]
-    if logits.shape[-1] != cfg.padded_vocab:
-        # shard_map TP: unembed is vocab-column-sharded (a bit-exact
-        # per-shard matmul — the contraction dim is unsharded), so the
-        # greedy argmax needs the full row back.
-        logits = gather_parts(logits, axis=-1)
-    logits = constrain(logits, "batch", None, "vocab")
+    with jax.named_scope("logits"):
+        if cfg.family == "audio":
+            logits = jnp.einsum("bsd,kdv->bskv", x, params["unembed"])
+        else:
+            logits = x @ params["unembed"]
+        if logits.shape[-1] != cfg.padded_vocab:
+            # shard_map TP: unembed is vocab-column-sharded (a bit-exact
+            # per-shard matmul — the contraction dim is unsharded), so the
+            # greedy argmax needs the full row back.
+            logits = gather_parts(logits, axis=-1)
+        logits = constrain(logits, "batch", None, "vocab")
     if mode == "train":
         return logits
     if rewrap_kv:

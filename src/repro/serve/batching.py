@@ -143,11 +143,15 @@ from __future__ import annotations
 
 import bisect
 import collections
+import collections.abc
+import contextlib
 import dataclasses
 import functools
 import os
+import re
 import time
 import warnings
+import weakref
 from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
                     Tuple)
 
@@ -165,13 +169,66 @@ from .kv_tiers import KVTierManager, SnapshotCorruptError, StagedTransferEngine
 from .prefix_cache import PageAllocator, PrefixIndex
 from .resilience import (BatcherFault, FaultPlan, InjectedFault, StallFault,
                          TerminalEvent, class_rank)
-from .telemetry import ServeTelemetry, _NULLCTX
+from .telemetry import ENGINE_RID, ServeTelemetry
 from .serve_loop import (make_chunk_prefill_step, make_paged_decode_step,
                          make_spec_verify_step, named_shardings,
                          paged_sharding_specs, serving_mesh_for)
 
 _MIN_BUCKET = 8            # smallest prefill bucket (pad-to-power-of-two)
 _MIN_CHUNK = 16            # smallest auto-selected prefill chunk
+_NULLCTX = contextlib.nullcontext()      # a span site with telemetry off
+# compiled HLO text: the module's name, and each op's metadata path
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
+_HLO_OP_NAME = re.compile(
+    r"^\s*(?:ROOT )?%?([^\s=]+) = .*?metadata=\{op_name=\"([^\"]*)\"", re.M)
+
+
+def _compiled_ops(fn, args) -> Tuple[str, Dict[str, str]]:
+    """A jitted program's module name and ``{op: op_name}`` over the ops
+    of its optimized HLO, where ``op_name`` is the op's metadata path
+    with its ``jax.named_scope`` names (``.../attn_read/...``).
+
+    JAX's persistent compile cache leaves metadata out of its key, so
+    the executable a call loaded may carry the metadata of an older
+    version of the program.  So the program is compiled once more with
+    the metadata in the key, and with an option of its own that keeps
+    the in-process cache from answering; XLA names the ops of one HLO
+    the same way in every compile."""
+    key = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        text = fn.lower(*args).compile(
+            compiler_options={"xla_embed_ir_in_executable": True}).as_text()
+    finally:
+        jax.config.update(key, was)
+    return (_HLO_MODULE.search(text).group(1),
+            dict(_HLO_OP_NAME.findall(text)))
+
+
+class _ProgramOps(collections.abc.Mapping):
+    """``stats()["programs"]`` while profiling
+    (``ContinuousBatcher._program_ops``): compiled when first read, by a
+    profile's reader after serving, not when ``stats()`` is taken.  It
+    holds the batcher weakly, so a kept ``stats()`` snapshot does not
+    keep the batcher's device state alive; once the batcher is gone it
+    reads empty."""
+
+    def __init__(self, batcher: "ContinuousBatcher"):
+        self._batcher = weakref.ref(batcher)
+
+    def _ops(self) -> Dict[str, Dict[str, str]]:
+        batcher = self._batcher()
+        return batcher._program_ops() if batcher is not None else {}
+
+    def __getitem__(self, module: str) -> Dict[str, str]:
+        return self._ops()[module]
+
+    def __iter__(self):
+        return iter(self._ops())
+
+    def __len__(self) -> int:
+        return len(self._ops())
 
 
 def _next_pow2(n: int) -> int:
@@ -436,6 +493,7 @@ class ContinuousBatcher:
         self.resumes = 0
         self.peak_pages = 0
         self.preempted_rids: List[int] = []    # observability (tests/benches)
+        self._programs: Optional[Dict[str, Dict[str, str]]] = None
         # prefix-cache observability (stats(); all zero when disabled).
         self.prefix_lookups = 0
         self.prefix_hits = 0
@@ -741,43 +799,28 @@ class ContinuousBatcher:
                 total_bytes += int(leaf.nbytes)
                 local = leaf.sharding.shard_shape(leaf.shape)
                 shard_bytes += int(np.prod(local)) * leaf.dtype.itemsize
-            # static per-decode-step collective counts (from the model
-            # shape, not a trace): one psum per attention + one per
-            # ff/moe block; MLA adds a latent all_gather per layer and
-            # every tp > 1 step gathers the logits tile.
             s["mesh"] = {
                 "shape": tuple(self.cfg.mesh_shape),
                 "axes": tuple(self.mesh.axis_names),
                 "tp": tp,
                 "pool_bytes_per_shard": shard_bytes,
                 "pool_bytes_total": total_bytes,
-                "collectives_per_decode_step": {
-                    "psum": 2 * self.cfg.n_layers,
-                    "all_gather": (0 if tp <= 1 else
-                                   1 + (self.cfg.n_layers
-                                        if self.cfg.mla else 0)),
-                },
             }
         # every accepted draft token is one decode step the slot skipped;
         # rolled_back counts draft tokens whose speculative KV was
         # discarded by block-table rollback.
         s["speculation"] = {
             "k": self.speculate_k,
-            # canonical names (what the Prometheus surface exports);
-            # the old bare names ride along as aliases for one release
-            # — mapping table in docs/serving.md "Observability".
             "tokens_drafted": self.spec_drafted,
             "tokens_accepted": self.spec_accepted,
             "tokens_rolled_back": self.spec_rolled_back,
             "verify_rounds": self.spec_verify_steps,
-            "drafted": self.spec_drafted,
-            "accepted": self.spec_accepted,
-            "rolled_back": self.spec_rolled_back,
             "acceptance_rate": (self.spec_accepted
                                 / max(self.spec_drafted, 1)),
-            "verify_steps": self.spec_verify_steps,
             "decode_steps_saved": self.spec_accepted,
         }
+        if self._telemetry and self._telemetry.profile:
+            s["programs"] = _ProgramOps(self)
         if self._tiers is not None:
             s["tiers"] = {**self._tiers.stats(),
                           "recompute_resumes": self.recompute_resumes}
@@ -791,6 +834,21 @@ class ContinuousBatcher:
             s["cached_prefixes"] = self._prefix.n_nodes
             s["cached_prefix_pages"] = self._prefix.n_pages
         return s
+
+    def _program_ops(self) -> Dict[str, Dict[str, str]]:
+        """For reading a device profile: the decode and chunk programs,
+        once both have run, by module name, as ``{op: op_name}`` over
+        the ops of their compiled HLO (``_compiled_ops``), lowered from
+        the batcher's own state; computed once."""
+        if self._programs is None and self.steps and self.prefill_chunks:
+            chunk = self._chunk_inputs(np.zeros((1, self.chunk), np.int32),
+                                       0, 0, 0, False, 1, 1, 0)
+            state = (self.params, self.pools, self.block_tab, self.last_tok,
+                     self.pos, self.remaining, self.active)
+            self._programs = dict(
+                _compiled_ops(fn, args) for fn, args in
+                ((self._step, state), (self._chunk_fn, state + chunk)))
+        return self._programs or {}
 
     def _sync_telemetry(self) -> None:
         """Collector: mirror the plain-attribute lifetime counters into
@@ -1064,6 +1122,35 @@ class ContinuousBatcher:
             return taken * tiers.block
         return 0
 
+    def _match_prefix(self, prompt) -> Tuple[int, Dict[str, List[int]]]:
+        """The prefix cache's longest match for ``prompt`` (T1-cached
+        blocks the index lacks are promoted first): matched tokens and
+        the matched pages of each group."""
+        prompt_i32 = np.asarray(prompt, np.int32)
+        if self._tiers is not None:
+            self._tier_promote(prompt_i32)
+        return self._prefix.match(prompt_i32)
+
+    def _alloc_admission(self, r: Request, n_attach: int,
+                         resume: Optional[_Preempted]
+                         ) -> Optional[Dict[str, List[int]]]:
+        """The private pages an admission needs in every group (evicting
+        cached prefixes under pressure), all or nothing: None, with
+        nothing held, when a group's pool is dry."""
+        grabbed: Dict[str, List[int]] = {}
+        for g in self.layout.groups:
+            need = self._admit_pages_needed(
+                r, g.name, cover=(resume.pos + 1) if resume else None)
+            if g.shareable:
+                need -= n_attach
+            pages = self._alloc_evict(g.name, max(need, 0))
+            if pages is None:
+                for name, pgs in grabbed.items():
+                    self._alloc[name].free(pgs)
+                return None
+            grabbed[g.name] = pages
+        return grabbed
+
     def _try_admit_paged(self, r: Request, slot: int,
                          resume: Optional[_Preempted] = None) -> bool:
         """Reserve admission pages + a slot and start chunked prefill.
@@ -1088,15 +1175,18 @@ class ContinuousBatcher:
         prompt blocks), but the final chunk restores the parked decode
         budget and arms the suppressed-output replay instead of
         emitting a first token."""
+        tel = self._telemetry
         plen = len(r.prompt)
         m = 0
         shared: Dict[str, List[int]] = {g.name: [] for g in self.layout.groups}
         if self.prefix_cache:
             self.prefix_lookups += 1
-            prompt_i32 = np.asarray(r.prompt, np.int32)
-            if self._tiers is not None:
-                self._tier_promote(prompt_i32)
-            m, shared = self._prefix.match(prompt_i32)
+            if tel:
+                with tel.span(r.rid, "serve.prefix_match") as sp:
+                    m, shared = self._match_prefix(r.prompt)
+                    sp.set(prefix_hit_tokens=m)
+            else:
+                m, shared = self._match_prefix(r.prompt)
         n_matched = _ceil_div(m, self.page_size)
         partial = bool(m % self.page_size)
         cow = partial and (m < plen or self.reserve_decode)
@@ -1112,30 +1202,31 @@ class ContinuousBatcher:
         for name, pgs in pinned.items():
             if pgs:
                 self._alloc[name].incref(pgs)
-        grabbed: Dict[str, List[int]] = {}
-        for g in self.layout.groups:
-            need = self._admit_pages_needed(
-                r, g.name, cover=(resume.pos + 1) if resume else None)
-            if g.shareable:
-                need -= n_attach
-            pages = self._alloc_evict(g.name, max(need, 0))
-            if pages is None:
-                for name, pgs in grabbed.items():
+        if tel:
+            with tel.span(r.rid, "serve.alloc") as sp:
+                evictions = self.prefix_evictions
+                grabbed = self._alloc_admission(r, n_attach, resume)
+                sp.set(pages=sum(map(len, (grabbed or {}).values())),
+                       evicted=self.prefix_evictions - evictions)
+        else:
+            grabbed = self._alloc_admission(r, n_attach, resume)
+        if grabbed is None:
+            for name, pgs in pinned.items():
+                if pgs:
                     self._alloc[name].free(pgs)
-                for name, pgs in pinned.items():
-                    if pgs:
-                        self._alloc[name].free(pgs)
-                return False
-            grabbed[g.name] = pages
+            return False
         for g in self.layout.groups:
             name = g.name
             attach = shared[name][:n_attach] if g.shareable else []
             if cow and shared[name][n_attach:]:
                 # divergence mid-page: duplicate the boundary page into
                 # the first private page before any differing write.
-                self.pools = self._pinned(self.layout.copy_pages(
-                    self.pools, name, shared[name][n_attach:n_attach + 1],
-                    grabbed[name][:1]))
+                with (tel.span(r.rid, "serve.cow_copy", pages=1) if tel
+                      else _NULLCTX):
+                    self.pools = self._pinned(self.layout.copy_pages(
+                        self.pools, name,
+                        shared[name][n_attach:n_attach + 1],
+                        grabbed[name][:1]))
             if pinned[name][n_attach:]:            # unpin the CoW source
                 self._alloc[name].free(pinned[name][n_attach:])
             row = attach + grabbed[name]
@@ -1217,80 +1308,108 @@ class ContinuousBatcher:
             C = max(_next_pow2(a.plen - base), _MIN_CHUNK)
             fn = make_chunk_prefill_step(self.cfg, C, self.max_seq,
                                          self.page_size)
-        seg = np.zeros((1, C), np.int32)
-        part = np.asarray(a.req.prompt[base:base + C], np.int32)
-        seg[0, :len(part)] = part
-        last_in_chunk = (a.plen - 1 - base) if final else (C - 1)
-        # A resume re-admission needs no special budget: pos + remaining
-        # == plen + max_new - 1 at every step (set at admission, kept in
-        # lockstep by decode, re-established by both resume modes), so
-        # installing max_new - 1 again leaves exactly (replay steps +
-        # parked remaining) on the device counter.
         tel = self._telemetry
+        rid = a.req.rid
         t0 = tel.clock() if tel else 0.0
+        with (tel.span(rid, "serve.prefill_inputs") if tel else _NULLCTX):
+            seg = np.zeros((1, C), np.int32)
+            part = np.asarray(a.req.prompt[base:base + C], np.int32)
+            seg[0, :len(part)] = part
+            last_in_chunk = (a.plen - 1 - base) if final else (C - 1)
+            # A resume re-admission needs no special budget: pos +
+            # remaining == plen + max_new - 1 at every step (set at
+            # admission, kept in lockstep by decode, re-established by
+            # both resume modes), so installing max_new - 1 again leaves
+            # exactly (replay steps + parked remaining) on the device
+            # counter.
+            inputs = self._chunk_inputs(seg, base, last_in_chunk, a.slot,
+                                        final, a.plen, a.req.max_new,
+                                        a.cache_offset)
         try:
-            with (tel.annotate("serve.prefill_chunk",
-                               step=self.prefill_chunks)
+            with (tel.span(rid, "serve.prefill_chunk",
+                           step=self.prefill_chunks)
                   if tel else _NULLCTX):
                 (self.pools, self.last_tok, self.pos, self.remaining,
                  self.active, tok0) = fn(
                     self.params, self.pools, self.block_tab, self.last_tok,
-                    self.pos, self.remaining, self.active, jnp.asarray(seg),
-                    jnp.full((1,), base, jnp.int32),
-                    jnp.full((1,), last_in_chunk, jnp.int32),
-                    jnp.int32(a.slot), jnp.asarray(final),
-                    jnp.int32(a.plen), jnp.int32(a.req.max_new),
-                    jnp.int32(a.cache_offset))
+                    self.pos, self.remaining, self.active, *inputs)
         except Exception as e:
             # a genuine failure inside the jitted prefill may have
             # consumed the donated pools — fatal; the supervisor owns
             # the rebuild.
             raise BatcherFault(e) from e
-        if tel:
-            tel.note_chunk(a.req.rid, a.slot, c, t0, tel.clock(),
-                           base=base, final=final)
         self.prefill_chunks += 1
         a.next_chunk += 1
-        if final:
-            self._admitting.popleft()
-            # drafter history = every token the model has consumed
-            # (prompt + first sampled token); invariant len == pos + 1.
-            self._history[a.slot] = \
-                [int(t) for t in a.req.prompt] + [int(tok0)]
-            self._accept_ewma[a.slot] = 1.0
-            self._probe_at[a.slot] = 0
-            self._probe_gap[a.slot] = 0
-            self._ng_idx[a.slot].clear()
-            self._ng_done[a.slot] = 0
-            if a.resume is not None:
-                # first token already reached the consumer before the
-                # preemption: arm the suppressed-output replay instead.
-                replay = a.resume.pos - a.plen
-                self._slot_req[a.slot] = a.req
-                self._host_pos[a.slot] = a.plen
-                self._host_last_tok[a.slot] = int(tok0)
-                self._host_remaining[a.slot] = a.resume.remaining + replay
-                self._replay_skip[a.slot] = replay + a.resume.skip
-                self.resumes += 1
-                self.recompute_resumes += 1
-                if tel:
-                    tel.note_resume(a.req.rid, a.slot, "recompute")
-                return
-            a.req.out.Push(int(tok0))
+        if not final:
+            # nothing is read back: the chunk runs on behind the host, and
+            # its span ends with the dispatch.
             if tel:
-                tel.note_first_token(a.req.rid, a.slot, tel.clock(),
-                                     pos=a.plen)
-            if a.req.max_new > 1 and a.plen < self.max_seq - 1:
-                self._slot_req[a.slot] = a.req
-                self._host_pos[a.slot] = a.plen
-                self._host_last_tok[a.slot] = int(tok0)
-                self._host_remaining[a.slot] = a.req.max_new - 1
-            else:                              # retired at admission
-                a.req.out.close()
-                self.retired += 1
-                self._release_slot(a.slot, prompt=a.req.prompt)
-                if tel:
-                    tel.note_retire(a.req.rid, a.slot)
+                tel.note_chunk(rid, a.slot, c, t0, tel.clock(), base=base,
+                               final=False)
+            return
+        self._admitting.popleft()
+        if not tel:
+            self._emit_first_token(a, int(tok0), 0.0)
+            return
+        with tel.span(rid, "serve.readback"):
+            tok0 = int(tok0)
+        t_tok = tel.clock()
+        tel.note_chunk(rid, a.slot, c, t0, t_tok, base=base, final=True)
+        with tel.span(rid, "serve.emit") as sp:
+            sp.set(tokens=int(a.resume is None),
+                   retired=self._emit_first_token(a, tok0, t_tok))
+
+    def _chunk_inputs(self, seg, base, last_in_chunk, slot, final, plen,
+                      max_new, cache_offset):
+        """The chunk program's per-chunk arguments, after the batcher's
+        state, as device arrays."""
+        return (jnp.asarray(seg), jnp.full((1,), base, jnp.int32),
+                jnp.full((1,), last_in_chunk, jnp.int32), jnp.int32(slot),
+                jnp.asarray(final), jnp.int32(plen), jnp.int32(max_new),
+                jnp.int32(cache_offset))
+
+    def _emit_first_token(self, a: _Admission, tok0: int, ts: float) -> int:
+        """Install a finished admission's first token: arm the slot for
+        decode, or retire the request at admission.  Returns the number
+        of requests retired (0 or 1)."""
+        tel = self._telemetry
+        # drafter history = every token the model has consumed
+        # (prompt + first sampled token); invariant len == pos + 1.
+        self._history[a.slot] = [int(t) for t in a.req.prompt] + [tok0]
+        self._accept_ewma[a.slot] = 1.0
+        self._probe_at[a.slot] = 0
+        self._probe_gap[a.slot] = 0
+        self._ng_idx[a.slot].clear()
+        self._ng_done[a.slot] = 0
+        if a.resume is not None:
+            # first token already reached the consumer before the
+            # preemption: arm the suppressed-output replay instead.
+            replay = a.resume.pos - a.plen
+            self._slot_req[a.slot] = a.req
+            self._host_pos[a.slot] = a.plen
+            self._host_last_tok[a.slot] = tok0
+            self._host_remaining[a.slot] = a.resume.remaining + replay
+            self._replay_skip[a.slot] = replay + a.resume.skip
+            self.resumes += 1
+            self.recompute_resumes += 1
+            if tel:
+                tel.note_resume(a.req.rid, a.slot, "recompute")
+            return 0
+        a.req.out.Push(tok0)
+        if tel:
+            tel.note_first_token(a.req.rid, a.slot, ts, pos=a.plen)
+        if a.req.max_new > 1 and a.plen < self.max_seq - 1:
+            self._slot_req[a.slot] = a.req
+            self._host_pos[a.slot] = a.plen
+            self._host_last_tok[a.slot] = tok0
+            self._host_remaining[a.slot] = a.req.max_new - 1
+            return 0
+        a.req.out.close()                  # retired at admission
+        self.retired += 1
+        self._release_slot(a.slot, prompt=a.req.prompt)
+        if tel:
+            tel.note_retire(a.req.rid, a.slot)
+        return 1
 
     def _release_slot(self, slot: int,
                       prompt: Optional[np.ndarray] = None,
@@ -1417,11 +1536,10 @@ class ContinuousBatcher:
         if self._telemetry:
             self._telemetry.note_preempt(r.rid, slot, pos, "recompute")
 
-    def _grow_slot(self, slot: int) -> bool:
+    def _grow_slot(self, slot: int) -> int:
         """Ensure every group holds a WRITABLE page for the slot's next
         decode write; preempts other slots when the pool is dry
-        (self-preempts as a last resort).  Returns False iff the slot
-        was preempted.
+        (self-preempts as a last resort).
 
         Two cases need pages: the write position crosses into an
         unallocated logical page (plain lazy growth), or it lands inside
@@ -1429,8 +1547,9 @@ class ContinuousBatcher:
         a shared prefix triggers copy-on-write: the page is duplicated
         into a fresh private page and the block table redirected, so the
         cached original stays bit-stable for every other sequence
-        aliasing it."""
+        aliasing it.  Returns the pages it took."""
         nxt = self._host_pos[slot]             # position decode writes next
+        taken = 0
 
         def take_one(name: str) -> Optional[List[int]]:
             got = self._alloc_evict(name, 1)
@@ -1452,7 +1571,8 @@ class ContinuousBatcher:
             while len(pages) < need:
                 got = take_one(g.name)
                 if got is None:
-                    return False
+                    return taken
+                taken += 1
                 pages.append(got[0])
                 self.block_tab[g.name] = self.block_tab[g.name].at[
                     slot, len(pages) - 1].set(got[0])
@@ -1460,7 +1580,8 @@ class ContinuousBatcher:
             if j < self._slot_nshared[g.name][slot]:
                 got = take_one(g.name)
                 if got is None:
-                    return False
+                    return taken
+                taken += 1
                 self.pools = self._pinned(self.layout.copy_pages(
                     self.pools, g.name, [pages[j]], got))
                 self._alloc[g.name].free([pages[j]])   # drop the shared ref
@@ -1470,7 +1591,7 @@ class ContinuousBatcher:
                 self._slot_nshared[g.name][slot] = j
                 self.cow_copies += 1
         self._note_peak()
-        return True
+        return taken
 
     def _try_resume(self) -> int:
         """Restore preempted requests into free slots, highest priority
@@ -2275,7 +2396,8 @@ class ContinuousBatcher:
             # unwind below must leave the allocator consistent.
             self._fault.check("verify")
             copy_src, copy_dst, rows, cols, vals = xfer
-            with (tel.annotate("serve.verify") if tel else _NULLCTX):
+            with (tel.span(ENGINE_RID, "serve.verify") if tel
+                  else _NULLCTX):
                 (self.pools, self.last_tok, self.pos, self.remaining,
                  self.active, out) = self._verify(
                     self.params, self.pools, self.block_tab,
@@ -2285,13 +2407,15 @@ class ContinuousBatcher:
         except Exception as e:
             self._spec_unwind(swaps)
             raise BatcherFault(e) from e
+        # the ONLY per-step transfer
+        with (tel.span(ENGINE_RID, "serve.readback") if tel else _NULLCTX):
+            out = np.asarray(out)
         dt = time.monotonic() - t0
         t_round = 0.0
         if tel:
             t_round = tel.clock()
             tel.note_verify_round(tel_t0, t_round,
                                   n_drafting=int((n_draft > 0).sum()))
-        out = np.asarray(out)                  # the ONLY per-step transfer
         preds, commit, finished = out[:k], out[k], out[k + 1]
         self._spec_resolve(swaps, commit)
         done = 0
@@ -2381,12 +2505,17 @@ class ContinuousBatcher:
         place — and surfaces as ``BatcherFault``; under a
         ``ServeSupervisor`` the in-flight requests are journaled,
         pools rebuilt, and the journal replayed (``recover``)."""
+        tel = self._telemetry
         if self._deadlines_live:
             self._cancel_expired_slots()
         if self.paged and not self.reserve_decode:
-            for slot in range(self.n_slots):
-                if self._slot_req[slot] is not None:
-                    self._grow_slot(slot)
+            if tel:
+                with tel.span(ENGINE_RID, "serve.grow_tables") as sp:
+                    preemptions = self.preemptions
+                    sp.set(pages=self._grow_tables(),
+                           preemptions=self.preemptions - preemptions)
+            else:
+                self._grow_tables()
         if all(r is None for r in self._slot_req):
             return 0
         if self.paged and self.speculate_k:
@@ -2396,13 +2525,12 @@ class ContinuousBatcher:
                 if drafts:       # setup may prune drafts (dry pool)
                     return self._spec_step(drafts, swaps, xfer)
         n_live = sum(1 for r in self._slot_req if r is not None)
-        tel = self._telemetry
         tel_t0 = tel.clock() if tel else 0.0
         t0 = time.monotonic()
         try:
             self._fault.check("step")
-            with (tel.annotate("serve.decode_step", step=self.steps)
-                  if tel else _NULLCTX):
+            with (tel.span(ENGINE_RID, "serve.decode_step", step=self.steps,
+                           n_live=n_live) if tel else _NULLCTX):
                 if self.paged:
                     (self.pools, self.last_tok, self.pos, self.remaining,
                      self.active, out) = self._step(
@@ -2416,14 +2544,37 @@ class ContinuousBatcher:
                         self.remaining, self.active)
         except Exception as e:
             raise BatcherFault(e) from e
+        # the ONLY per-step transfer; the step ends when its tokens are
+        # on the host.
+        with (tel.span(ENGINE_RID, "serve.readback") if tel else _NULLCTX):
+            out = np.asarray(out)
         self._note_rate(time.monotonic() - t0, n_live)
-        t_step = 0.0
         if tel:
             t_step = tel.clock()
             tel.note_decode_step(tel_t0, t_step, n_live)
-        out = np.asarray(out)                  # the ONLY per-step transfer
+            with tel.span(ENGINE_RID, "serve.emit") as sp:
+                pushed, done = self._emit_step(out, t_step)
+                sp.set(tokens=pushed, retired=done)
+        else:
+            done = self._emit_step(out, 0.0)[1]
+        self.steps += 1
+        self.retired += done
+        return done
+
+    def _grow_tables(self) -> int:
+        """Lazy growth before a decode step: every decoding slot's block
+        tables cover its next write position (``_grow_slot``).  Returns
+        the pages taken."""
+        return sum(self._grow_slot(slot) for slot in range(self.n_slots)
+                   if self._slot_req[slot] is not None)
+
+    def _emit_step(self, out: np.ndarray, ts: float) -> Tuple[int, int]:
+        """Hand a decode step's tokens (``out``: tokens, finished flags)
+        to their requests' streams, stamped ``ts``, and retire the
+        finished ones.  Returns (tokens pushed, requests retired)."""
+        tel = self._telemetry
         toks, finished = out[0], out[1]
-        done = 0
+        pushed = done = 0
         for i, r in enumerate(self._slot_req):
             if r is None:
                 continue
@@ -2434,9 +2585,10 @@ class ContinuousBatcher:
                 self._replay_skip[i] -= 1
             else:
                 r.out.Push(int(toks[i]))
+                pushed += 1
                 if tel:
                     tel.note_token(
-                        r.rid, i, t_step,
+                        r.rid, i, ts,
                         pos=self._host_pos[i] if self.paged else -1)
             if self.paged:
                 self._host_last_tok[i] = int(toks[i])
@@ -2455,9 +2607,7 @@ class ContinuousBatcher:
                 done += 1
                 if tel:
                     tel.note_retire(r.rid, i)
-        self.steps += 1
-        self.retired += done
-        return done
+        return pushed, done
 
     def run(self, total_requests: int, *, poll_timeout: float = 1.0) -> None:
         """Batcher PE: admit + decode until ``total_requests`` retire.
@@ -2482,6 +2632,7 @@ class ContinuousBatcher:
         no consumer hangs) before it propagates; under a
         ``ServeSupervisor`` the fault propagates as-is and the
         supervisor drives ``recover()``/``fail_inflight``."""
+        tel = self._telemetry
         decodes_since_chunk = 0
         try:
             while self.retired < total_requests:
@@ -2490,7 +2641,13 @@ class ContinuousBatcher:
                 if self._stalled:
                     raise BatcherFault(StallFault(
                         "batcher run loop missed its heartbeat window"))
-                self.admit()
+                if tel:
+                    with tel.span(ENGINE_RID, "serve.admit") as sp:
+                        retired = self.retired    # expired or rejected
+                        sp.set(admitted=self.admit(),
+                               expired_or_rejected=self.retired - retired)
+                else:
+                    self.admit()
                 busy = any(r is not None for r in self._slot_req)
                 if self.paged and self._admitting:
                     if busy and decodes_since_chunk < self.prefill_interleave:
@@ -2507,7 +2664,9 @@ class ContinuousBatcher:
                     continue       # waiting on pages with idle slots:
                                    # admit() above will retry/reject.
                 try:
-                    r = self.requests.Pop(timeout=poll_timeout)
+                    with (tel.span(ENGINE_RID, "serve.idle_wait") if tel
+                          else _NULLCTX):
+                        r = self.requests.Pop(timeout=poll_timeout)
                 except TimeoutError:
                     continue               # re-check; producer may be slow
                 except StreamClosed:

@@ -192,16 +192,12 @@ class StagedTransferEngine:
         return pools
 
     def stats(self) -> Dict[str, Any]:
-        # canonical names first; ``staged_*`` kept one release as
-        # aliases (see the counter-name mapping in docs/serving.md).
         return {"gathers": self.gathers,
                 "scatters": self.scatters,
                 "d2h_bytes": self.d2h_bytes,
                 "h2d_bytes": self.h2d_bytes,
                 "gather_seconds": self.gather_s,
-                "scatter_seconds": self.scatter_s,
-                "staged_gathers": self.gathers,
-                "staged_scatters": self.scatters}
+                "scatter_seconds": self.scatter_s}
 
 
 class _T1Entry:

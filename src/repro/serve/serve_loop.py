@@ -311,7 +311,8 @@ def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
     def step_fn(params, pools, block_tab, last_tok, pos, remaining, active):
         logits, new_pools = forward(params, pools, block_tab, last_tok, pos,
                                     active)
-        nxt = jnp.argmax(logits[:, -1], -1).astype(i32)
+        with jax.named_scope("logits"):
+            nxt = jnp.argmax(logits[:, -1], -1).astype(i32)
         nxt = jnp.where(active, nxt, last_tok)
         pos = jnp.where(active, pos + 1, pos)
         remaining = jnp.where(active, remaining - 1, remaining)
@@ -445,7 +446,8 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int, max_seq: int,
             fcfg, params, {"tokens": tokens}, mode="chunk", cache=cache,
             pos=pos0, last_pos=last_in_chunk,
             cache_offset=jnp.broadcast_to(cache_offset, (1,)))
-        tok0 = jnp.argmax(logits[0, -1], -1).astype(i32)
+        with jax.named_scope("logits"):
+            tok0 = jnp.argmax(logits[0, -1], -1).astype(i32)
         # final chunk installs the slot's decode state; non-final chunks
         # scatter-drop (idx == n_slots) and leave every vector untouched.
         idx = jnp.where(is_final, slot_idx, n_slots)

@@ -30,16 +30,21 @@ request's latency go?".  This module is that answer, with three layers:
   The supervisor/recovery path emits events under the same rid, so a
   replayed request's trace stitches to its original.
 
-* ``ServeTelemetry.annotate`` — ``jax.profiler``
-  ``TraceAnnotation``/``StepTraceAnnotation`` context managers around
-  the three jitted serving steps (chunk prefill / decode / verify), so
-  device profiles line up with the host spans.  The import is lazy and
+* ``ServeTelemetry.span`` — one context manager for a span of the
+  serving thread's host work (admission, table growth, a program's
+  dispatch, its readback, token emission, the idle wait).  It records
+  the ``Tracer`` ``"X"`` event and, with ``profile`` on, opens a
+  ``jax.profiler`` ``TraceAnnotation`` (``StepTraceAnnotation`` for a
+  step) of the same name whose ``t`` argument is the ``Tracer``'s stamp
+  at entry: every program span in a device profile carries its time on
+  the program's clock, so the offset between the two clocks can be
+  fitted from the spans themselves.  The import is lazy and
   failure-tolerant: this module stays stdlib-only.
 
 Everything here is zero-dependency (stdlib only); the hot-path contract
 is that a disabled batcher (``telemetry=None``) pays a single ``if``
 per instrumentation point and an enabled one pays two clock reads and
-a couple of list/dict operations per step.
+one ``Tracer`` append per span (plus the annotation while profiling).
 
 The shared percentile helpers (``percentile`` / ``percentiles``) also
 back the bench harness (``benchmarks/run.py``), replacing its inline
@@ -50,7 +55,6 @@ raw samples, matching numpy's default method.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import json
 import math
 import threading
@@ -61,7 +65,7 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional,
 __all__ = [
     "percentile", "percentiles", "DEFAULT_TIME_BUCKETS",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "Tracer", "ServeTelemetry", "MetricsServer",
+    "Tracer", "Span", "ServeTelemetry", "MetricsServer",
     "render_labels", "validate_exposition", "parse_exposition",
     "ENGINE_RID",
 ]
@@ -527,7 +531,6 @@ class Tracer:
 
 # --- jax.profiler bridge (lazy; stdlib fallback) ---------------------------------------
 
-_NULLCTX = contextlib.nullcontext()
 _PROFILER: Any = None                     # None = untried, False = absent
 
 
@@ -540,6 +543,47 @@ def _jax_profiler():
         except Exception:                      # jax absent/broken: degrade
             _PROFILER = False
     return _PROFILER
+
+
+class Span:
+    """One ``ServeTelemetry.span``: stamps its entry on the telemetry
+    clock, annotates the device profile while profiling, and records the
+    ``Tracer`` event at exit."""
+
+    __slots__ = ("tel", "rid", "name", "step", "args", "t0", "ann")
+
+    def __init__(self, tel: "ServeTelemetry", rid: int, name: str,
+                 step: Optional[int], args: Dict[str, Any]):
+        self.tel, self.rid, self.name = tel, rid, name
+        self.step, self.args = step, args
+        self.t0 = 0.0
+        self.ann: Any = None
+
+    def __enter__(self) -> "Span":
+        self.t0 = self.tel.clock()
+        prof = _jax_profiler() if self.tel.profile else None
+        if prof:
+            if self.step is None:
+                self.ann = prof.TraceAnnotation(self.name, t=self.t0,
+                                                **self.args)
+            else:
+                self.ann = prof.StepTraceAnnotation(
+                    self.name, step_num=self.step, t=self.t0, **self.args)
+            self.ann.__enter__()
+        return self
+
+    def set(self, **counts: Any) -> None:
+        self.args.update(counts)
+        if self.ann is not None:
+            self.ann.set_metadata(**counts)
+
+    def __exit__(self, *exc) -> None:
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        tracer = self.tel.tracer
+        if tracer is not None:
+            tracer.span(self.rid, self.name, self.t0, self.tel.clock(),
+                        **self.args)
 
 
 # --- the serving telemetry facade ------------------------------------------------------
@@ -575,11 +619,15 @@ class ServeTelemetry:
                        "gap between consecutive streamed tokens of one "
                        "request")
         self.h_chunk = h("serve_prefill_chunk_seconds",
-                         "one chunked-prefill jit call")
+                         "one chunked-prefill call, inputs and dispatch "
+                         "through the readback of a final chunk's first "
+                         "token")
         self.h_step = h("serve_decode_step_seconds",
-                        "one batched decode jit call")
+                        "one batched decode call, dispatch through "
+                        "readback")
         self.h_verify = h("serve_verify_round_seconds",
-                          "one speculative verify jit call")
+                          "one speculative verify call, dispatch through "
+                          "readback")
         self.h_spill = h("serve_spill_seconds",
                          "preemption spill (staged gather) per request")
         self.h_restore = h("serve_restore_seconds",
@@ -641,25 +689,16 @@ class ServeTelemetry:
         if self.tracer is not None:
             self.tracer.event(rid, name, **args)
 
-    def span(self, rid: int, name: str, t0: float, t1: float,
-             **args: Any) -> None:
-        if self.tracer is not None:
-            self.tracer.span(rid, name, t0, t1, **args)
-
-    def annotate(self, name: str, step: Optional[int] = None):
-        """``jax.profiler`` annotation around a jitted call — a
-        ``StepTraceAnnotation`` when ``step`` is given (device profile
-        rows line up with the host decode-step spans), else a plain
-        ``TraceAnnotation``.  No-op context when profiling is off or
-        jax is unavailable."""
-        if not self.profile:
-            return _NULLCTX
-        prof = _jax_profiler()
-        if not prof:
-            return _NULLCTX
-        if step is None:
-            return prof.TraceAnnotation(name)
-        return prof.StepTraceAnnotation(name, step_num=step)
+    def span(self, rid: int, name: str, step: Optional[int] = None,
+             **args: Any) -> "Span":
+        """A span of host work, as a context manager: the ``Tracer``
+        ``"X"`` event ``name`` from entry to exit with ``args``, and,
+        with ``profile`` on, a ``jax.profiler`` annotation of the same
+        name around the work (a ``StepTraceAnnotation`` numbered
+        ``step`` when it is given) carrying ``args`` and ``t``, the
+        ``Tracer``'s stamp at entry.  ``Span.set`` adds the counts of
+        the work done, known only at its end, to both."""
+        return Span(self, rid, name, step, args)
 
     # -- lifecycle hooks (called by the batcher stack) ------------------------------
 

@@ -67,3 +67,45 @@ def test_cache_defaults_to_checkout_dir_which_git_ignores():
             os.remove(path)
     with open(os.path.join(CHECKOUT, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
+
+
+_SCOPED = r'''
+import sys
+import jax, jax.numpy as jnp
+from repro.serve.batching import _compiled_ops
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+def f(x):
+    with jax.named_scope(sys.argv[2]):
+        return jnp.tanh(x @ x) * 1.5
+f.__name__ = "scoped_" + sys.argv[3]
+g = jax.jit(f)
+x = jnp.ones((8, 8))
+g(x).block_until_ready()
+loaded = g.lower(x).compile().as_text()
+_, ops = _compiled_ops(g, (x,))
+print("LOADED", [s for s in ("alpha", "beta") if s in loaded])
+print("OPS", sorted({s for p in ops.values() for s in ("alpha", "beta")
+                     if s in p.split("/")}))
+'''
+
+
+def test_compiled_ops_read_the_program_s_own_scopes(tmp_path):
+    """The executable a call loads from the persistent cache may carry
+    the scopes of an older version of the program (JAX keeps metadata
+    out of the key); the op map a profile is read by does not."""
+    tag = uuid.uuid4().hex
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    outs = []
+    for scope in ("alpha", "beta"):
+        r = subprocess.run([sys.executable, "-c", _SCOPED,
+                            str(tmp_path / "cache"), scope, tag], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        outs.append(r.stdout)
+    assert "LOADED ['alpha']" in outs[0] and "OPS ['alpha']" in outs[0]
+    assert "LOADED ['alpha']" in outs[1]          # stale, from the cache
+    assert "OPS ['beta']" in outs[1]

@@ -367,7 +367,7 @@ def test_int8_preempt_spill_dtype_and_bit_identical_resume():
         assert d["k"] == np.int8 and d["v"] == np.int8
         assert d["k_scale"] == jnp.bfloat16 and d["v_scale"] == jnp.bfloat16
     x = bat._xfer.stats()
-    assert x["staged_gathers"] > 0 and x["staged_scatters"] > 0
+    assert x["gathers"] > 0 and x["scatters"] > 0
 
 
 # --- recompute-vs-restore policy -------------------------------------------------------

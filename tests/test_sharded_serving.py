@@ -196,7 +196,7 @@ scfg = dataclasses.replace(base, speculate_k=4, speculate_ngram=1,
 s, b = serve(scfg, reps, [16, 16])
 assert s == u, (u, s)
 sp = b.stats()["speculation"]
-assert sp["drafted"] > 0 and sp["verify_steps"] > 0, sp
+assert sp["tokens_drafted"] > 0 and sp["verify_rounds"] > 0, sp
 f_u, _ = serve(base, reps, [10, 10])
 fcfg = dataclasses.replace(base, decode_flash=True, mesh_shape=(1, 2))
 f_s, _ = serve(fcfg, reps, [10, 10])
@@ -298,7 +298,8 @@ print("BUILT-SHARDED", err)
 @pytest.mark.multidevice
 def test_launch_cli_mesh_banner():
     """--mesh end to end through the CLI: the banner surfaces the mesh
-    shape, per-shard pool bytes, and the collective counts."""
+    shape and the per-shard pool bytes (no collective counts: the static
+    ones derived from the model shape are gone)."""
     code = '''
 from repro.launch.serve import main
 main(["--arch", "minitron-4b", "--smoke", "--page-size", "8",
@@ -307,4 +308,4 @@ main(["--arch", "minitron-4b", "--smoke", "--page-size", "8",
 '''
     out = check_mesh(code, (1, 2))
     assert "mesh: 1x2" in out and "tp=2" in out
-    assert "B/shard" in out and "psum" in out
+    assert "B/shard" in out and "B total" in out and "psum" not in out

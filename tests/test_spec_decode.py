@@ -117,8 +117,9 @@ def test_spec_bit_identical_across_layouts(arch, extra):
                      max_seq=48)
     assert spec == plain
     st = bat.stats()["speculation"]
-    assert st["drafted"] > 0, "repetitive workload must actually draft"
-    assert st["drafted"] == st["accepted"] + st["rolled_back"]
+    assert st["tokens_drafted"] > 0, "repetitive workload must actually draft"
+    assert st["tokens_drafted"] == (st["tokens_accepted"]
+                                    + st["tokens_rolled_back"])
     assert bat.total_used_pages() == 0
     _check_allocators(bat)
 
@@ -162,12 +163,12 @@ def test_forced_rejection_rolls_back_and_self_disables(model):
     prod.join()
     assert [drain(r) for r in reqs] == plain
     st = bat.stats()["speculation"]
-    assert st["rolled_back"] > 0
+    assert st["tokens_rolled_back"] > 0
     # EWMA 1.0 -> 0.5 -> 0.25 < 0.3: at most 3 drafting rounds per slot
     # before self-disable (garbage may accidentally match argmax once,
     # allow slack), so rollbacks are bounded, not O(steps).
-    assert st["verify_steps"] <= 10
-    assert bat.steps > st["verify_steps"], "plain path must take over"
+    assert st["verify_rounds"] <= 10
+    assert bat.steps > st["verify_rounds"], "plain path must take over"
     _check_allocators(bat)
 
 
@@ -253,7 +254,7 @@ def test_spec_deadline_expiry_frees_everything(model):
         drain(dying)
     assert len(ei.value.tokens) >= 1       # partial prefix delivered
     assert bat.stats()["expired"] == 1
-    assert bat.stats()["speculation"]["accepted"] > 0
+    assert bat.stats()["speculation"]["tokens_accepted"] > 0
     assert bat.total_used_pages() == 0
     _check_allocators(bat)
 
@@ -285,7 +286,7 @@ def test_prefix_rehit_unaffected_by_speculating_sharer(model):
     assert spec[1] == spec[0] and spec[2] == spec[0]
     st = bat.stats()
     assert st["prefix_hits"] >= 2
-    assert st["speculation"]["drafted"] > 0
+    assert st["speculation"]["tokens_drafted"] > 0
     _check_allocators(bat)
 
 
@@ -313,7 +314,7 @@ def test_verify_fault_unwinds_scratch_before_dying(model):
     for r in reqs:
         with pytest.raises(RequestErrored):
             drain(r, timeout=2.0)
-    assert bat.stats()["speculation"]["accepted"] > 0
+    assert bat.stats()["speculation"]["tokens_accepted"] > 0
     _check_allocators(bat)
 
 
@@ -337,5 +338,5 @@ def test_supervised_recovery_from_verify_fault_is_bit_identical(model):
     sup.run(len(reqs))
     assert [drain(r) for r in reqs] == plain
     assert sup.report.restarts >= 1
-    assert bat.stats()["speculation"]["accepted"] > 0
+    assert bat.stats()["speculation"]["tokens_accepted"] > 0
     _check_allocators(bat)

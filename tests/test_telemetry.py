@@ -471,15 +471,17 @@ def test_stats_alias_keys(model):
     assert len(drain(req)) == 10
     st = bat.stats()
     sp = st["speculation"]
-    assert sp["drafted"] == sp["tokens_drafted"]
-    assert sp["accepted"] == sp["tokens_accepted"]
-    assert sp["rolled_back"] == sp["tokens_rolled_back"]
-    assert sp["verify_steps"] == sp["verify_rounds"]
+    # the aliases kept for one release are gone; only canonical names.
+    for alias in ("drafted", "accepted", "rolled_back", "verify_steps"):
+        assert alias not in sp, alias
+    assert sp["tokens_drafted"] == (sp["tokens_accepted"]
+                                    + sp["tokens_rolled_back"])
+    assert sp["verify_rounds"] >= 1
     tr = st["transfers"]
-    assert tr["staged_gathers"] == tr["gathers"] >= 1
-    assert tr["staged_scatters"] == tr["scatters"] >= 1
+    assert "staged_gathers" not in tr and "staged_scatters" not in tr
+    assert tr["gathers"] >= 1 and tr["scatters"] >= 1
     assert tr["gather_seconds"] >= 0 and tr["scatter_seconds"] >= 0
-    # the registry's canonical series agree with the alias'd dicts.
+    # the registry's series agree with the stats() dicts.
     samples = validate_exposition(tel.render_prometheus())
     assert samples["serve_transfer_gathers_total"] == tr["gathers"]
     assert (samples["serve_spec_verify_rounds_total"]
@@ -526,6 +528,155 @@ def test_heartbeat_injectable_clock():
     fake[0] = 6.0
     assert hb.dead() == ["w0"]             # silent past the timeout
     assert hb.alive() == ["w1"]
+
+
+# --- program spans: Tracer events and profiler annotations -----------------------------
+
+
+def _host_annotations(log_dir):
+    """(name, stats) of every ``serve.*`` host event in a recorded
+    profile."""
+    import glob
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb"))[-1]
+    out = []
+    for p in ProfileData.from_file(path).planes:
+        for ln in p.lines:
+            for e in ln.events:
+                if e.name.startswith("serve."):
+                    out.append((e.name, dict(e.stats)))
+    return out
+
+
+def _profiled(log_dir, fn):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_annotations(log_dir)
+
+
+def test_span_tracer_event_and_annotation(tmp_path):
+    """``span`` records the Tracer "X" event with its args and the counts
+    set at its end; with ``profile`` on it also writes a profiler
+    annotation of the same name carrying the Tracer's entry stamp."""
+    tel = ServeTelemetry(clock=_tick_clock(), profile=True)
+
+    def work():
+        with tel.span(3, "serve.unit", step=7, n_live=2) as sp:
+            sp.set(tokens=5)
+
+    anns = _profiled(tmp_path / "on", work)
+    (ev,) = tel.tracer.events()
+    assert ev["name"] == "serve.unit" and ev["ph"] == "X"
+    assert ev["rid"] == 3 and ev["dur"] > 0
+    assert ev["args"] == {"n_live": 2, "tokens": 5}
+    ((name, st),) = anns
+    assert name == "serve.unit"
+    assert st["t"] == pytest.approx(ev["ts"], abs=1e-9)
+    assert st["n_live"] == 2 and st["tokens"] == 5 and st["step_num"] == 7
+
+    # profile off: the Tracer event only; trace off too: nothing at all.
+    quiet = ServeTelemetry(clock=_tick_clock())
+    silent = ServeTelemetry(clock=_tick_clock(), trace=False)
+
+    def off():
+        with quiet.span(3, "serve.unit"):
+            pass
+        with silent.span(3, "serve.unit") as sp:
+            sp.set(tokens=1)
+
+    assert _profiled(tmp_path / "off", off) == []
+    assert [e["name"] for e in quiet.tracer.events()] == ["serve.unit"]
+    assert silent.tracer is None
+
+
+def test_telemetry_off_writes_no_span(model, tmp_path):
+    """A batcher without telemetry serves without writing a single
+    program annotation into a running profile."""
+    cfg, params = model
+    pcfg = dataclasses.replace(cfg, kv_page_size=PAGE, prefill_chunk=PAGE,
+                               prefix_cache=True)
+    bat = ContinuousBatcher(pcfg, params, n_slots=2, max_seq=48,
+                            queue_depth=8)
+    req = Request(rid=0, prompt=_motif_prompt(11), max_new=4)
+
+    def serve():
+        bat.submit(req)
+        bat.run(1)
+
+    assert _profiled(tmp_path, serve) == []
+    assert len(drain(req)) == 4
+
+
+def test_profiling_stats_do_not_keep_the_batcher(model):
+    """``stats()["programs"]`` (profiling) compiles the step programs'
+    op map when read, and a kept snapshot does not hold the batcher, so
+    its device state is freed with it."""
+    import gc
+    import weakref
+    cfg, params = model
+    pcfg = dataclasses.replace(cfg, kv_page_size=PAGE, prefill_chunk=PAGE)
+    bat = ContinuousBatcher(pcfg, params, n_slots=2, max_seq=48,
+                            queue_depth=8,
+                            telemetry=ServeTelemetry(profile=True))
+    req = Request(rid=0, prompt=_motif_prompt(11), max_new=4)
+    bat.submit(req)
+    bat.run(1)
+    assert len(drain(req)) == 4
+    before, after = bat.stats(), bat.stats()
+    ops = after["programs"]["jit_step_fn"]
+    assert any("/attn_read/" in p for p in ops.values())
+    gone = weakref.ref(bat)
+    del bat
+    gc.collect()
+    assert gone() is None
+    assert dict(before["programs"]) == {}
+
+
+def test_step_spans_end_after_readback(model):
+    """The decode_step and final prefill_chunk spans (and the histograms
+    they feed) end after their readback, and the tokens they produced are
+    stamped at that end: they time the step as the host sees it, not
+    its dispatch."""
+    cfg, params = model
+    pcfg = dataclasses.replace(cfg, kv_page_size=PAGE, prefill_chunk=PAGE)
+    clk = _tick_clock()
+    tel = ServeTelemetry(clock=clk)
+    bat = ContinuousBatcher(pcfg, params, n_slots=2, max_seq=48,
+                            queue_depth=8, clock=clk, telemetry=tel)
+    reqs = [Request(rid=i, prompt=_motif_prompt(12 + i), max_new=5)
+            for i in range(2)]
+    for r in reqs:
+        bat.submit(r)
+    bat.run(len(reqs))
+    assert [len(drain(r)) for r in reqs] == [5, 5]
+    evs = tel.tracer.events()
+    end = lambda e: e["ts"] + e["dur"]            # noqa: E731
+    readback = None
+    steps = chunks = 0
+    for e in evs:
+        if e["name"] == "serve.readback":
+            readback = e
+        elif e["name"] in ("decode_step", "prefill_chunk"):
+            if e["name"] == "prefill_chunk" and not e["args"]["final"]:
+                continue
+            assert readback is not None
+            assert e["ts"] < readback["ts"]
+            assert end(readback) <= end(e) + 1e-9
+            made = [t for t in evs if t["name"] in ("token", "first_token")
+                    and t["ts"] == pytest.approx(end(e), abs=1e-9)]
+            assert made, e
+            steps += e["name"] == "decode_step"
+            chunks += e["name"] == "prefill_chunk"
+            readback = None
+    assert steps == tel.h_step.count and chunks == 2
+    assert tel.h_step.sum == pytest.approx(
+        sum(e["dur"] for e in evs if e["name"] == "decode_step"))
 
 
 # --- zero-perturbation: telemetry must not change decode -------------------------------
