@@ -7,7 +7,8 @@
 One chip.  minitron-4b at its published config in bfloat16 (paged KV,
 page 16, prefix cache on, 4 slots, max_seq 2048) serves 8 requests — a
 shared 256-token prefix plus 256-768 unique tokens each, 32 new tokens —
-through the batcher ``repro.launch.serve`` builds.  Then the same config
+through the batcher ``repro.launch.serve`` builds, reading its KV
+through the XLA gather (``decode_flash=False``).  Then the same config
 with ``decode_flash=True`` compiles the Pallas paged decode kernel for
 the chip, and one decode step's logits through it are compared with the
 gather path's on the same prefilled state.
@@ -178,7 +179,8 @@ def one_chip(cfg, seed, clock):
                                   *UNIQUE)
 
     t0, c0 = time.perf_counter(), clock.seconds
-    batcher = build_batcher(cfg, seed=seed, n_slots=SLOTS, max_seq=MAX_SEQ)
+    gcfg = dataclasses.replace(cfg, decode_flash=False)
+    batcher = build_batcher(gcfg, seed=seed, n_slots=SLOTS, max_seq=MAX_SEQ)
     params = batcher.params
     log(f"weights: {describe(cfg)}: {param_bytes(params) / 1e9:.2f} GB "
         f"built in {time.perf_counter() - t0:.1f} s")
@@ -197,7 +199,7 @@ def one_chip(cfg, seed, clock):
     t0, c0 = time.perf_counter(), clock.seconds
     fcfg = dataclasses.replace(cfg, decode_flash=True)
     fast = build_batcher(fcfg, params=params, n_slots=SLOTS, max_seq=MAX_SEQ)
-    held, (kern, gath) = prefilled_logits(fast, (fcfg, cfg),
+    held, (kern, gath) = prefilled_logits(fast, (fcfg, gcfg),
                                           prompts[:SLOTS])
     compare("kernel: Pallas paged decode vs gather path", kern, gath)
     outs_f = serve(fast, prompts[SLOTS:], MAX_NEW, inflight=held)

@@ -83,7 +83,11 @@ class ModelConfig:
     attn_p_bf16: bool = False         # beyond-paper: bf16 probs into PV matmul
     moe_groups: int = 0               # beyond-paper: grouped dispatch (DPxEP)
     decode_seq_shard: bool = False    # beyond-paper: shard decode KV over seq
-    decode_flash: bool = False        # beyond-paper: sq=1 flash decode kernel
+    # decode read through the Pallas kernels: True/False force it on or
+    # off, None lets the platform choose (kernels.platform.
+    # paged_decode_kernel: the paged block-table kernel on a TPU); the
+    # dense-cache decode takes its kernel only when True.
+    decode_flash: Optional[bool] = None
     kv_cache_dtype: str = "bfloat16"  # beyond-paper: "int8" quantized KV
     # paged serving (continuous batcher): page-pool KV with per-slot block
     # tables, pluggable per attention family via models.cache_layouts

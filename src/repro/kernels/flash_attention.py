@@ -328,99 +328,223 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.reshape(b, hq, d)[:, :, None, :]
 
 
-# --- paged decode (block-table gather via scalar prefetch) ----------------------------
+# --- paged decode (block-table DMA over the live pages) -------------------------------
 #
 # The paged KV cache stores (page, kv_head, page_size, d) tiles in one
 # shared pool; a per-sequence block table maps logical page j to its
-# physical page id.  The decode kernel keeps the kv-only sequential grid
-# of ``flash_attention_decode`` but *gathers* its kv blocks through the
-# scalar-prefetched block table: the BlockSpec index map reads
-# ``bt_ref[seq, j]`` to pick which physical page to DMA next, so the
-# dense (b, S, d) cache view is never materialized — pages stream
-# HBM -> VMEM exactly like contiguous blocks would.  Logical pages whose
-# start is past ``pos`` are skipped via ``pl.when`` (their table entries
-# may be unallocated; callers clamp them so the prefetched index is
-# always a fetchable page).
+# physical page id.  The decode read walks, per slot, only the pages
+# that hold its live positions — ``ceil((pos + sq) / page)`` of them,
+# from the first in-window page when the layer has a flat window, the
+# whole window-bounded table on a ring of pages — so its cost follows
+# the context, not the table width.
+#
+# * The grid is one step per slot.  The pools stay in HBM
+#   (``memory_space=ANY``); a loop inside the step fetches compute
+#   blocks of ``P`` pages with one async copy per page, which covers
+#   every KV head at once because ``(hkv, page, d)`` is contiguous in
+#   the pool.
+# * Copies are double-buffered: block j+1 streams while block j is
+#   scored, and a slot's last block prefetches the next live slot's
+#   first, so the pipeline does not drain between slots.  The loop body
+#   is one straight run (start the next copies, wait for this block,
+#   score it) with no branch, so the scheduler may overlap the copies'
+#   issue, one descriptor per page, with the matmuls.  Idle slots (the
+#   first entry their walk reads is unallocated) take no step of the
+#   chain.  The buffer holding the next block is carried across grid
+#   steps in SMEM, so the grid runs sequentially.
+# * ``P`` follows from the bytes of one page across heads, aiming at
+#   about ``_BLOCK_BYTES`` of K (and as much of V) a block, and from the
+#   page size, filling at least one 128-lane row of scores: 8 pages of
+#   32 KiB for 8 heads of 128 in bf16, 32 pages of 4 KiB for one head.
+#   Smaller blocks pay a loop trip and a wait more often; larger ones
+#   score more masked columns past the last live page.
+# * Scores use the online softmax of the kernels above, in f32, with
+#   the matmul operands in the query's dtype.
+
+_BLOCK_BYTES = 128 * 1024
+_LANES = 128
+
+
+def pages_per_block(page_bytes: int, page: int, n_blocks: int) -> int:
+    """Pages per compute block: the power of two nearest below
+    ``_BLOCK_BYTES / page_bytes``, but at least enough pages to fill a
+    128-lane row of scores, and at most the table width ``n_blocks``."""
+    p = max(1, _BLOCK_BYTES // page_bytes, _LANES // page)
+    return min(1 << (p.bit_length() - 1), n_blocks)
+
+
+def _walk(pos, *, sq: int, page: int, n_blocks: int, ppb: int,
+          window: Optional[int], ring: bool):
+    """(first table entry, entries past the last) a slot at ``pos``
+    reads; works on a scalar in the kernel and on the (b,) positions in
+    the wrapper."""
+    if ring:
+        return jnp.zeros_like(pos), jnp.full_like(pos, n_blocks)
+    j1 = jnp.minimum((pos + sq + page - 1) // page, n_blocks)
+    if window is None:
+        return jnp.zeros_like(pos), j1
+    # blocks stay aligned to multiples of ppb entries, so a block's
+    # scale columns are an aligned slice of the slot's row.
+    j0 = jnp.maximum(pos - window + 1, 0) // page
+    return jnp.minimum(j0, j1 - 1) // ppb * ppb, j1
 
 
 def _flash_decode_paged_kernel(*refs, scale: float, window: Optional[int],
                                page: int, hkv: int, group: int, sq: int,
+                               n_blocks: int, n_pages: int, ppb: int,
                                has_base: bool, quantized: bool):
-    """Refs: [pos, bt(, page_base)] prefetch, [q, k, v(, ks, vs)] inputs,
-    o output, (m, l, acc) scratch — optional refs keyed by the static
-    ``has_base``/``quantized`` flags.
+    """Refs: [pos, bt, live, order(, page_base)] prefetch (bt and
+    page_base flattened to (b·n_blocks,); ``order[0]`` the first live
+    slot, ``order[i + 1]`` the live slot after slot i, b for none),
+    [q, k, v(, ks, vs)] inputs — the pools in HBM, the slot's scales as
+    (1, hkv, n·ppb·page) rows — o output, then scratch: the
+    double-buffered page blocks kbuf, vbuf, their DMA semaphores, the
+    (m, l, acc) online-softmax state and the SMEM index of the buffer
+    that holds the next block.  Optional refs are keyed by the static
+    ``has_base`` / ``quantized`` flags.
 
     ``sq`` > 1 is the speculative-verify span: the q block carries
-    sq·group rows (query position-major), row rr belonging to query
-    position ``pos + rr // group`` — each gets its own causal band, so
-    one pass over the block table scores every position of the span."""
-    n_pre = 3 if has_base else 2
-    pos_ref = refs[0]
-    pb_ref = refs[2] if has_base else None
-    q_ref, k_ref, v_ref = refs[n_pre:n_pre + 3]
-    ks_ref = refs[n_pre + 3] if quantized else None
-    vs_ref = refs[n_pre + 4] if quantized else None
-    o_ref = refs[-4]
-    m_scr, l_scr, acc_scr = refs[-3:]
+    sq·group rows per KV head (query position-major), row rr belonging
+    to query position ``pos + rr // group``, each with its own causal
+    band, so one walk over the pages scores every position of the
+    span."""
+    n_pre = 5 if has_base else 4
+    n_in = 5 if quantized else 3
+    pos_ref, bt_ref, live_ref, order_ref = refs[:4]
+    pb_ref = refs[4] if has_base else None
+    q_ref, k_hbm, v_hbm = refs[n_pre:n_pre + 3]
+    ks_ref, vs_ref = refs[n_pre + 3:n_pre + n_in] if quantized else (None,) * 2
+    (o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
+     nxt_ref) = refs[n_pre + n_in:]
 
     i = pl.program_id(0)
-    jk = pl.program_id(1)
-    nk = pl.num_programs(1)
-    pos = pos_ref[i // hkv]
+    n_slots = pl.num_programs(0)
+    cols = ppb * page
+    span = functools.partial(_walk, sq=sq, page=page, n_blocks=n_blocks,
+                             ppb=ppb, window=window, ring=has_base)
 
-    @pl.when(jk == 0)
+    def start(slot, blk, buf):
+        """One copy per page of block ``blk`` of ``slot``, covering every
+        KV head.  Pages past the slot's span repeat its last page (their
+        columns are masked); an unallocated entry inside it reads page
+        n_pages - 1."""
+        j0, j1 = span(pos_ref[slot])
+        for p in range(ppb):
+            j = jnp.minimum(j0 + blk * ppb + p, j1 - 1)
+            src = jnp.minimum(bt_ref[slot * n_blocks + j], n_pages - 1)
+            for pool, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                pltpu.make_async_copy(pool.at[src], dst.at[buf, p],
+                                      sems.at[buf]).start()
+
+    def wait(buf):
+        # a wait counts the destination's bytes: any page will do.
+        for p in range(ppb):
+            for pool, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
+                pltpu.make_async_copy(pool.at[0], dst.at[buf, p],
+                                      sems.at[buf]).wait()
+
+    @pl.when(i == 0)
     def _init():
+        nxt_ref[0] = 0
+
+    @pl.when(live_ref[i] == 0)
+    def _idle():                                   # reads nothing
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[i] != 0)
+    def _live():
+        @pl.when(order_ref[0] == i)
+        def _first():                  # no live slot before it prefetched
+            start(i, 0, nxt_ref[0])
+
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        pos = pos_ref[i]
+        j0, j1 = span(pos)
+        nb_i = (j1 - j0 + ppb - 1) // ppb
+        after = order_ref[i + 1]                   # next live slot, or b
+        cdt = q_ref.dtype
+        rows = sq * group
+        qs = [q_ref[0, h].astype(cdt) for h in range(hkv)]  # (rows, d)
+        # query position of each row (row rr is position pos + rr // group)
+        qpos = pos + (jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+                      // group if sq > 1 else 0)
 
-    # ``page_base`` (ring-of-pages groups): the logical base position of
-    # table entry jk, reconstructed by the caller — negative for slots
-    # never written.  Flat layouts keep the static jk * page base.
-    k_start = pb_ref[i // hkv, jk] if has_base else jk * page
-    active = k_start <= pos + (sq - 1)                # skip future pages
-    if has_base:
-        active &= k_start >= 0                        # skip unwritten slots
-    if window is not None:
-        active &= k_start + page - 1 > pos - window   # skip out-of-window
+        def body(blk, buf):
+            # one straight run, so the scheduler overlaps the next
+            # block's copies with this block's matmuls: the next block
+            # of this slot, else the next live slot's first, else (the
+            # last live slot) its own first again, drained below.
+            last = blk + 1 == nb_i
+            start(jnp.where(last & (after < n_slots), after, i),
+                  jnp.where(last, 0, blk + 1), 1 - buf)
+            wait(buf)
+            score(blk, buf)
+            return 1 - buf
 
-    @pl.when(active)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * scale      # (sq·group, d)
-        k = k_ref[0, 0].astype(jnp.float32)           # (page, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            # int8 pages dequantize in VMEM: per-position bf16 scales.
-            k = k * ks_ref[0, 0].astype(jnp.float32)
-            v = v * vs_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (sq·group, page)
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # row rr of the block is query position pos + rr // group.
-        qpos = pos + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0) // group
-        valid = kpos <= qpos
-        if has_base:
-            valid &= kpos >= 0
-        if window is not None:
-            valid &= kpos > qpos - window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        def score(blk, buf):
+            first = j0 + blk * ppb                     # table entry of col 0
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            if has_base:
+                # ring-of-pages: the logical base of each entry, rebuilt
+                # by the caller; negative for never-written slots and
+                # past the table.
+                base = jnp.zeros((1, cols), jnp.int32)
+                for p in range(ppb):
+                    j = first + p
+                    b_p = pb_ref[i * n_blocks + jnp.minimum(j, n_blocks - 1)]
+                    b_p = jnp.where(j < n_blocks, b_p, -(1 << 30))
+                    base = jnp.where(col // page == p, b_p, base)
+                kpos = base + col % page
+                valid = (kpos >= 0) & (kpos <= qpos)
+            else:
+                kpos = first * page + col
+                valid = (kpos <= qpos) & (kpos < n_blocks * page)
+            if window is not None:
+                valid &= kpos > qpos - window
+            valid = jnp.broadcast_to(valid, (rows, cols))
 
-    @pl.when(jk == nk - 1)
-    def _finalize():
+            def load(x_buf, h):
+                x = x_buf[buf, :, h]                     # (ppb, page, d)
+                return x.reshape(cols, x.shape[-1]).astype(cdt)
+
+            for h in range(hkv):
+                s = jax.lax.dot_general(
+                    qs[h], load(kbuf, h), (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    # int8 pages: per-position scales act on score
+                    # columns (K) and probability columns (V), so pages
+                    # enter the MXU as they are stored.
+                    at = pl.ds(pl.multiple_of(first * page, cols), cols)
+                    ks, vs = (r[0, pl.ds(h, 1), at].astype(jnp.float32)
+                              for r in (ks_ref, vs_ref))
+                    s = s * ks
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                p_ = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m_prev - m_new)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p_, -1, keepdims=True)
+                if quantized:
+                    p_ = p_ * vs
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p_.astype(cdt), load(vbuf, h), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_scr[h] = m_new
+
+        buf = jax.lax.fori_loop(0, nb_i, body, nxt_ref[0])
+        nxt_ref[0] = buf
+
+        @pl.when(after >= n_slots)
+        def _drain():
+            wait(buf)
+
         l = l_scr[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
 
 
 def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -437,26 +561,30 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 
     q: (b, hq, sq, d) — sq == 1 is the plain decode step; sq > 1 is a
     speculative *verify* span whose rows sit at positions
-    pos..pos+sq-1, each with its own causal band (one grid pass over
-    the block table scores all sq positions); k_pages/v_pages:
-    (n_pages, hkv, page, d) shared
-    pools; block_tab: (b, n_blocks) int32 physical page per logical page
-    (unallocated entries are clamped into [0, n_pages) — they are
-    skipped/masked, but the index map still has to name a fetchable
-    page); pos: (b,) int32 position of the first query row.  ``window``
-    applies the per-row (qpos - window, qpos] band on *logical*
-    positions.
+    pos..pos+sq-1, each with its own causal band (one walk over the
+    pages scores all sq positions); k_pages/v_pages: (n_pages, hkv,
+    page, d) shared pools; block_tab: (b, n_blocks) int32 physical page
+    per logical page (entries >= n_pages are unallocated: they may
+    follow a slot's live positions, past which the walk never reads; an
+    unallocated entry inside the walk reads page n_pages - 1, and a slot
+    whose first walked entry is unallocated is idle: it reads nothing
+    and returns zeros); pos: (b,) int32 position of the first query
+    row.  ``window`` applies the per-row (qpos - window,
+    qpos] band on *logical* positions, and the walk starts at the first
+    page inside it.
 
     ``page_base`` (optional, (b, n_blocks) int32): per-entry logical
     base position for ring-of-pages window groups, where table entry j
     holds logical page ``l ≡ j (mod n_blocks)``; negative bases mark
-    never-written slots.  Defaults to the flat ``j * page``.
+    never-written slots.  The walk then covers the whole
+    (window-bounded) table.  Defaults to the flat ``j * page``.
 
     ``k_scale_pages``/``v_scale_pages`` (optional, (n_pages, hkv, page,
-    1) bf16): per-position scales for int8 pools — pages dequantize
-    in VMEM right after the gather, so the dense bf16 view is never
-    materialized in HBM.  Returns (b, hq, sq, d), matching
-    ``ref.paged_attention_ref``.
+    1) bf16): per-position scales for int8 pools — each slot's scales are
+    gathered into one row per KV head and applied in VMEM to the score
+    and probability columns, so no dequantized view is materialized in
+    HBM.  Returns (b, hq, sq, d), matching ``ref.paged_attention_ref``
+    on every position that lies in an allocated page.
     """
     b, hq, sq, d = q.shape
     n_pages, hkv, page, _ = k_pages.shape
@@ -470,57 +598,80 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     quantized = k_scale_pages is not None
 
     n_blocks = block_tab.shape[1]
-    bh = b * hkv
     rows = sq * group
-    # Fold (b, hq, sq, d) position-major into (bh, sq·group, d): block
-    # row rr belongs to query position rr // group, head group rr % group.
-    q3 = (q.reshape(b, hkv, group, sq, d).transpose(0, 1, 3, 2, 4)
-          .reshape(bh, rows, d))
+    ppb = pages_per_block(hkv * page * d * k_pages.dtype.itemsize, page,
+                          n_blocks)
+    # Fold (b, hq, sq, d) position-major into (b, hkv, sq·group, d): row
+    # rr belongs to query position rr // group, head group rr % group.
+    q4 = (q.reshape(b, hkv, group, sq, d).transpose(0, 1, 3, 2, 4)
+          .reshape(b, hkv, rows, d))
     pos_arr = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    bt = jnp.minimum(block_tab.astype(jnp.int32), n_pages - 1)
+    bt = block_tab.astype(jnp.int32)
+    # a slot is live when the first entry its walk reads is allocated:
+    # pages are allocated from the sequence's start and a flat group
+    # frees none while the slot lives (the page of the current position
+    # may not be yet); an idle slot's row is all unallocated.
+    j0, _ = _walk(pos_arr, sq=sq, page=page, n_blocks=n_blocks, ppb=ppb,
+                  window=window, ring=has_base)
+    live = jnp.take_along_axis(bt, j0[:, None], axis=1)[:, 0] < n_pages
+    # order[k]: the first live slot from slot k on, b where there is
+    # none — order[0] the first live slot, order[i + 1] the one after i.
+    idx = jnp.where(live, jnp.arange(b, dtype=jnp.int32), b)
+    order = jax.lax.cummin(jnp.append(idx, jnp.int32(b)), reverse=True)
+    prefetch = [pos_arr, bt.reshape(-1), live.astype(jnp.int32), order]
+    if has_base:
+        prefetch.append(jnp.asarray(page_base, jnp.int32).reshape(-1))
+    inputs = [q4, k_pages, v_pages]
+    cols = ppb * page
+
+    def _qmap(i, *prefs):
+        return (i, 0, 0, 0)
+
+    qspec = pl.BlockSpec((1, hkv, rows, d), _qmap)
+    in_specs = [qspec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    if quantized:
+        # each slot's scales as one row per KV head, by table entry and
+        # padded to whole blocks: a block's scales are a lane slice.
+        width = -(-n_blocks // ppb) * cols
+        bt = jnp.minimum(block_tab, n_pages - 1)
+
+        def scale_rows(sp):
+            g = sp[bt][..., 0].transpose(0, 2, 1, 3)  # (b, hkv, nb, page)
+            g = g.reshape(b, hkv, n_blocks * page)
+            return jnp.pad(g, ((0, 0), (0, 0), (0, width - g.shape[-1])))
+
+        inputs += [scale_rows(k_scale_pages), scale_rows(v_scale_pages)]
+        in_specs += [pl.BlockSpec((1, hkv, width),
+                                  lambda i, *prefs: (i, 0, 0))] * 2
 
     kernel = functools.partial(
         _flash_decode_paged_kernel, scale=scale, window=window, page=page,
-        hkv=hkv, group=group, sq=sq, has_base=has_base, quantized=quantized)
-
-    n_pre = 3 if has_base else 2
-
-    def _qmap(i, jk, *prefs):
-        return (i, 0, 0)
-
-    def _pmap(i, jk, *prefs, h=hkv):
-        return (prefs[1][i // h, jk], i % h, 0, 0)
-
-    in_specs = [pl.BlockSpec((1, rows, d), _qmap),
-                # the paged gather: physical page picked by the block table.
-                pl.BlockSpec((1, 1, page, d), _pmap),
-                pl.BlockSpec((1, 1, page, d), _pmap)]
-    inputs = [q3, k_pages, v_pages]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, 1, page, 1), _pmap),
-                     pl.BlockSpec((1, 1, page, 1), _pmap)]
-        inputs += [k_scale_pages, v_scale_pages]
-
-    prefetch = [pos_arr, bt]
-    if has_base:
-        prefetch.append(jnp.asarray(page_base, jnp.int32))
+        hkv=hkv, group=group, sq=sq, n_blocks=n_blocks, n_pages=n_pages,
+        ppb=ppb, has_base=has_base, quantized=quantized)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=n_pre,                    # pos, bt(, page_base)
-        grid=(bh, n_blocks),
+        num_scalar_prefetch=len(prefetch),
+        grid=(b,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, rows, d), _qmap),
+        out_specs=qspec,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((2, ppb, hkv, page, d), k_pages.dtype),
+            pltpu.VMEM((2, ppb, hkv, page, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((hkv, rows, d), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
 
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, rows, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        # the buffer index and the in-flight copy cross grid steps.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret_mode(interpret),
     )(*prefetch, *inputs)
 

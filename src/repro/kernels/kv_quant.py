@@ -14,8 +14,8 @@ The *paged* int8 KV cache reuses exactly this granularity: each page
 pool carries int8 ``k``/``v`` pages plus bf16 ``k_scale``/``v_scale``
 pages of shape (n_pages, hkv, page, 1) — one scale per (head, position)
 row, matching the (rows, 1) scales emitted here — and
-``flash_attention_decode_paged`` applies them in VMEM right after the
-block-table gather (see ``models/cache_layouts`` and
+``flash_attention_decode_paged`` applies them in VMEM to the score and
+probability columns of each page it copies (see ``models/cache_layouts`` and
 ``models/layers.attention_apply_paged``).
 """
 

@@ -17,6 +17,7 @@ import numpy as np
 from jax import lax
 
 from ..core import datapack
+from ..kernels.platform import paged_decode_kernel
 from ..distributed.sharding import (constrain, gather_parts, part_index,
                                     psum_parts)
 from .params import Decl
@@ -595,26 +596,30 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
 
         kc = k.transpose(0, 2, 1, 3)                     # (b, s, hkv, hd)
         vc = v.transpose(0, 2, 1, 3)
+        # one scatter row per (position, head): a whole (hkv, hd) window
+        # per position would lead XLA to lay the pools out position-major,
+        # and the read kernel's page copies would then pay a relayout of
+        # the layer's whole pool.
+        at = (wp[..., None], jnp.arange(hkv)[None, None, :], wo[..., None])
+
+        def put(pool, rows):
+            return pool.at[at].set(rows.astype(pool.dtype), mode="drop")
+
         new_pages = dict(pages)
         if quantized:
             kq, ks = _kv_quantize(kc)             # int8 + (b,s,hkv,1) scale
             vq, vs = _kv_quantize(vc)
-            new_pages["k"] = pk.at[wp, :, wo].set(kq, mode="drop")
-            new_pages["v"] = pv.at[wp, :, wo].set(vq, mode="drop")
-            new_pages["k_scale"] = pages["k_scale"].at[wp, :, wo].set(
-                ks, mode="drop")
-            new_pages["v_scale"] = pages["v_scale"].at[wp, :, wo].set(
-                vs, mode="drop")
+            new_pages["k"], new_pages["v"] = put(pk, kq), put(pv, vq)
+            new_pages["k_scale"] = put(pages["k_scale"], ks)
+            new_pages["v_scale"] = put(pages["v_scale"], vs)
         else:
-            new_pages["k"] = pk.at[wp, :, wo].set(kc.astype(pk.dtype),
-                                                  mode="drop")
-            new_pages["v"] = pv.at[wp, :, wo].set(vc.astype(pv.dtype),
-                                                  mode="drop")
+            new_pages["k"], new_pages["v"] = put(pk, kc), put(pv, vc)
 
     # --- read ------------------------------------------------------------------
     with jax.named_scope("attn_read"):
         page_base = _ring_page_base(pos, page, n_blocks) if ring else None
-        if cfg.decode_flash and (s == 1 or verify) and cache_offset is None:
+        if (s == 1 or verify) and cache_offset is None \
+                and paged_decode_kernel(cfg):
             # write-then-read through the block-table kernel.  The
             # verify span's writes land before the read, so ring bases key
             # off the span END — entries the span wrote hold NEW logical

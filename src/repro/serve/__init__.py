@@ -21,7 +21,9 @@ leaves the pipeline — applied to inference:
 * ``kernels.flash_attention.flash_attention_decode`` is the sq=1
   decode-specialized attention kernel (kv-only grid, GQA group folded
   into the q block, static skipping of future/out-of-window kv blocks),
-  routed via ``ModelConfig.decode_flash``.
+  routed via ``ModelConfig.decode_flash``; the paged read takes
+  ``flash_attention_decode_paged`` on a TPU
+  (``kernels.platform.paged_decode_kernel``).
 """
 
 from . import (serve_loop, batching, kv_tiers, prefix_cache, resilience,

@@ -162,6 +162,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P_spec
 
 from ..configs.base import ModelConfig
 from ..core.stream import Stream, StreamClosed
+from ..kernels.platform import paged_decode_kernel
 from ..models import registry
 from ..models import params as PP
 from ..models.cache_layouts import get_layout
@@ -492,6 +493,12 @@ class ContinuousBatcher:
         self.preemptions = 0
         self.resumes = 0
         self.peak_pages = 0
+        # decode read (stats()["decode_read"], counted while telemetry is
+        # on): table entries holding each live slot's positions, against
+        # the entries its table has, summed over decode calls (not
+        # verify rounds) and page groups.
+        self.decode_live_pages = 0
+        self.decode_table_pages = 0
         self.preempted_rids: List[int] = []    # observability (tests/benches)
         self._programs: Optional[Dict[str, Dict[str, str]]] = None
         # prefix-cache observability (stats(); all zero when disabled).
@@ -789,6 +796,12 @@ class ContinuousBatcher:
                              "shared": a.shared_pages}
                       for name, a in self._alloc.items()}
         s["shared_pages"] = sum(a.shared_pages for a in self._alloc.values())
+        s["decode_read"] = {
+            "path": ("kernel" if paged_decode_kernel(self.cfg)
+                     and not self.cfg.mla else "gather"),
+            "live_pages": self.decode_live_pages,
+            "table_pages": self.decode_table_pages,
+        }
         s["cow_copies"] = self.cow_copies
         s["prefix_cache"] = self.prefix_cache
         s["transfers"] = self._xfer.stats()
@@ -2525,6 +2538,8 @@ class ContinuousBatcher:
                 if drafts:       # setup may prune drafts (dry pool)
                     return self._spec_step(drafts, swaps, xfer)
         n_live = sum(1 for r in self._slot_req if r is not None)
+        if tel and self.paged:
+            self._count_decode_read()
         tel_t0 = tel.clock() if tel else 0.0
         t0 = time.monotonic()
         try:
@@ -2560,6 +2575,19 @@ class ContinuousBatcher:
         self.steps += 1
         self.retired += done
         return done
+
+    def _count_decode_read(self) -> None:
+        """Add a decode call's read to ``stats()["decode_read"]``, from
+        the host's positions: the table entries holding each live slot's
+        positions 0..pos (a ring group's at most its width; the kernel
+        walks these, less the pages before a flat window), and the
+        table's width."""
+        for name, width in self.n_blocks.items():
+            for i, r in enumerate(self._slot_req):
+                if r is not None:
+                    self.decode_live_pages += self.layout.blocks_for(
+                        name, self._host_pos[i] + 1, self.max_seq)
+                    self.decode_table_pages += width
 
     def _grow_tables(self) -> int:
         """Lazy growth before a decode step: every decoding slot's block

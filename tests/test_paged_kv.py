@@ -20,11 +20,14 @@ import pytest
 from repro import configs
 from repro.configs.base import smoke_variant
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_decode_paged
+from repro.kernels.flash_attention import (flash_attention_decode_paged,
+                                           pages_per_block)
+from repro.kernels.platform import paged_decode_kernel
 from repro.models import registry
 from repro.serve.batching import (ContinuousBatcher, PageAllocator, Request,
                                   drain)
 from repro.serve.serve_loop import greedy_generate, make_paged_logits_step
+from repro.serve.telemetry import ServeTelemetry
 
 
 @pytest.fixture(scope="module")
@@ -506,6 +509,52 @@ def test_paged_logits_step_matches_decode_step_and_kernel(model):
     assert bat._host_last_tok == np.argmax(np.asarray(gath), -1).tolist()
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("flag", [None, True, False])
+def test_paged_decode_read_chosen_by_platform(monkeypatch, backend, flag):
+    """``decode_flash`` forces the read when set; unset, the kernel runs
+    on a TPU and the gather elsewhere, and heads narrower than a lane
+    row keep the gather."""
+    cfg = dataclasses.replace(smoke_variant(configs.get("minitron-4b")),
+                              head_dim=128, decode_flash=flag)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert paged_decode_kernel(cfg) == (
+        flag if flag is not None else backend == "tpu")
+    narrow = dataclasses.replace(cfg, head_dim=32)
+    assert paged_decode_kernel(narrow) == bool(flag)
+
+
+@pytest.mark.parametrize("flag", [None, True])
+def test_decode_read_counts_live_and_table_pages(model, flag):
+    """``stats()["decode_read"]`` names the decode program's read path
+    and, while telemetry is on, sums over decode calls the table entries
+    holding the live slots' positions against the table's width: both
+    grow with every call, the live ones never past the table."""
+    cfg, params = model
+    pcfg = dataclasses.replace(cfg, kv_page_size=8, decode_flash=flag)
+    bat = ContinuousBatcher(pcfg, params, n_slots=2, max_seq=64,
+                            telemetry=ServeTelemetry(trace=False))
+    for i, p in enumerate(_prompts(pcfg, [9, 14])):
+        bat.submit(Request(rid=i, prompt=p, max_new=6))
+    bat.admit()
+    while bat._admitting:
+        bat._prefill_step()
+    read = bat.stats()["decode_read"]
+    assert read == {"path": "kernel" if flag else "gather",
+                    "live_pages": 0, "table_pages": 0}
+    for _ in range(4):
+        bat.step()
+        now = bat.stats()["decode_read"]
+        assert now["live_pages"] > read["live_pages"]
+        assert now["table_pages"] - read["table_pages"] == 2 * 8
+        assert now["live_pages"] <= now["table_pages"]
+        read = now
+    # the slots hold 9 and 14 prompt positions plus 4 decoded: pages of
+    # 8 positions up to each decode position, summed over 4 calls.
+    assert read["live_pages"] == sum(
+        -(-(9 + t + 1) // 8) + -(-(14 + t + 1) // 8) for t in range(4))
+
+
 def test_gqa_paged_matches_dense():
     """True GQA (hkv < hq) through the paged batcher."""
     cfg = dataclasses.replace(smoke_variant(configs.get("minitron-4b")),
@@ -521,28 +570,98 @@ def test_gqa_paged_matches_dense():
 # --- paged decode kernel vs reference -------------------------------------------------
 
 
+def _paged_case(rng, b_pos, hq, hkv, d, page, n_blocks, n_pages, sq=1,
+                ungrown=(), dtype=jnp.float32):
+    """Random pools, q and block tables: each slot holds distinct pages
+    for its positions pos..pos+sq-1; the entries past them are
+    unallocated (>= n_pages).  A position of None is an idle slot: its
+    whole row is unallocated (and its position 0).  Slots in
+    ``ungrown`` leave the page of their last position unallocated, as
+    a table not yet grown for the step leaves it."""
+    kp = jnp.asarray(rng.standard_normal((n_pages, hkv, page, d)), dtype)
+    vp = jnp.asarray(rng.standard_normal((n_pages, hkv, page, d)), dtype)
+    q = jnp.asarray(rng.standard_normal((len(b_pos), hq, sq, d)), dtype)
+    free = list(rng.permutation(n_pages))
+    bt = np.full((len(b_pos), n_blocks), n_pages + 7, np.int32)
+    for i, p in enumerate(b_pos):
+        n = 0 if p is None else (p + sq - 1) // page + 1 - (i in ungrown)
+        bt[i, :n] = [free.pop() for _ in range(n)]
+    pos = [p or 0 for p in b_pos]
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(pos, jnp.int32)
+
+
+def _kernel_tol(out, vp):
+    """What the kernel may differ from the float32 reference by.  In
+    float32, 1e-3.  With bfloat16 operands, as on the chip, the kernel
+    rounds the probabilities to bfloat16 before the PV matmul and its
+    output to bfloat16, each within half an ulp (2**-9 relative), and
+    both errors are at most that share of the largest value (after
+    scaling) it averages: 2**-8 of max |v|, besides float32 rounding."""
+    if out.dtype == jnp.float32:
+        return 1e-3
+    return 2.0 ** -8 * float(jnp.abs(vp.astype(jnp.float32)).max()) + 1e-5
+
+
+# (hq, hkv, d, page, n_blocks, n_pages, positions of the first query,
+# slots left ungrown, dtype): GQA group 3 with contexts ending mid-page,
+# at a page's end and at 0, on 2 KV heads and on 8; an idle slot (no
+# page allocated) between live ones; a slot at a page boundary whose new
+# page is not allocated yet (the table not grown); a full table beside a
+# short one; MQA group 48; a 20-entry table that the pages per block
+# (8 pages of 16 KiB) do not divide; and slots walking one and two
+# blocks of 8 pages of 64 positions, with idle slots first, between and
+# last (the prefetch chain skips them), in float32 and in bfloat16, the
+# chip's operand type.
+_KERNEL_CASES = {
+    "gqa3": (6, 2, 32, 16, 4, 10, [35, 15, 0], (), jnp.float32),
+    "gqa3_8_heads": (24, 8, 32, 16, 4, 10, [35, 15, 0], (), jnp.float32),
+    "idle_slot": (6, 2, 32, 16, 4, 10, [35, None, 17], (), jnp.float32),
+    "ungrown_page": (6, 2, 32, 16, 4, 10, [32, 15], (0,), jnp.float32),
+    "full_table": (6, 2, 32, 16, 4, 10, [63, 20], (), jnp.float32),
+    "mqa48": (48, 1, 32, 16, 4, 10, [35, 0], (), jnp.float32),
+    "blocks_not_dividing": (4, 2, 128, 16, 20, 48, [300, 17, 0], (),
+                            jnp.float32),
+    "gqa3_blocks": (6, 2, 32, 64, 24, 40, [None, 830, 700, None, 100, 5,
+                                           None], (), jnp.float32),
+    "mqa48_blocks": (48, 1, 64, 64, 24, 40, [830, 700, 100, 5], (),
+                     jnp.float32),
+    "gqa3_blocks_bf16": (6, 2, 64, 64, 24, 40, [830, 700, None, 100], (),
+                         jnp.bfloat16),
+    "mqa48_blocks_bf16": (48, 1, 128, 64, 24, 40, [None, 830, 700, 100, 5],
+                          (), jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("sq", [1, 3])
 @pytest.mark.parametrize("window", [None, 24])
-def test_paged_flash_kernel_matches_ref(window):
-    rng = np.random.default_rng(5)
-    b, hq, hkv, d = 3, 8, 2, 32
-    n_pages, page, n_blocks = 10, 16, 4
-    kp = jnp.asarray(rng.standard_normal((n_pages, hkv, page, d)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, hkv, page, d)),
-                     jnp.float32)
-    q = jnp.asarray(rng.standard_normal((b, hq, 1, d)), jnp.float32)
-    # 99 marks unallocated logical pages: skipped/masked, never read for
-    # live positions.
-    bt = jnp.asarray([[3, 1, 7, 99], [0, 5, 99, 99], [8, 2, 4, 6]],
-                     jnp.int32)
-    pos = jnp.asarray([35, 15, 63], jnp.int32)
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+def test_paged_flash_kernel_matches_ref(case, window, sq):
+    (hq, hkv, d, page, n_blocks, n_pages, pos, ungrown,
+     dtype) = _KERNEL_CASES[case]
+    ppb = pages_per_block(hkv * page * d * jnp.dtype(dtype).itemsize, page,
+                          n_blocks)
+    if case == "blocks_not_dividing":
+        assert n_blocks % ppb and pos[0] // page >= ppb
+    if case.endswith(("_blocks", "_blocks_bf16")):
+        assert max(-(-(p + sq) // page) for p in pos if p is not None) > ppb
+    last = n_blocks * page - sq                  # the span stays in the table
+    live = [p is not None for p in pos]
+    pos = [p if p is None else min(p, last) for p in pos]
+    q, kp, vp, bt, pos = _paged_case(np.random.default_rng(5), pos, hq,
+                                     hkv, d, page, n_blocks, n_pages, sq,
+                                     ungrown, dtype)
     out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window)
     gold = ref.paged_attention_ref(q, kp, vp, bt, pos, window=window)
-    assert float(jnp.abs(out - gold).max()) <= 1e-3
+    live = np.asarray(live)
+    assert out.dtype == dtype
+    err = jnp.abs(out.astype(jnp.float32) - gold)[live].max()
+    assert float(err) <= _kernel_tol(out, vp)
+    assert not np.asarray(out)[~live].any()     # an idle slot reads nothing
 
 
+@pytest.mark.parametrize("sq", [1, 3])
 @pytest.mark.parametrize("window", [16, 24])
-def test_paged_flash_kernel_ring_page_base_matches_ref(window):
+def test_paged_flash_kernel_ring_page_base_matches_ref(window, sq):
     """Ring-of-pages window groups: the kernel's per-entry logical base
     (scalar-prefetched ``page_base``) must reproduce the reference's
     reconstructed-position masking, including negative (never-written)
@@ -554,7 +673,7 @@ def test_paged_flash_kernel_ring_page_base_matches_ref(window):
                      jnp.float32)
     vp = jnp.asarray(rng.standard_normal((n_pages, hkv, page, d)),
                      jnp.float32)
-    q = jnp.asarray(rng.standard_normal((b, hq, 1, d)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, hq, sq, d)), jnp.float32)
     bt = jnp.asarray([[3, 1, 7, 0], [2, 5, 99, 99]], jnp.int32)
     pos = jnp.asarray([43, 9], jnp.int32)
     # entry j holds logical page l = cur - ((cur - j) % nbl).
@@ -568,10 +687,13 @@ def test_paged_flash_kernel_ring_page_base_matches_ref(window):
     assert float(jnp.abs(out - gold).max()) <= 1e-3
 
 
+@pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("window", [None, 24])
-def test_paged_flash_kernel_int8_scales_match_ref(window):
+def test_paged_flash_kernel_int8_scales_match_ref(window, qdtype):
     """int8 pools with per-position scale pages: the kernel dequantizes
-    in VMEM and must match the dense dequantize-then-attend oracle."""
+    in VMEM and must match the dense dequantize-then-attend oracle, with
+    the pages entering the matmuls in the query's type (bfloat16 on
+    the chip)."""
     rng = np.random.default_rng(9)
     b, hq, hkv, d = 2, 4, 2, 32
     n_pages, page, n_blocks = 6, 16, 3
@@ -583,14 +705,15 @@ def test_paged_flash_kernel_int8_scales_match_ref(window):
                      jnp.bfloat16)
     vs = jnp.asarray(rng.uniform(0.005, 0.02, (n_pages, hkv, page, 1)),
                      jnp.bfloat16)
-    q = jnp.asarray(rng.standard_normal((b, hq, 1, d)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, hq, 1, d)), qdtype)
     bt = jnp.asarray([[0, 2, 4], [5, 1, 99]], jnp.int32)
     pos = jnp.asarray([40, 20], jnp.int32)
     out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window,
                                        k_scale_pages=ks, v_scale_pages=vs)
     gold = ref.paged_attention_ref(q, kp, vp, bt, pos, window=window,
                                    k_scale_pages=ks, v_scale_pages=vs)
-    assert float(jnp.abs(out - gold).max()) <= 1e-3
+    err = float(jnp.abs(out.astype(jnp.float32) - gold).max())
+    assert err <= _kernel_tol(out, vp.astype(jnp.float32) * vs)
 
 
 @pytest.mark.parametrize("window", [None, 24])

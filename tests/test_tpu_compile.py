@@ -7,18 +7,24 @@ inside a fixture, so importing or collecting this file never loads the
 TPU library, and where no topology can be described every test skips.
 """
 
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
+from repro.configs.base import smoke_variant
 from repro.kernels.flash_attention import (flash_attention_decode,
                                            flash_attention_decode_paged)
 from repro.kernels.ssd_scan import ssd_scan
+from repro.models import params, registry
 from repro.models.cache_layouts import ring_blocks
+from repro.serve.serve_loop import make_paged_decode_step
 
 SLOTS, MAX_SEQ, PAGE = 4, 2048, 16
 
@@ -48,16 +54,16 @@ def _compile(fn, one_chip, *shapes):
 
 
 def _paged(one_chip, hq, hkv, d, kv_dtype, *, window=None, ring=False,
-           scales=False):
-    n_blocks = (ring_blocks(window, PAGE) if ring
-                else MAX_SEQ // PAGE)
-    n_pages = SLOTS * n_blocks
+           scales=False, slots=SLOTS, n_blocks=None, n_pages=None):
+    n_blocks = n_blocks or (ring_blocks(window, PAGE) if ring
+                            else MAX_SEQ // PAGE)
+    n_pages = n_pages or slots * n_blocks
     pool = (n_pages, hkv, PAGE, d)
-    shapes = [((SLOTS, hq, 1, d), jnp.bfloat16), (pool, kv_dtype),
-              (pool, kv_dtype), ((SLOTS, n_blocks), jnp.int32),
-              ((SLOTS,), jnp.int32)]
+    shapes = [((slots, hq, 1, d), jnp.bfloat16), (pool, kv_dtype),
+              (pool, kv_dtype), ((slots, n_blocks), jnp.int32),
+              ((slots,), jnp.int32)]
     if ring:
-        shapes.append(((SLOTS, n_blocks), jnp.int32))
+        shapes.append(((slots, n_blocks), jnp.int32))
     if scales:
         shapes += [((n_pages, hkv, PAGE, 1), jnp.bfloat16)] * 2
 
@@ -78,6 +84,50 @@ def test_paged_decode_compiles_at_minitron_widths(one_chip, kv):
         _paged(one_chip, 24, 8, 128, jnp.bfloat16)
     else:
         _paged(one_chip, 24, 8, 128, jnp.int8, scales=True)
+
+
+@pytest.mark.parametrize("slots, n_blocks, n_pages, hq, hkv", [
+    (16, 256, 1664, 24, 8),       # minitron-4b: max_seq 4096, page 16
+    (64, 128, 8192, 48, 1),       # granite-34b: max_seq 2048, one KV head
+], ids=["minitron", "granite"])
+def test_paged_decode_compiles_at_bench_shapes(one_chip, slots, n_blocks,
+                                               n_pages, hq, hkv):
+    """The benchmark's serving shapes: every slot's block table and
+    position in scalar memory, the pools in HBM."""
+    _paged(one_chip, hq, hkv, 128, jnp.bfloat16, slots=slots,
+           n_blocks=n_blocks, n_pages=n_pages)
+
+
+def test_paged_decode_step_takes_the_kernel_on_tpu(one_chip, monkeypatch):
+    """With ``decode_flash`` unset the platform picks the read: lowered
+    for the described chip (smoke widths, heads of 128 lanes), the paged
+    decode step holds the kernel, and the kernel's op carries the
+    ``attn_read`` scope the device-trace metrics read."""
+    cfg = dataclasses.replace(smoke_variant(configs.get("minitron-4b")),
+                              head_dim=128)
+    assert cfg.decode_flash is None
+    # the described chip is not the default backend of this process.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, max_seq, n_pages = 4, 256, 64
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    i32 = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    args = (shaped(registry.abstract(cfg)),
+            shaped(params.abstract_params(registry.paged_cache_decls(
+                cfg, n_pages, PAGE))),
+            {"kv": jax.ShapeDtypeStruct((slots, max_seq // PAGE), jnp.int32,
+                                        sharding=one_chip)},
+            i32, i32, i32,
+            jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip))
+    text = make_paged_decode_step(cfg, max_seq, PAGE).lower(
+        *args).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls, "the paged decode step holds no kernel"
+    assert all(re.search(r'op_name="[^"]*attn_read', ln) for ln in calls)
 
 
 def test_paged_decode_compiles_at_gemma3_widths_with_ring(one_chip):
