@@ -33,6 +33,17 @@ class ModelConfig:
     qk_norm: bool = False
     rope_theta: float = 1e4
     rope_theta_global: float = 1e6     # gemma3 global layers
+    # YaRN rope scaling of the MLA rope head (deepseek-v2's rope_scaling
+    # group); factor 0 keeps plain RoPE.  The frequencies blend theta's
+    # with theta's / factor over the correction range that beta_fast and
+    # beta_slow give at the original context; the MLA softmax scale
+    # gains yarn_mscale(factor, mscale_all_dim)^2 (models.layers).
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_max: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     sliding_window: Optional[int] = None
     local_global_pattern: int = 0      # N local layers per 1 global (gemma3)
 
@@ -49,7 +60,11 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0
     first_dense_layers: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25     # training only: serving never drops
+    # the published key: True renormalises the top-k gates (a softmax
+    # over the k chosen logits); False keeps the top-k of a softmax over
+    # every expert as they are (deepseek-v2).
+    norm_topk_prob: bool = True
 
     # SSM (mamba2)
     ssm_state: int = 0

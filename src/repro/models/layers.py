@@ -9,6 +9,7 @@ Stacked leading dims (for scan-over-layers) are threaded via ``stack``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -34,18 +35,71 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return ((xf * lax.rsqrt(var + eps)) * (1.0 + w.astype(F32))).astype(x.dtype)
 
 
-def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., s, h, d) or (..., s, d); pos: (s,) or (b, s)."""
+def rope(x: jnp.ndarray, pos: jnp.ndarray, theta: float,
+         freqs: Optional[np.ndarray] = None, mscale: float = 1.0
+         ) -> jnp.ndarray:
+    """x: (..., s, h, d) or (..., s, d); pos: (s,) or (b, s).  ``freqs``
+    (d/2,) replaces theta's frequencies and ``mscale`` scales cos and
+    sin (YaRN, ``yarn_freqs``)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
-    ang = pos[..., None].astype(F32) * freqs          # (..., s, half)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(half, dtype=F32) / half)
+    ang = pos[..., None].astype(F32) * jnp.asarray(freqs, F32)
+    cos, sin = jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
     if x.ndim - cos.ndim == 2:                        # head axis present
         cos, sin = cos[..., None, :], sin[..., None, :]
     x1, x2 = x[..., :half].astype(F32), x[..., half:].astype(F32)
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention magnitude correction (deepseek-v2's
+    ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(d: int, theta: float, factor: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies of a head of width ``d`` (deepseek-v2's
+    ``DeepseekV2YarnRotaryEmbedding``): theta's own frequencies where a
+    pair turns more than ``beta_fast`` times over the original context,
+    theta's / ``factor`` where it turns fewer than ``beta_slow`` times,
+    and a linear blend between."""
+    def corr_dim(rot):
+        return d * math.log(original_max / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(corr_dim(beta_fast)), 0)
+    hi = min(math.ceil(corr_dim(beta_slow)), d - 1)
+    extra = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    ramp = np.clip((np.arange(d // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    mask = 1.0 - ramp                   # 1: keep theta's frequency
+    return (extra / factor * (1 - mask) + extra * mask).astype(np.float32)
+
+
+def mla_rope(cfg, x: jnp.ndarray, pos: jnp.ndarray) -> jnp.ndarray:
+    """RoPE over the MLA rope head: theta's, or YaRN's where the config
+    scales it."""
+    if not cfg.rope_yarn_factor:
+        return rope(x, pos, cfg.rope_theta)
+    f = cfg.rope_yarn_factor
+    freqs = yarn_freqs(x.shape[-1], cfg.rope_theta, f,
+                       cfg.rope_yarn_original_max, cfg.rope_yarn_beta_fast,
+                       cfg.rope_yarn_beta_slow)
+    mscale = (yarn_mscale(f, cfg.rope_yarn_mscale)
+              / yarn_mscale(f, cfg.rope_yarn_mscale_all_dim))
+    return rope(x, pos, cfg.rope_theta, freqs, mscale)
+
+
+def mla_scale(cfg) -> float:
+    """The MLA softmax scale: 1/sqrt(qk head width), times
+    yarn_mscale(factor, mscale_all_dim)^2 under YaRN."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_dim + cfg.qk_rope_dim)
+    if cfg.rope_yarn_factor and cfg.rope_yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.rope_yarn_factor,
+                             cfg.rope_yarn_mscale_all_dim) ** 2
+    return scale
 
 
 def swiglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
@@ -774,9 +828,9 @@ def mla_apply(cfg, p, x, *, cache=None, pos=None):
     k_rope_raw = dkv[..., lora:]                        # (b, s, rp)
     if cache is None:
         positions = jnp.arange(s)
-        q_nope, q_rope = q[..., :nope], rope(q[..., nope:], positions,
-                                             cfg.rope_theta)
-        k_rope = rope(k_rope_raw, positions, cfg.rope_theta)  # shared head
+        q_nope, q_rope = q[..., :nope], mla_rope(cfg, q[..., nope:],
+                                                 positions)
+        k_rope = mla_rope(cfg, k_rope_raw, positions)       # shared head
         k_nope = (c_kv @ p["w_uk"]).reshape(b, s, hq, nope)
         vv = (c_kv @ p["w_uv"]).reshape(b, s, hq, vd)
         qq = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
@@ -788,16 +842,16 @@ def mla_apply(cfg, p, x, *, cache=None, pos=None):
         kk = constrain(kk, "batch", "heads", None, None)
         vv = constrain(vv, "batch", "heads", None, None)
         o = attention_xla(qq, kk, vv, causal=True,
-                          scale=1.0 / np.sqrt(nope + rp),
+                          scale=mla_scale(cfg),
                           block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
                           block_skip=cfg.attn_block_skip)
         new_cache = None
     else:
         # Absorbed decode on the *compressed* cache — the MLA memory win
         # (cache lora+rope per token instead of hq·(nope+vd)).
-        q_nope, q_rope = q[..., :nope], rope(q[..., nope:], pos[None],
-                                             cfg.rope_theta)
-        k_rope = rope(k_rope_raw, pos[None], cfg.rope_theta)   # (b, 1, rp)
+        q_nope, q_rope = q[..., :nope], mla_rope(cfg, q[..., nope:],
+                                                 pos[None])
+        k_rope = mla_rope(cfg, k_rope_raw, pos[None])         # (b, 1, rp)
         cc, cr = cache["c_kv"], cache["k_rope"]
         cc = lax.dynamic_update_slice_in_dim(cc, c_kv.astype(cc.dtype), pos, 1)
         cr = lax.dynamic_update_slice_in_dim(cr, k_rope.astype(cr.dtype),
@@ -807,7 +861,7 @@ def mla_apply(cfg, p, x, *, cache=None, pos=None):
                            w_uk.astype(F32))              # (b, 1, hq, lora)
         logits = (jnp.einsum("bshl,bSl->bhsS", q_eff, cc.astype(F32))
                   + jnp.einsum("bshr,bSr->bhsS", q_rope.astype(F32),
-                               cr.astype(F32))) / np.sqrt(nope + rp)
+                               cr.astype(F32))) * mla_scale(cfg)
         valid = jnp.arange(cc.shape[1]) <= pos
         logits = jnp.where(valid[None, None, None], logits, -jnp.inf)
         probs = jax.nn.softmax(logits, -1)
@@ -845,13 +899,14 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
     nope, rp, lora, vd = (cfg.qk_nope_dim, cfg.qk_rope_dim,
                           cfg.kv_lora_rank, cfg.v_head_dim)
     h = rmsnorm(x, p["norm"])
-    q = (h @ p["wq"]).reshape(b, s, hq, nope + rp)
-    dkv = h @ p["w_dkv"]
-    c_kv = rmsnorm(dkv[..., :lora], p["kv_norm"])            # (b, s, lora)
+    with jax.named_scope("attn_proj"):
+        q = (h @ p["wq"]).reshape(b, s, hq, nope + rp)
+        dkv = h @ p["w_dkv"]
+        c_kv = rmsnorm(dkv[..., :lora], p["kv_norm"])        # (b, s, lora)
     positions = pos[:, None] + jnp.arange(s)                 # (b, s)
-    k_rope = rope(dkv[..., lora:], positions, cfg.rope_theta)
+    k_rope = mla_rope(cfg, dkv[..., lora:], positions)
     q_nope = q[..., :nope]
-    q_rope = rope(q[..., nope:], positions[:, :, None], cfg.rope_theta)
+    q_rope = mla_rope(cfg, q[..., nope:], positions[:, :, None])
 
     cp, rpool = pages["c_kv"], pages["k_rope"]
     n_pages, page, lora_local = cp.shape
@@ -867,61 +922,68 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
     # append: scatter latent rows (padded chunk tails write nowhere;
     # positions below cache_offset live in shared prefix pages and are
     # read-only — see attention_apply_paged).
-    logical = positions // page
-    keep = logical < n_blocks
-    if last_idx is not None:
-        keep &= jnp.arange(s)[None, :] <= last_idx[:, None]
-    if cache_offset is not None:
-        keep &= positions >= cache_offset[:, None]
-    wp = jnp.take_along_axis(block_tab,
-                             jnp.minimum(logical, n_blocks - 1), axis=1)
-    wp = jnp.where(keep, wp, n_pages)
-    wo = positions % page
-    c_kv_loc = c_kv
-    if sharded_latent:
-        c_kv_loc = lax.dynamic_slice_in_dim(
-            c_kv, part_index() * lora_local, lora_local, axis=-1)
-    new_pages = {
-        "c_kv": cp.at[wp, wo].set(c_kv_loc.astype(cp.dtype), mode="drop"),
-        "k_rope": rpool.at[wp, wo].set(k_rope.astype(rpool.dtype),
-                                       mode="drop"),
-    }
+    with jax.named_scope("kv_write"):
+        logical = positions // page
+        keep = logical < n_blocks
+        if last_idx is not None:
+            keep &= jnp.arange(s)[None, :] <= last_idx[:, None]
+        if cache_offset is not None:
+            keep &= positions >= cache_offset[:, None]
+        wp = jnp.take_along_axis(block_tab,
+                                 jnp.minimum(logical, n_blocks - 1), axis=1)
+        wp = jnp.where(keep, wp, n_pages)
+        wo = positions % page
+        c_kv_loc = c_kv
+        if sharded_latent:
+            c_kv_loc = lax.dynamic_slice_in_dim(
+                c_kv, part_index() * lora_local, lora_local, axis=-1)
+        new_pages = {
+            "c_kv": cp.at[wp, wo].set(c_kv_loc.astype(cp.dtype),
+                                      mode="drop"),
+            "k_rope": rpool.at[wp, wo].set(k_rope.astype(rpool.dtype),
+                                           mode="drop"),
+        }
+
+    # absorbed query: scores against a latent row are q_nope W_uk^T c.
+    with jax.named_scope("attn_proj"):
+        w_uk = p["w_uk"].reshape(lora, hq, nope)
+        q_eff = jnp.einsum("bshn,lhn->bshl", q_nope.astype(F32),
+                           w_uk.astype(F32))             # (b, s, hq, lora)
 
     # read: pre-write pool gather + own-chunk overlay.
-    bt = jnp.minimum(block_tab, n_pages - 1)
-    S = n_blocks * page
-    cc = cp[bt].reshape(b, S, lora_local)
-    if sharded_latent:
-        cc = gather_parts(cc, axis=-1)               # back to full lora
-    cc = cc.astype(F32)
-    cr = rpool[bt].reshape(b, S, rp).astype(F32)
-    if s == 1 or verify:                             # pool-rounded own rows
-        cl = c_kv.astype(cp.dtype).astype(F32)
-        rl = k_rope.astype(rpool.dtype).astype(F32)
-    else:
-        cl, rl = c_kv.astype(F32), k_rope.astype(F32)
-    CC = jnp.concatenate([cc, cl], axis=1)           # (b, S + s, lora)
-    CR = jnp.concatenate([cr, rl], axis=1)
-    kpos = jnp.broadcast_to(jnp.arange(S)[None, :], (b, S))
-    kpos_cat = jnp.concatenate([kpos, positions], axis=1)
-    pre_ok = jnp.concatenate([kpos < pos[:, None], jnp.ones((b, s), bool)],
-                             axis=1)
-    valid = (kpos_cat[:, None, :] <= positions[:, :, None]) \
-        & pre_ok[:, None, :]                         # (b, s, S+s)
-
-    w_uk = p["w_uk"].reshape(lora, hq, nope)
-    q_eff = jnp.einsum("bshn,lhn->bshl", q_nope.astype(F32),
-                       w_uk.astype(F32))             # (b, s, hq, lora)
-    logits = (jnp.einsum("bshl,bSl->bhsS", q_eff, CC)
-              + jnp.einsum("bshr,bSr->bhsS", q_rope.astype(F32), CR)) \
-        / np.sqrt(nope + rp)
-    logits = jnp.where(valid[:, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, -1)
-    ctx = jnp.einsum("bhsS,bSl->bshl", probs, CC)
-    w_uv = p["w_uv"].reshape(lora, hq, vd)
-    o = jnp.einsum("bshl,lhv->bshv", ctx, w_uv.astype(F32)).astype(x.dtype)
-    # TP: query heads column-sharded, wo row-sharded -> per-shard partial.
-    y = psum_parts(o.reshape(b, s, hq * vd) @ p["wo"])
+    with jax.named_scope("attn_read"):
+        bt = jnp.minimum(block_tab, n_pages - 1)
+        S = n_blocks * page
+        cc = cp[bt].reshape(b, S, lora_local)
+        if sharded_latent:
+            cc = gather_parts(cc, axis=-1)               # back to full lora
+        cc = cc.astype(F32)
+        cr = rpool[bt].reshape(b, S, rp).astype(F32)
+        if s == 1 or verify:                             # pool-rounded rows
+            cl = c_kv.astype(cp.dtype).astype(F32)
+            rl = k_rope.astype(rpool.dtype).astype(F32)
+        else:
+            cl, rl = c_kv.astype(F32), k_rope.astype(F32)
+        CC = jnp.concatenate([cc, cl], axis=1)           # (b, S + s, lora)
+        CR = jnp.concatenate([cr, rl], axis=1)
+        kpos = jnp.broadcast_to(jnp.arange(S)[None, :], (b, S))
+        kpos_cat = jnp.concatenate([kpos, positions], axis=1)
+        pre_ok = jnp.concatenate([kpos < pos[:, None],
+                                  jnp.ones((b, s), bool)], axis=1)
+        valid = (kpos_cat[:, None, :] <= positions[:, :, None]) \
+            & pre_ok[:, None, :]                         # (b, s, S+s)
+        logits = (jnp.einsum("bshl,bSl->bhsS", q_eff, CC)
+                  + jnp.einsum("bshr,bSr->bhsS", q_rope.astype(F32), CR)) \
+            * mla_scale(cfg)
+        logits = jnp.where(valid[:, None], logits, -jnp.inf)
+        probs = jax.nn.softmax(logits, -1)
+        ctx = jnp.einsum("bhsS,bSl->bshl", probs, CC)
+    with jax.named_scope("attn_proj"):
+        w_uv = p["w_uv"].reshape(lora, hq, vd)
+        o = jnp.einsum("bshl,lhv->bshv", ctx,
+                       w_uv.astype(F32)).astype(x.dtype)
+        # TP: query heads column-sharded, wo row-sharded -> partial sums.
+        y = psum_parts(o.reshape(b, s, hq * vd) @ p["wo"])
     y = constrain(y, "batch", None, "embed")
     return x + y, new_pages
 
@@ -1001,15 +1063,34 @@ def moe_decls(cfg, stack: Tuple[int, ...] = ()) -> Dict[str, Decl]:
     return decls
 
 
+MOE_BLOCK = 256        # tokens a dropless expert pass holds at once
+
+
+def _moe_route(cfg, p, x2):
+    """Each token's top-k experts and their gates (float32), (T, k).
+
+    ``norm_topk_prob`` (the published key): a softmax over the k chosen
+    logits, i.e. the top-k renormalised to sum to one; else (deepseek-v2)
+    the top k of a softmax over every expert, kept as they are, with the
+    router's logits taken in float32."""
+    if cfg.norm_topk_prob:
+        logits = (x2 @ p["router"]).astype(F32)          # (T, E)
+        gates, idx = lax.top_k(logits, cfg.top_k)
+        return idx, jax.nn.softmax(gates, axis=-1)
+    logits = jnp.matmul(x2.astype(F32), p["router"].astype(F32),
+                        precision=lax.Precision.HIGHEST)
+    gates, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    return idx, gates
+
+
 def _moe_dispatch_combine(cfg, p, x2, dtype):
     """Capacity-bounded scatter dispatch + expert SwiGLU + gather combine
     for one token group.  Returns the combined output (T, d)."""
     T, d = x2.shape
     E, k, f = cfg.n_experts, cfg.top_k, cfg.moe_d_ff
 
-    logits = (x2 @ p["router"]).astype(F32)              # (T, E)
-    gates, idx = lax.top_k(logits, k)                    # (T, k)
-    gates = jax.nn.softmax(gates, axis=-1).astype(dtype)
+    idx, gates = _moe_route(cfg, p, x2)                  # (T, k)
+    gates = gates.astype(dtype)
 
     cap = int(np.ceil(T * k / E * cfg.capacity_factor))
     cap = datapack.round_up(max(cap, 8), 8)
@@ -1034,8 +1115,49 @@ def _moe_combine(T, k, d, out_e, meta, dtype):
     return (y_rep.reshape(T, k, d) * gates[..., None]).sum(1).astype(dtype)
 
 
-def moe_apply(cfg, p, x):
-    """Top-k MoE with capacity-bounded scatter dispatch (EP over 'model').
+def _moe_dropless(cfg, p, x2):
+    """Every routed assignment kept: each expert's capacity is the
+    call's tokens, so every expert runs over every token and each token
+    gathers its own k results.  A token's output depends on its own row
+    alone, never on the other tokens of the call.  The serving steps'
+    tokens (the slots, or one prefill chunk) fit one block, and at decode
+    every expert's weights are read once whether it is hit or not;
+    longer inputs (a whole prompt's prefill) run in blocks of
+    ``MOE_BLOCK`` tokens, so the (E, tokens, ff) intermediate stays
+    bounded."""
+    T, d = x2.shape
+    k = cfg.top_k
+    with jax.named_scope("moe_route"):
+        idx, gates = _moe_route(cfg, p, x2)              # (T, k)
+
+    def block(xb, ib, gb):
+        n = xb.shape[0]
+        hh = swiglu(jnp.einsum("td,edf->etf", xb, p["w_gate"]),
+                    jnp.einsum("td,edf->etf", xb, p["w_up"]))
+        out_e = jnp.einsum("etf,efd->etd", hh, p["w_down"])   # (E, n, d)
+        meta = (ib.reshape(-1), jnp.repeat(jnp.arange(n), k),
+                jnp.ones((n * k,), bool), gb.astype(xb.dtype))
+        return _moe_combine(n, k, d, out_e, meta, xb.dtype)
+
+    with jax.named_scope("moe_experts"):
+        if T <= MOE_BLOCK:
+            return block(x2, idx, gates), idx
+        nb = -(-T // MOE_BLOCK)
+        pad = ((0, nb * MOE_BLOCK - T), (0, 0))
+        y = lax.map(lambda a: block(*a), tuple(
+            jnp.pad(a, pad).reshape(nb, MOE_BLOCK, -1)
+            for a in (x2, idx, gates)))
+    return y.reshape(nb * MOE_BLOCK, d)[:T], idx
+
+
+def moe_apply(cfg, p, x, *, dropless: bool = False):
+    """Top-k MoE.  Returns (x + y, each token's experts (b, s, k)).
+
+    ``dropless`` (every mode but training): no routed assignment is
+    dropped (``_moe_dropless``), so a request's output never depends on
+    what else is in the call.  Otherwise (training) capacity-bounded
+    scatter dispatch (EP over 'model'), which drops what passes
+    ``capacity_factor``.
 
     Baseline: one global dispatch — the (E, C, d) tensor has no
     data-sharded dim, so expert compute replicates across the data axis
@@ -1047,12 +1169,16 @@ def moe_apply(cfg, p, x):
     """
     b, s, d = x.shape
     E, k, f = cfg.n_experts, cfg.top_k, cfg.moe_d_ff
-    h = rmsnorm(x, p["norm"])
+    with jax.named_scope("moe_route"):
+        h = rmsnorm(x, p["norm"])
     x2 = h.reshape(b * s, d)
     T = b * s
     G = cfg.moe_groups
 
-    if G and T % G == 0 and T // G >= 8:
+    if dropless:
+        y, idx = _moe_dropless(cfg, p, x2)
+        y = y.reshape(b, s, d)
+    elif G and T % G == 0 and T // G >= 8:
         xg = x2.reshape(G, T // G, d)
         xg = constrain(xg, "moe_groups", None, "embed")
         disp, meta = jax.vmap(
@@ -1070,6 +1196,7 @@ def moe_apply(cfg, p, x):
         y = jax.vmap(lambda oe, mt: _moe_combine(T // G, k, d, oe, mt,
                                                  x.dtype))(out_e, meta)
         y = y.reshape(b, s, d)
+        idx = meta[0].reshape(b, s, k)
     else:
         disp, meta = _moe_dispatch_combine(cfg, p, x2, x.dtype)
         disp = constrain(disp, "experts", None, "embed")
@@ -1079,9 +1206,12 @@ def moe_apply(cfg, p, x):
         out_e = jnp.einsum("ecf,efd->ecd", hh, p["w_down"])
         out_e = constrain(out_e, "experts", None, "embed")
         y = _moe_combine(T, k, d, out_e, meta, x.dtype).reshape(b, s, d)
+        idx = meta[0]
 
     if cfg.n_shared_experts:
-        y = y + (swiglu(h @ p["sh_gate"], h @ p["sh_up"]) @ p["sh_down"])
+        with jax.named_scope("moe_shared"):
+            y = y + (swiglu(h @ p["sh_gate"], h @ p["sh_up"])
+                     @ p["sh_down"])
     # TP (shard_map serving): experts keep their full set per shard but
     # the ff dim is column-sharded (router replicated -> identical
     # routing), so expert + shared-expert outputs are partial sums over
@@ -1091,4 +1221,4 @@ def moe_apply(cfg, p, x):
     # Load-balance auxiliary loss (Switch-style) is returned via closure-
     # free side channel: recomputed in the train loop if needed; here we
     # keep the block pure.
-    return x + y
+    return x + y, idx.reshape(b, s, k)

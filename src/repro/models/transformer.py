@@ -28,6 +28,10 @@ from . import ssm as S
 from .params import Decl, stack_decls as P_stack_decls
 
 F32 = jnp.float32
+# the serving step programs' modes: their MoE layers hand each token's
+# experts out beside the cache (MoE layers drop nothing in any mode but
+# training).
+SERVING_MODES = ("decode", "chunk", "verify")
 
 
 def _tree_idx(tree, i):
@@ -114,7 +118,7 @@ def _mla_block(cfg, p, x, *, cache, pos, mode, cache_len=None,
     dkv = h @ p["w_dkv"]
     lora = cfg.kv_lora_rank
     c_kv = L.rmsnorm(dkv[..., :lora], p["kv_norm"])
-    k_rope = L.rope(dkv[..., lora:], jnp.arange(s), cfg.rope_theta)
+    k_rope = L.mla_rope(cfg, dkv[..., lora:], jnp.arange(s))
     Sc = cache_len or s
     if Sc > s:
         c_kv = jnp.pad(c_kv, ((0, 0), (0, Sc - s), (0, 0)))
@@ -255,6 +259,16 @@ def gemma3_blocks(cfg):
     return decls, apply, cache_decl, G
 
 
+def _with_routes(nc, idx, mode):
+    """A MoE layer's scan output: in the serving modes its cache and
+    each token's experts (split again by ``_split_routes``)."""
+    return (nc, idx) if mode in SERVING_MODES else nc
+
+
+def _split_routes(ys, mode):
+    return ys if mode in SERVING_MODES else (ys, None)
+
+
 def moe_blocks(cfg):
     """phi3.5-style: every layer attention + MoE."""
     Ln = cfg.n_layers
@@ -269,8 +283,9 @@ def moe_blocks(cfg):
                             theta=cfg.rope_theta, cache=cache, pos=pos,
                             mode=mode, cache_len=cl, last_pos=last_pos,
                             block_tab=block_tab, cache_offset=cache_offset)
-        x = L.moe_apply(cfg, p["moe"], x)
-        return x, nc
+        x, idx = L.moe_apply(cfg, p["moe"], x,
+                             dropless=mode != "train")
+        return x, _with_routes(nc, idx, mode)
 
     def cache_decl(batch, max_seq):
         return _stack_decls(
@@ -305,8 +320,9 @@ def deepseek_blocks(cfg):
                            mode=mode, cache_len=cache_len,
                            block_tab=block_tab, last_pos=last_pos,
                            cache_offset=cache_offset)
-        x = L.moe_apply(cfg, p["moe"], x)
-        return x, nc
+        x, idx = L.moe_apply(cfg, p["moe"], x,
+                             dropless=mode != "train")
+        return x, _with_routes(nc, idx, mode)
 
     def cache_decl(batch, max_seq):
         base = L.mla_cache_decl(cfg, batch, max_seq)
@@ -544,9 +560,14 @@ def forward(cfg, params, batch, mode: str = "train",
             cache: Optional[Any] = None, pos: Optional[jnp.ndarray] = None,
             cache_len: Optional[int] = None,
             last_pos: Optional[jnp.ndarray] = None,
-            cache_offset: Optional[jnp.ndarray] = None):
+            cache_offset: Optional[jnp.ndarray] = None,
+            with_routes: bool = False):
     """train -> logits (b, s, Vp); prefill -> (last logits, cache);
     decode/chunk -> (logits, new cache).
+
+    ``with_routes`` (serving modes): also return each token's experts,
+    (n_moe_layers, b, s, top_k) int32, stacked over the MoE layers (None
+    for a model without experts).
 
     ``last_pos`` (prefill/chunk): (b,) int32 per-sequence index of the
     true last token.  Bucketed serving right-pads prompts to a
@@ -605,6 +626,7 @@ def forward(cfg, params, batch, mode: str = "train",
 
     fam = _family(cfg)
     blocks_p = params["blocks"]
+    routes = None
     cond = batch.get("cond")
     if cond is not None:
         cond = cond.astype(dtype)
@@ -630,6 +652,7 @@ def forward(cfg, params, batch, mode: str = "train",
                                  cr, pos, mode, cache_len,
                                  last_pos=last_pos, block_tab=bt,
                                  cache_offset=cache_offset)
+        c_rest, routes = _split_routes(c_rest, mode)
         new_cache = None if mode == "train" else {"first": c_first,
                                                   "rest": c_rest}
         if bt is not None:
@@ -686,6 +709,8 @@ def forward(cfg, params, batch, mode: str = "train",
                                     mode, cache_len, last_pos=last_pos,
                                     block_tab=block_tab,
                                     cache_offset=cache_offset)
+        if cfg.family == "moe":
+            new_cache, routes = _split_routes(new_cache, mode)
 
     x = L.rmsnorm(x, params["final_norm"])
     if mode in ("prefill", "chunk"):
@@ -709,4 +734,6 @@ def forward(cfg, params, batch, mode: str = "train",
         return logits
     if rewrap_kv:
         new_cache = {"kv": new_cache}    # mirror the paged input structure
+    if with_routes:
+        return logits, new_cache, routes
     return logits, new_cache

@@ -172,8 +172,9 @@ from .resilience import (BatcherFault, FaultPlan, InjectedFault, StallFault,
                          TerminalEvent, class_rank)
 from .telemetry import ENGINE_RID, ServeTelemetry
 from .serve_loop import (make_chunk_prefill_step, make_paged_decode_step,
-                         make_spec_verify_step, named_shardings,
-                         paged_sharding_specs, serving_mesh_for)
+                         make_spec_verify_step, moe_load_init,
+                         named_shardings, paged_sharding_specs,
+                         serving_mesh_for)
 
 _MIN_BUCKET = 8            # smallest prefill bucket (pad-to-power-of-two)
 _MIN_CHUNK = 16            # smallest auto-selected prefill chunk
@@ -499,6 +500,7 @@ class ContinuousBatcher:
         # verify rounds) and page groups.
         self.decode_live_pages = 0
         self.decode_table_pages = 0
+        self._moe_load = None        # expert load, device-resident
         self.preempted_rids: List[int] = []    # observability (tests/benches)
         self._programs: Optional[Dict[str, Dict[str, str]]] = None
         # prefix-cache observability (stats(); all zero when disabled).
@@ -657,9 +659,19 @@ class ContinuousBatcher:
             self._host_remaining = [0] * n_slots
             self._slot_seq = [0] * n_slots
             self._admit_seq = 0
-            self._step = make_paged_decode_step(cfg, max_seq, self.page_size)
-            self._chunk_fn = make_chunk_prefill_step(cfg, self.chunk,
-                                                     max_seq, self.page_size)
+            # expert load (stats()["moe"]): counted on the device by the
+            # decode and chunk programs while telemetry is on.
+            if telemetry is not None and cfg.n_experts:
+                self._moe_load = moe_load_init(cfg)
+                if self.mesh is not None:
+                    self._moe_load = jax.device_put(self._moe_load,
+                                                    self._rep_ns)
+            self._count_kw = ({"count_moe": True}
+                              if self._moe_load is not None else {})
+            self._step = make_paged_decode_step(cfg, max_seq, self.page_size,
+                                                **self._count_kw)
+            self._chunk_fn = make_chunk_prefill_step(
+                cfg, self.chunk, max_seq, self.page_size, **self._count_kw)
             # speculative decode (paged only: rollback needs the block
             # tables).  History/acceptance state exists even at k=0 so
             # the bookkeeping paths stay branch-free.
@@ -832,6 +844,13 @@ class ContinuousBatcher:
                                 / max(self.spec_drafted, 1)),
             "decode_steps_saved": self.spec_accepted,
         }
+        if self._moe_load is not None:
+            ld = jax.tree.map(lambda a: np.asarray(a).tolist(),
+                              self._moe_load)
+            s["moe"] = {"decode_tokens": ld["decode"],
+                        "prefill_tokens": ld["prefill"],
+                        "decode_experts_hit": ld["hit"],
+                        "decode_calls": ld["calls"]}
         if self._telemetry and self._telemetry.profile:
             s["programs"] = _ProgramOps(self)
         if self._tiers is not None:
@@ -858,9 +877,11 @@ class ContinuousBatcher:
                                        0, 0, 0, False, 1, 1, 0)
             state = (self.params, self.pools, self.block_tab, self.last_tok,
                      self.pos, self.remaining, self.active)
+            load = () if self._moe_load is None else (self._moe_load,)
             self._programs = dict(
                 _compiled_ops(fn, args) for fn, args in
-                ((self._step, state), (self._chunk_fn, state + chunk)))
+                ((self._step, state + load),
+                 (self._chunk_fn, state + chunk + load)))
         return self._programs or {}
 
     def _sync_telemetry(self) -> None:
@@ -1320,7 +1341,7 @@ class ContinuousBatcher:
             base = a.start
             C = max(_next_pow2(a.plen - base), _MIN_CHUNK)
             fn = make_chunk_prefill_step(self.cfg, C, self.max_seq,
-                                         self.page_size)
+                                         self.page_size, **self._count_kw)
         tel = self._telemetry
         rid = a.req.rid
         t0 = tel.clock() if tel else 0.0
@@ -1343,9 +1364,10 @@ class ContinuousBatcher:
                            step=self.prefill_chunks)
                   if tel else _NULLCTX):
                 (self.pools, self.last_tok, self.pos, self.remaining,
-                 self.active, tok0) = fn(
-                    self.params, self.pools, self.block_tab, self.last_tok,
-                    self.pos, self.remaining, self.active, *inputs)
+                 self.active, tok0) = self._counted(
+                    fn, self.params, self.pools, self.block_tab,
+                    self.last_tok, self.pos, self.remaining, self.active,
+                    *inputs)
         except Exception as e:
             # a genuine failure inside the jitted prefill may have
             # consumed the donated pools — fatal; the supervisor owns
@@ -1796,7 +1818,14 @@ class ContinuousBatcher:
         self.pos = jnp.zeros((n_slots,), i32)
         self.remaining = jnp.zeros((n_slots,), i32)
         self.active = jnp.zeros((n_slots,), bool)
+        if self._moe_load is not None and any(
+                a.is_deleted() for a in jax.tree.leaves(self._moe_load)):
+            # the failed call consumed the donated counts: start again
+            self._moe_load = moe_load_init(self.cfg)
         if self._rep_ns is not None:
+            if self._moe_load is not None:
+                self._moe_load = jax.device_put(self._moe_load,
+                                                self._rep_ns)
             self.block_tab = jax.device_put(self.block_tab, self._rep_ns)
             self.last_tok = jax.device_put(self.last_tok, self._rep_ns)
             self.pos = jax.device_put(self.pos, self._rep_ns)
@@ -2548,8 +2577,8 @@ class ContinuousBatcher:
                            n_live=n_live) if tel else _NULLCTX):
                 if self.paged:
                     (self.pools, self.last_tok, self.pos, self.remaining,
-                     self.active, out) = self._step(
-                        self.params, self.pools, self.block_tab,
+                     self.active, out) = self._counted(
+                        self._step, self.params, self.pools, self.block_tab,
                         self.last_tok, self.pos, self.remaining,
                         self.active)
                 else:
@@ -2575,6 +2604,15 @@ class ContinuousBatcher:
         self.steps += 1
         self.retired += done
         return done
+
+    def _counted(self, fn, *args):
+        """Call a paged step program, threading the expert load through
+        it where the batcher keeps one (the load is its last argument
+        and output)."""
+        if self._moe_load is None:
+            return fn(*args)
+        *out, self._moe_load = fn(*args, self._moe_load)
+        return tuple(out)
 
     def _count_decode_read(self) -> None:
         """Add a decode call's read to ``stats()["decode_read"]``, from

@@ -287,7 +287,8 @@ def _paged_decode_forward(cfg: ModelConfig, page_size: int):
     layout = get_layout(cfg, page_size)
     fcfg = shard_local_cfg(cfg)
 
-    def forward(params, pools, block_tab, last_tok, pos, active):
+    def forward(params, pools, block_tab, last_tok, pos, active,
+                with_routes=False):
         bt = {}
         for g in layout.groups:
             n_pages = jax.tree.leaves(pools[g.name])[0].shape[
@@ -297,20 +298,51 @@ def _paged_decode_forward(cfg: ModelConfig, page_size: int):
         cache = {"pages": pools, "block_tab": bt}
         return registry.forward(
             fcfg, params, {"tokens": last_tok[:, None]}, mode="decode",
-            cache=cache, pos=pos)
+            cache=cache, pos=pos, with_routes=with_routes)
 
     return forward
 
 
+# --- expert load (stats()["moe"]) ------------------------------------------------------
+#
+# With ``count_moe`` the decode and chunk programs take one more argument
+# and hand it back updated: the expert load, accumulated on the device
+# and read only when the batcher's stats() asks.  Per MoE layer: the
+# live tokens routed to each expert by decode calls and by prefill
+# chunks, the distinct experts hit per decode call summed, and the
+# decode calls.
+
+
+def moe_load_init(cfg: ModelConfig):
+    """The zero expert load of ``cfg``'s MoE layers."""
+    n = cfg.n_layers - (cfg.first_dense_layers if cfg.mla else 0)
+    z = functools.partial(jnp.zeros, dtype=jnp.int32)
+    return {"decode": z((n, cfg.n_experts)),
+            "prefill": z((n, cfg.n_experts)), "hit": z((n,)),
+            "calls": z(())}
+
+
+def _routed(routes, valid, n_experts):
+    """Tokens routed to each expert, (layers, E), over the valid tokens:
+    ``routes`` (layers, b, s, k), ``valid`` (b, s) int32."""
+    oh = jax.nn.one_hot(routes, n_experts, dtype=jnp.int32)
+    return (oh * valid[None, :, :, None, None]).sum((1, 2, 3))
+
+
 @functools.lru_cache(maxsize=32)
-def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
-    """Jitted batched decode over paged KV: advances all slots at once."""
+def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int,
+                           count_moe: bool = False):
+    """Jitted batched decode over paged KV: advances all slots at once.
+    ``count_moe``: the expert load is the last argument and output."""
     forward = _paged_decode_forward(cfg, page_size)
     i32 = jnp.int32
 
-    def step_fn(params, pools, block_tab, last_tok, pos, remaining, active):
-        logits, new_pools = forward(params, pools, block_tab, last_tok, pos,
-                                    active)
+    def step_fn(params, pools, block_tab, last_tok, pos, remaining, active,
+                *load):
+        res = forward(params, pools, block_tab, last_tok, pos, active,
+                      with_routes=count_moe)
+        logits, new_pools = res[:2]
+        live = active.astype(i32)
         with jax.named_scope("logits"):
             nxt = jnp.argmax(logits[:, -1], -1).astype(i32)
         nxt = jnp.where(active, nxt, last_tok)
@@ -319,10 +351,19 @@ def make_paged_decode_step(cfg: ModelConfig, max_seq: int, page_size: int):
         finished = active & ((remaining <= 0) | (pos >= max_seq - 1))
         active = active & ~finished
         out = jnp.stack([nxt, finished.astype(i32)])   # (2, n_slots)
-        return new_pools, nxt, pos, remaining, active, out
+        if not count_moe:
+            return new_pools, nxt, pos, remaining, active, out
+        (ld,) = load
+        n = _routed(res[2], live[:, None], cfg.n_experts)
+        ld = dict(ld, decode=ld["decode"] + n,
+                  hit=ld["hit"] + (n > 0).sum(-1, dtype=i32),
+                  calls=ld["calls"] + 1)
+        return new_pools, nxt, pos, remaining, active, out, ld
 
-    return _shard_wrap(cfg, page_size, step_fn, n_extra_in=5,
-                       n_extra_out=5, donate=(1, 3, 4, 5, 6))
+    n = int(count_moe)
+    return _shard_wrap(cfg, page_size, step_fn, n_extra_in=5 + n,
+                       n_extra_out=5 + n,
+                       donate=(1, 3, 4, 5, 6) + ((7,) if n else ()))
 
 
 @functools.lru_cache(maxsize=32)
@@ -423,13 +464,14 @@ def make_spec_verify_step(cfg: ModelConfig, k: int, max_seq: int,
 
 @functools.lru_cache(maxsize=32)
 def make_chunk_prefill_step(cfg: ModelConfig, chunk: int, max_seq: int,
-                            page_size: int):
+                            page_size: int, count_moe: bool = False):
     """Jitted single-request prefill chunk against the paged cache.
 
     ``cache_offset`` (traced scalar) is the prefix-cache read-only
     boundary: positions below it live in shared prefix pages and are
     never rewritten (0 = plain chunked prefill; one compiled program
-    serves both the cold and the cache-hit path)."""
+    serves both the cold and the cache-hit path).  ``count_moe``: the
+    expert load is the last argument and output."""
     from ..models.cache_layouts import get_layout
     layout = get_layout(cfg, page_size)
     fcfg = shard_local_cfg(cfg)
@@ -437,15 +479,17 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int, max_seq: int,
 
     def chunk_fn(params, pools, block_tab, last_tok, pos, remaining, active,
                  tokens, pos0, last_in_chunk, slot_idx, is_final, plen,
-                 max_new, cache_offset):
+                 max_new, cache_offset, *load):
         n_slots = jax.tree.leaves(block_tab)[0].shape[0]
         bt_row = {g.name: jax.lax.dynamic_index_in_dim(
             block_tab[g.name], slot_idx, 0) for g in layout.groups}
         cache = {"pages": pools, "block_tab": bt_row}
-        logits, new_pools = registry.forward(
+        res = registry.forward(
             fcfg, params, {"tokens": tokens}, mode="chunk", cache=cache,
             pos=pos0, last_pos=last_in_chunk,
-            cache_offset=jnp.broadcast_to(cache_offset, (1,)))
+            cache_offset=jnp.broadcast_to(cache_offset, (1,)),
+            with_routes=count_moe)
+        logits, new_pools = res[:2]
         with jax.named_scope("logits"):
             tok0 = jnp.argmax(logits[0, -1], -1).astype(i32)
         # final chunk installs the slot's decode state; non-final chunks
@@ -456,10 +500,18 @@ def make_chunk_prefill_step(cfg: ModelConfig, chunk: int, max_seq: int,
         remaining = remaining.at[idx].set(max_new - 1, mode="drop")
         alive = (max_new > 1) & (plen < max_seq - 1)
         active = active.at[idx].set(alive, mode="drop")
-        return new_pools, last_tok, pos, remaining, active, tok0
+        if not count_moe:
+            return new_pools, last_tok, pos, remaining, active, tok0
+        (ld,) = load
+        prompt = jnp.arange(chunk)[None, :] <= last_in_chunk[:, None]
+        ld = dict(ld, prefill=ld["prefill"] + _routed(
+            res[2], prompt.astype(i32), cfg.n_experts))
+        return new_pools, last_tok, pos, remaining, active, tok0, ld
 
-    return _shard_wrap(cfg, page_size, chunk_fn, n_extra_in=13,
-                       n_extra_out=5, donate=(1, 3, 4, 5, 6))
+    n = int(count_moe)
+    return _shard_wrap(cfg, page_size, chunk_fn, n_extra_in=13 + n,
+                       n_extra_out=5 + n,
+                       donate=(1, 3, 4, 5, 6) + ((15,) if n else ()))
 
 
 def greedy_generate(cfg: ModelConfig, params, prompt_batch: Dict,

@@ -150,3 +150,57 @@ def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
     _compile(fn, one_chip, ((b, s, h, dh), jnp.float32),
              ((b, s, h), jnp.float32), ((h,), jnp.float32),
              ((b, s, ds), jnp.float32), ((b, s, ds), jnp.float32))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_docqa_programs_fit_the_chip(one_chip, program):
+    """The ``deepseek-v2-lite.doc-qa`` cell's decode and chunk programs
+    at published widths (9 layers, 64 experts of 1,408, latent 512 +
+    rope 64, vocabulary 102,400), bf16 weights, with the cell's
+    ``n_slots`` and ``n_pages``: the TPU compiler accepts them, so they
+    fit the chip's HBM (it refuses a program that does not, as it does
+    the decode program over twice the pages), and the expert layers and
+    the latent read keep their scopes."""
+    from bench import cell
+    from repro.serve.serve_loop import make_chunk_prefill_step, moe_load_init
+    conf = cell.config("deepseek-v2-lite")
+    cfg = cell.model_config(conf, "deepseek-v2-lite")
+    sv = conf["serving"]
+    slots, max_seq, page = sv["n_slots"], sv["max_seq"], sv["kv_page_size"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert sv["n_pages"] >= 5400      # 8 cached documents + 32 slots' own
+    weights = jax.tree.map(
+        lambda a: sds(a.shape, jnp.bfloat16 if jnp.issubdtype(
+            a.dtype, jnp.floating) else a.dtype), registry.abstract(cfg))
+    i32 = sds((slots,), jnp.int32)
+
+    def state(n_pages):
+        pools = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                             params.abstract_params(registry.paged_cache_decls(
+                                 cfg, n_pages, page)))
+        return (weights, pools, {"latent": sds((slots, max_seq // page),
+                                               jnp.int32)},
+                i32, i32, i32, sds((slots,), jnp.bool_))
+    load = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                        jax.eval_shape(lambda: moe_load_init(cfg)))
+    if program == "decode":
+        fn = make_paged_decode_step(cfg, max_seq, page, count_moe=True)
+        args = state(sv["n_pages"]) + (load,)
+        with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+            fn.lower(*state(2 * sv["n_pages"]), load).compile()
+    else:
+        chunk = sv["prefill_chunk"]
+        fn = make_chunk_prefill_step(cfg, chunk, max_seq, page,
+                                     count_moe=True)
+        one = sds((1,), jnp.int32)
+        s = sds((), jnp.int32)
+        args = state(sv["n_pages"]) + (sds((1, chunk), jnp.int32), one, one,
+                                       s, sds((), jnp.bool_), s, s, s, load)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    for scope in ("attn_read", "kv_write", "moe_route", "moe_experts",
+                  "moe_shared"):
+        assert scope in text, scope
