@@ -32,7 +32,7 @@ No collective appears until after the kernel, at the wo projection.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -339,10 +339,12 @@ def flash_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 # the context, not the table width.
 #
 # * The grid is one step per slot.  The pools stay in HBM
-#   (``memory_space=ANY``); a loop inside the step fetches compute
-#   blocks of ``P`` pages with one async copy per page, which covers
-#   every KV head at once because ``(hkv, page, d)`` is contiguous in
-#   the pool.
+#   (``memory_space=ANY``), stacked over layers as the layer loop carries
+#   them; the layer's index is one more scalar prefetch, so no layer's
+#   pool is sliced out of the stack.  A loop inside the step fetches
+#   compute blocks of ``P`` pages with one async copy per page, which
+#   covers every KV head at once because ``(hkv, page, d)`` is
+#   contiguous in the pool.
 # * Copies are double-buffered: block j+1 streams while block j is
 #   scored, and a slot's last block prefetches the next live slot's
 #   first, so the pipeline does not drain between slots.  The loop body
@@ -393,11 +395,13 @@ def _flash_decode_paged_kernel(*refs, scale: float, window: Optional[int],
                                page: int, hkv: int, group: int, sq: int,
                                n_blocks: int, n_pages: int, ppb: int,
                                has_base: bool, quantized: bool):
-    """Refs: [pos, bt, live, order(, page_base)] prefetch (bt and
+    """Refs: [pos, bt, live, order, layer(, page_base)] prefetch (bt and
     page_base flattened to (b·n_blocks,); ``order[0]`` the first live
-    slot, ``order[i + 1]`` the live slot after slot i, b for none),
-    [q, k, v(, ks, vs)] inputs — the pools in HBM, the slot's scales as
-    (1, hkv, n·ppb·page) rows — o output, then scratch: the
+    slot, ``order[i + 1]`` the live slot after slot i, b for none;
+    ``layer[0]`` the layer's index into the stacked pools),
+    [q, k, v(, ks, vs)] inputs — the (n_layers, n_pages, hkv, page, d)
+    pools in HBM, the slot's scales as (1, hkv, n·ppb·page) rows — o
+    output, then scratch: the
     double-buffered page blocks kbuf, vbuf, their DMA semaphores, the
     (m, l, acc) online-softmax state and the SMEM index of the buffer
     that holds the next block.  Optional refs are keyed by the static
@@ -408,10 +412,10 @@ def _flash_decode_paged_kernel(*refs, scale: float, window: Optional[int],
     to query position ``pos + rr // group``, each with its own causal
     band, so one walk over the pages scores every position of the
     span."""
-    n_pre = 5 if has_base else 4
+    n_pre = 6 if has_base else 5
     n_in = 5 if quantized else 3
-    pos_ref, bt_ref, live_ref, order_ref = refs[:4]
-    pb_ref = refs[4] if has_base else None
+    pos_ref, bt_ref, live_ref, order_ref, layer_ref = refs[:5]
+    pb_ref = refs[5] if has_base else None
     q_ref, k_hbm, v_hbm = refs[n_pre:n_pre + 3]
     ks_ref, vs_ref = refs[n_pre + 3:n_pre + n_in] if quantized else (None,) * 2
     (o_ref, kbuf, vbuf, sems, m_scr, l_scr, acc_scr,
@@ -433,14 +437,14 @@ def _flash_decode_paged_kernel(*refs, scale: float, window: Optional[int],
             j = jnp.minimum(j0 + blk * ppb + p, j1 - 1)
             src = jnp.minimum(bt_ref[slot * n_blocks + j], n_pages - 1)
             for pool, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
-                pltpu.make_async_copy(pool.at[src], dst.at[buf, p],
-                                      sems.at[buf]).start()
+                pltpu.make_async_copy(pool.at[layer_ref[0], src],
+                                      dst.at[buf, p], sems.at[buf]).start()
 
     def wait(buf):
         # a wait counts the destination's bytes: any page will do.
         for p in range(ppb):
             for pool, dst in ((k_hbm, kbuf), (v_hbm, vbuf)):
-                pltpu.make_async_copy(pool.at[0], dst.at[buf, p],
+                pltpu.make_async_copy(pool.at[0, 0], dst.at[buf, p],
                                       sems.at[buf]).wait()
 
     @pl.when(i == 0)
@@ -555,15 +559,19 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  k_scale_pages: Optional[jnp.ndarray] = None,
                                  v_scale_pages: Optional[jnp.ndarray] = None,
                                  scale: Optional[float] = None,
-                                 interpret: Optional[bool] = None
+                                 interpret: Optional[bool] = None,
+                                 layer: Tuple[Any, ...] = ()
                                  ) -> jnp.ndarray:
     """Decode attention over a *paged* KV cache.
 
     q: (b, hq, sq, d) — sq == 1 is the plain decode step; sq > 1 is a
     speculative *verify* span whose rows sit at positions
     pos..pos+sq-1, each with its own causal band (one walk over the
-    pages scores all sq positions); k_pages/v_pages: (n_pages, hkv,
-    page, d) shared pools; block_tab: (b, n_blocks) int32 physical page
+    pages scores all sq positions); k_pages/v_pages: (*stack, n_pages,
+    hkv, page, d) shared pools, read at ``layer`` (one index per stack
+    axis, static or traced; ``()`` for one layer's (n_pages, hkv, page,
+    d) pools): the kernel copies pages out of the stack, so no layer's
+    pool is sliced out of it; block_tab: (b, n_blocks) int32 physical page
     per logical page (entries >= n_pages are unallocated: they may
     follow a slot's live positions, past which the walk never reads; an
     unallocated entry inside the walk reads page n_pages - 1, and a slot
@@ -579,15 +587,17 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     never-written slots.  The walk then covers the whole
     (window-bounded) table.  Defaults to the flat ``j * page``.
 
-    ``k_scale_pages``/``v_scale_pages`` (optional, (n_pages, hkv, page,
-    1) bf16): per-position scales for int8 pools — each slot's scales are
-    gathered into one row per KV head and applied in VMEM to the score
+    ``k_scale_pages``/``v_scale_pages`` (optional, (*stack, n_pages,
+    hkv, page, 1) bf16): per-position scales for int8 pools — each
+    slot's scales are gathered into one row per KV head and applied in
+    VMEM to the score
     and probability columns, so no dequantized view is materialized in
     HBM.  Returns (b, hq, sq, d), matching ``ref.paged_attention_ref``
     on every position that lies in an allocated page.
     """
     b, hq, sq, d = q.shape
-    n_pages, hkv, page, _ = k_pages.shape
+    stack = k_pages.shape[:len(layer)]
+    n_pages, hkv, page, _ = k_pages.shape[len(layer):]
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
     if (k_scale_pages is None) != (v_scale_pages is None):
@@ -596,6 +606,18 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     has_base = page_base is not None
     quantized = k_scale_pages is not None
+    # the kernel walks one leading layer axis: the stack axes merge into
+    # it (free: they are the major dims) at the row-major index of layer.
+    lyr = jnp.int32(0)
+    for i, n in zip(layer, stack):
+        lyr = lyr * n + jnp.asarray(i, jnp.int32)
+
+    def one_axis(pool):
+        return None if pool is None else pool.reshape(
+            (-1,) + pool.shape[len(layer):])
+
+    k_pages, v_pages, k_scale_pages, v_scale_pages = map(
+        one_axis, (k_pages, v_pages, k_scale_pages, v_scale_pages))
 
     n_blocks = block_tab.shape[1]
     rows = sq * group
@@ -618,7 +640,8 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     # none — order[0] the first live slot, order[i + 1] the one after i.
     idx = jnp.where(live, jnp.arange(b, dtype=jnp.int32), b)
     order = jax.lax.cummin(jnp.append(idx, jnp.int32(b)), reverse=True)
-    prefetch = [pos_arr, bt.reshape(-1), live.astype(jnp.int32), order]
+    prefetch = [pos_arr, bt.reshape(-1), live.astype(jnp.int32), order,
+                lyr.reshape(1)]
     if has_base:
         prefetch.append(jnp.asarray(page_base, jnp.int32).reshape(-1))
     inputs = [q4, k_pages, v_pages]
@@ -636,7 +659,7 @@ def flash_attention_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         bt = jnp.minimum(block_tab, n_pages - 1)
 
         def scale_rows(sp):
-            g = sp[bt][..., 0].transpose(0, 2, 1, 3)  # (b, hkv, nb, page)
+            g = sp[lyr, bt][..., 0].transpose(0, 2, 1, 3)  # (b,hkv,nb,page)
             g = g.reshape(b, hkv, n_blocks * page)
             return jnp.pad(g, ((0, 0), (0, 0), (0, width - g.shape[-1])))
 

@@ -567,14 +567,21 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
                           ring: bool = False,
                           last_idx: Optional[jnp.ndarray] = None,
                           cache_offset: Optional[jnp.ndarray] = None,
-                          verify: bool = False):
+                          verify: bool = False,
+                          layer: Tuple[Any, ...] = ()):
     """Pre-norm attention against a *paged* KV cache.
 
     x: (b, s, d) — s == 1 is a decode step, s > 1 a prefill chunk whose
-    tokens sit at positions pos..pos+s-1.  ``pages``: this layer's pool
-    leaves — {"k", "v"} of shape (n_pages, hkv, page, hd), plus
-    {"k_scale", "v_scale"} (n_pages, hkv, page, 1) when
+    tokens sit at positions pos..pos+s-1.  ``pages``: the pool leaves —
+    {"k", "v"} of shape (*stack, n_pages, hkv, page, hd), plus
+    {"k_scale", "v_scale"} (*stack, n_pages, hkv, page, 1) when
     ``cfg.kv_cache_dtype == "int8"`` (pages carry per-position scales).
+    ``layer`` indexes the leading ``stack`` axes (one index per axis,
+    static or traced): the layer loop hands in the whole stack of every
+    layer's pools and this layer's index, and the layer writes and reads
+    its own pool inside the stack, so the stack is updated in place with
+    no per-layer slice or write-back (see ``transformer._scan_blocks``).
+    ``layer=()`` takes one layer's pools.
     ``block_tab``: (b, n_blocks) int32, entries >= n_pages meaning
     unallocated (writes through them drop; reads are clamped and
     masked).  ``pos``: (b,) int32 start position per row.  ``last_idx``
@@ -596,12 +603,15 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
     O(window/page) forever; readers reconstruct each entry's logical
     base position from ``pos`` (see ``_ring_page_base``).
 
-    Reads take the *pre-write* pool state concatenated with the current
-    chunk's own K/V, so numerics mirror the dense path's rounding
-    exactly: a prefill chunk (s > 1) attends its own positions at full
-    precision (dense prefill never rounds within-prompt K/V through the
-    cache), while a decode step (s == 1) attends the pool-rounded values
-    (dense decode reads the quantized/bf16 cache).
+    The gather reads take the pool's rows strictly before ``pos``
+    concatenated with the current chunk's own K/V, so numerics mirror the
+    dense path's rounding exactly: a prefill chunk (s > 1) attends its
+    own positions at full precision (dense prefill never rounds
+    within-prompt K/V through the cache), while a decode step (s == 1)
+    attends the pool-rounded values (dense decode reads the quantized/
+    bf16 cache).  On the flat layout the write touches no row before
+    ``pos``, so the gather may follow it; a ring's write can relabel an
+    entry the chunk still reads, so there the gather runs first.
 
     ``verify=True`` (speculative decode): s == k rows behave like k
     *sequential decode steps* scored at once — own K/V is pool-rounded
@@ -622,9 +632,26 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
     v = v.transpose(0, 2, 1, 3)
 
     quantized = cfg.kv_cache_dtype == "int8"
-    pk, pv = pages["k"], pages["v"]
-    n_pages, hkv, page, hd = pk.shape
+    n_pages, hkv, page, hd = pages["k"].shape[len(layer):]
     n_blocks = block_tab.shape[1]
+    kernel = ((s == 1 or verify) and cache_offset is None
+              and paged_decode_kernel(cfg))
+    bt = jnp.minimum(block_tab, n_pages - 1)
+    S = n_blocks * page
+
+    def gather(pool):
+        g = pool[layer + (bt,)]                      # (b, nb, hkv, page, X)
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, S, -1)
+
+    rows = None
+    if ring and not kernel:
+        # the PRE-write ring: gather first, and make the write wait for
+        # the gathered rows (the barrier), so the pools are written in
+        # place rather than copied to keep the old state readable.
+        with jax.named_scope("attn_read"):
+            rows = {n: gather(a) for n, a in pages.items()}
+        rows, pages = lax.optimization_barrier((rows, pages))
+    pk, pv = pages["k"], pages["v"]
 
     # --- append: scatter the chunk's K/V into the pool -------------------------
     with jax.named_scope("kv_write"):
@@ -653,11 +680,12 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
         # one scatter row per (position, head): a whole (hkv, hd) window
         # per position would lead XLA to lay the pools out position-major,
         # and the read kernel's page copies would then pay a relayout of
-        # the layer's whole pool.
-        at = (wp[..., None], jnp.arange(hkv)[None, None, :], wo[..., None])
+        # the whole pool.
+        at = layer + (wp[..., None], jnp.arange(hkv)[None, None, :],
+                      wo[..., None])
 
-        def put(pool, rows):
-            return pool.at[at].set(rows.astype(pool.dtype), mode="drop")
+        def put(pool, vals):
+            return pool.at[at].set(vals.astype(pool.dtype), mode="drop")
 
         new_pages = dict(pages)
         if quantized:
@@ -672,8 +700,7 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
     # --- read ------------------------------------------------------------------
     with jax.named_scope("attn_read"):
         page_base = _ring_page_base(pos, page, n_blocks) if ring else None
-        if (s == 1 or verify) and cache_offset is None \
-                and paged_decode_kernel(cfg):
+        if kernel:
             # write-then-read through the block-table kernel.  The
             # verify span's writes land before the read, so ring bases key
             # off the span END — entries the span wrote hold NEW logical
@@ -688,21 +715,14 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
                 q, new_pages["k"], new_pages["v"], block_tab, pos,
                 window=window, page_base=flash_base,
                 k_scale_pages=new_pages.get("k_scale"),
-                v_scale_pages=new_pages.get("v_scale"))
+                v_scale_pages=new_pages.get("v_scale"), layer=layer)
         else:
-            # gather the PRE-write pool state + overlay the chunk's own K/V.
-            bt = jnp.minimum(block_tab, n_pages - 1)
-            S = n_blocks * page
-
-            def gather(pool):
-                g = pool[bt]                         # (b, nb, hkv, page, X)
-                return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, S, -1)
-
+            # the pool's rows before pos + overlay the chunk's own K/V.
+            if rows is None:
+                rows = {n: gather(a) for n, a in new_pages.items()}
             if quantized:
-                kd = _kv_dequantize(gather(pages["k"]),
-                                    gather(pages["k_scale"]), q.dtype)
-                vd = _kv_dequantize(gather(pages["v"]),
-                                    gather(pages["v_scale"]), q.dtype)
+                kd = _kv_dequantize(rows["k"], rows["k_scale"], q.dtype)
+                vd = _kv_dequantize(rows["v"], rows["v_scale"], q.dtype)
                 if s == 1 or verify:                     # pool-rounded own k/v
                     kl = _kv_dequantize(kq, ks, q.dtype).transpose(
                         0, 2, 1, 3)
@@ -711,8 +731,8 @@ def attention_apply_paged(cfg, p, x, *, window: Optional[int] = None,
                 else:
                     kl, vl = k, v
             else:
-                kd = gather(pages["k"]).astype(q.dtype)
-                vd = gather(pages["v"]).astype(q.dtype)
+                kd = rows["k"].astype(q.dtype)
+                vd = rows["v"].astype(q.dtype)
                 if s == 1 or verify:
                     kl = k.astype(pk.dtype).astype(q.dtype)
                     vl = v.astype(pv.dtype).astype(q.dtype)
@@ -879,20 +899,27 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
                     block_tab: jnp.ndarray, pos: jnp.ndarray,
                     last_idx: Optional[jnp.ndarray] = None,
                     cache_offset: Optional[jnp.ndarray] = None,
-                    verify: bool = False):
+                    verify: bool = False,
+                    layer: Tuple[Any, ...] = ()):
     """MLA absorbed attention against a *paged* compressed latent cache.
 
     The pages hold the latent rows themselves — ``c_kv`` pages of shape
-    (n_pages, page, kv_lora_rank) and ``k_rope`` pages of
-    (n_pages, page, qk_rope_dim); there is no per-head axis at all, so a
-    page costs ``page · (lora + rope)`` bf16 values (the MLA memory win,
-    page-granular).  x: (b, s, d) — s == 1 decode, s > 1 a prefill
-    chunk at positions pos..pos+s-1.  Reads mirror the dense rounding:
-    a chunk attends its own rows at full precision, decode attends the
-    pool-rounded (bf16) rows.  ``verify=True`` (speculative decode):
-    the s == k span behaves like k sequential decode steps — own latent
-    rows are pool-rounded so accepted tokens stay bit-identical to
-    non-speculative greedy decode.  Returns (y, new_pages).
+    (*stack, n_pages, page, kv_lora_rank) and ``k_rope`` pages of
+    (*stack, n_pages, page · qk_rope_dim / W, W), a page's rope rows end
+    to end in rows of W lanes (``mla_paged_cache_decl``); there is no
+    per-head axis at all, so a page costs ``page · (lora + rope)`` bf16
+    values (the MLA memory win, page-granular).  ``layer`` indexes the
+    leading stack axes, as in ``attention_apply_paged``: the layer
+    writes and reads its own pool inside the stack.  x: (b, s, d) —
+    s == 1 decode, s > 1 a prefill chunk at positions pos..pos+s-1.
+    Reads mirror the dense rounding: a chunk attends its own rows at
+    full precision, decode attends the pool-rounded (bf16) rows.  The
+    read gathers after the write, which touches no row before ``pos``,
+    and takes only rows before ``pos`` from the pool.  ``verify=True``
+    (speculative decode): the s == k span behaves like k sequential
+    decode steps — own latent rows are pool-rounded so accepted tokens
+    stay bit-identical to non-speculative greedy decode.  Returns
+    (y, new_pages).
     """
     b, s, d = x.shape
     hq = cfg.n_heads
@@ -909,7 +936,7 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
     q_rope = mla_rope(cfg, q[..., nope:], positions[:, :, None])
 
     cp, rpool = pages["c_kv"], pages["k_rope"]
-    n_pages, page, lora_local = cp.shape
+    n_pages, page, lora_local = cp.shape[len(layer):]
     n_blocks = block_tab.shape[1]
     # under shard_map TP the latent pool is sharded over the lora dim
     # (lora_local = lora / tp) while w_dkv/kv_norm stay replicated: every
@@ -933,15 +960,19 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
                                  jnp.minimum(logical, n_blocks - 1), axis=1)
         wp = jnp.where(keep, wp, n_pages)
         wo = positions % page
+        lanes = rpool.shape[-1]
+        el = wo[..., None] * rp + jnp.arange(rp)            # (b, s, rp)
         c_kv_loc = c_kv
         if sharded_latent:
             c_kv_loc = lax.dynamic_slice_in_dim(
                 c_kv, part_index() * lora_local, lora_local, axis=-1)
         new_pages = {
-            "c_kv": cp.at[wp, wo].set(c_kv_loc.astype(cp.dtype),
-                                      mode="drop"),
-            "k_rope": rpool.at[wp, wo].set(k_rope.astype(rpool.dtype),
-                                           mode="drop"),
+            "c_kv": cp.at[layer + (wp, wo)].set(c_kv_loc.astype(cp.dtype),
+                                                mode="drop"),
+            # element e of a page's rope rows is at (e // W, e % W).
+            "k_rope": rpool.at[layer + (wp[..., None], el // lanes,
+                                        el % lanes)].set(
+                k_rope.astype(rpool.dtype), mode="drop"),
         }
 
     # absorbed query: scores against a latent row are q_nope W_uk^T c.
@@ -950,15 +981,15 @@ def mla_apply_paged(cfg, p, x, *, pages: Dict[str, jnp.ndarray],
         q_eff = jnp.einsum("bshn,lhn->bshl", q_nope.astype(F32),
                            w_uk.astype(F32))             # (b, s, hq, lora)
 
-    # read: pre-write pool gather + own-chunk overlay.
+    # read: the pool's rows before pos + own-chunk overlay.
     with jax.named_scope("attn_read"):
-        bt = jnp.minimum(block_tab, n_pages - 1)
+        bt = layer + (jnp.minimum(block_tab, n_pages - 1),)
         S = n_blocks * page
-        cc = cp[bt].reshape(b, S, lora_local)
+        cc = new_pages["c_kv"][bt].reshape(b, S, lora_local)
         if sharded_latent:
             cc = gather_parts(cc, axis=-1)               # back to full lora
         cc = cc.astype(F32)
-        cr = rpool[bt].reshape(b, S, rp).astype(F32)
+        cr = new_pages["k_rope"][bt].reshape(b, S, rp).astype(F32)
         if s == 1 or verify:                             # pool-rounded rows
             cl = c_kv.astype(cp.dtype).astype(F32)
             rl = k_rope.astype(rpool.dtype).astype(F32)
@@ -992,11 +1023,17 @@ def mla_paged_cache_decl(cfg, n_pages: int, page_size: int
                          ) -> Dict[str, Decl]:
     """One MLA layer's latent page pool: rows of the compressed cache,
     paged over the sequence — (n_pages, page, lora) + the shared rope
-    head (n_pages, page, rope_dim)."""
+    head, a page's rope rows end to end in rows of W = 128 lanes (fewer
+    where a page holds less): (n_pages, page · rope_dim / W, W).  A rope
+    row (64 wide in DeepSeek-V2) fills half a lane row, so a TPU stores
+    a (n_pages, page, rope_dim) pool with the page axis minor, and the
+    layer loop then relayouts the whole pool into and out of every call
+    and writes it by a loop of single rows."""
+    w = math.gcd(page_size * cfg.qk_rope_dim, 128)
     return {
         "c_kv": Decl((n_pages, page_size, cfg.kv_lora_rank),
                      (None, None, "lora"), jnp.bfloat16, init="zeros"),
-        "k_rope": Decl((n_pages, page_size, cfg.qk_rope_dim),
+        "k_rope": Decl((n_pages, page_size * cfg.qk_rope_dim // w, w),
                        (None, None, None), jnp.bfloat16, init="zeros"),
     }
 

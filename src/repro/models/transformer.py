@@ -49,7 +49,8 @@ def _attn_block(cfg, p, x, *, window, theta, cache, pos, mode,
                 last_pos: Optional[jnp.ndarray] = None,
                 block_tab: Optional[jnp.ndarray] = None,
                 ring: bool = False,
-                cache_offset: Optional[jnp.ndarray] = None):
+                cache_offset: Optional[jnp.ndarray] = None,
+                layer: Tuple[Any, ...] = ()):
     if mode in ("decode", "chunk", "verify"):
         if block_tab is not None:
             return L.attention_apply_paged(
@@ -57,7 +58,7 @@ def _attn_block(cfg, p, x, *, window, theta, cache, pos, mode,
                 block_tab=block_tab, pos=pos, ring=ring,
                 last_idx=last_pos if mode == "chunk" else None,
                 cache_offset=cache_offset if mode == "chunk" else None,
-                verify=mode == "verify")
+                verify=mode == "verify", layer=layer)
         if mode in ("chunk", "verify"):
             raise NotImplementedError(f"{mode} mode requires a paged cache")
         return L.attention_apply(cfg, p, x, window=window, theta=theta,
@@ -99,13 +100,13 @@ def _attn_block(cfg, p, x, *, window, theta, cache, pos, mode,
 
 
 def _mla_block(cfg, p, x, *, cache, pos, mode, cache_len=None,
-               block_tab=None, last_pos=None, cache_offset=None):
+               block_tab=None, last_pos=None, cache_offset=None, layer=()):
     if block_tab is not None and mode in ("decode", "chunk", "verify"):
         return L.mla_apply_paged(
             cfg, p, x, pages=cache, block_tab=block_tab, pos=pos,
             last_idx=last_pos if mode == "chunk" else None,
             cache_offset=cache_offset if mode == "chunk" else None,
-            verify=mode == "verify")
+            verify=mode == "verify", layer=layer)
     if mode in ("chunk", "verify"):
         raise NotImplementedError(f"{mode} mode requires a paged cache")
     if mode == "decode":
@@ -168,13 +169,14 @@ def dense_blocks(cfg):
              "mlp": L.mlp_decls(cfg, (Ln,))}
 
     def apply(cfg, p, x, cache, pos, mode, cache_len=None, last_pos=None,
-              block_tab=None, cache_offset=None):
+              block_tab=None, cache_offset=None, layer=()):
         w = cfg.sliding_window
         cl = min(cache_len, w) if (w and cache_len) else cache_len
         x, nc = _attn_block(cfg, p["attn"], x, window=w,
                             theta=cfg.rope_theta, cache=cache, pos=pos,
                             mode=mode, cache_len=cl, last_pos=last_pos,
-                            block_tab=block_tab, cache_offset=cache_offset)
+                            block_tab=block_tab, cache_offset=cache_offset,
+                            layer=layer)
         x = L.mlp_apply(cfg, p["mlp"], x)
         return x, nc
 
@@ -197,32 +199,30 @@ def gemma3_blocks(cfg):
         return None, cfg.rope_theta_global
 
     def apply(cfg, p, x, cache, pos, mode, cache_len=None, last_pos=None,
-              block_tab=None, cache_offset=None):
+              block_tab=None, cache_offset=None, layer=()):
         # Paged serving: ``block_tab`` is the {"local", "global"} table
-        # dict and ``cache`` the per-group page pools for this layer
-        # group.  Local (sliding-window) layers run the ring-of-pages
-        # layout — their page count stays window-bounded — while global
-        # layers use the flat growing layout.
+        # dict and ``cache`` the per-group page pools, which a layer
+        # writes and reads at ``layer`` + its index within its kind.
+        # Local (sliding-window) layers run the ring-of-pages layout —
+        # their page count stays window-bounded — while global layers use
+        # the flat growing layout.
         paged = block_tab is not None and mode in ("decode", "chunk",
                                                    "verify")
+        if paged:
+            cache = dict(cache)
         local_caches, global_caches = [], []
         for i in range(per):
             pi = _tree_idx(p, i)
             window, theta = layer_kind(i)
             if paged:
-                if i < n_local:
-                    ci = _tree_idx(cache["local"], i)
-                    bt, ring = block_tab["local"], True
-                else:
-                    ci = _tree_idx(cache["global"], i - n_local)
-                    bt, ring = block_tab["global"], False
-                x, nc = _attn_block(cfg, pi["attn"], x, window=window,
-                                    theta=theta, cache=ci, pos=pos,
-                                    mode=mode, last_pos=last_pos,
-                                    block_tab=bt, ring=ring,
-                                    cache_offset=cache_offset)
+                kind, j = (("local", i) if i < n_local
+                           else ("global", i - n_local))
+                x, cache[kind] = _attn_block(
+                    cfg, pi["attn"], x, window=window, theta=theta,
+                    cache=cache[kind], pos=pos, mode=mode, last_pos=last_pos,
+                    block_tab=block_tab[kind], ring=kind == "local",
+                    cache_offset=cache_offset, layer=layer + (j,))
                 x = L.mlp_apply(cfg, pi["mlp"], x)
-                (local_caches if i < n_local else global_caches).append(nc)
                 continue
             if cache is not None and mode == "decode":
                 ci = (_tree_idx(cache["local"], i) if i < n_local
@@ -238,7 +238,7 @@ def gemma3_blocks(cfg):
             x = L.mlp_apply(cfg, pi["mlp"], x)
             if nc is not None:
                 (local_caches if i < n_local else global_caches).append(nc)
-        new_cache = None
+        new_cache = cache if paged else None
         if local_caches:
             new_cache = {
                 "local": jax.tree.map(lambda *xs: jnp.stack(xs),
@@ -276,13 +276,14 @@ def moe_blocks(cfg):
              "moe": L.moe_decls(cfg, (Ln,))}
 
     def apply(cfg, p, x, cache, pos, mode, cache_len=None, last_pos=None,
-              block_tab=None, cache_offset=None):
+              block_tab=None, cache_offset=None, layer=()):
         w = cfg.sliding_window
         cl = min(cache_len, w) if (w and cache_len) else cache_len
         x, nc = _attn_block(cfg, p["attn"], x, window=w,
                             theta=cfg.rope_theta, cache=cache, pos=pos,
                             mode=mode, cache_len=cl, last_pos=last_pos,
-                            block_tab=block_tab, cache_offset=cache_offset)
+                            block_tab=block_tab, cache_offset=cache_offset,
+                            layer=layer)
         x, idx = L.moe_apply(cfg, p["moe"], x,
                              dropless=mode != "train")
         return x, _with_routes(nc, idx, mode)
@@ -306,20 +307,22 @@ def deepseek_blocks(cfg):
     }
 
     def apply_first(cfg, p, x, cache, pos, mode, cache_len=None,
-                    last_pos=None, block_tab=None, cache_offset=None):
+                    last_pos=None, block_tab=None, cache_offset=None,
+                    layer=()):
         x, nc = _mla_block(cfg, p["attn"], x, cache=cache, pos=pos,
                            mode=mode, cache_len=cache_len,
                            block_tab=block_tab, last_pos=last_pos,
-                           cache_offset=cache_offset)
+                           cache_offset=cache_offset, layer=layer)
         x = L.mlp_apply(cfg, p["mlp"], x)
         return x, nc
 
     def apply_rest(cfg, p, x, cache, pos, mode, cache_len=None,
-                   last_pos=None, block_tab=None, cache_offset=None):
+                   last_pos=None, block_tab=None, cache_offset=None,
+                   layer=()):
         x, nc = _mla_block(cfg, p["attn"], x, cache=cache, pos=pos,
                            mode=mode, cache_len=cache_len,
                            block_tab=block_tab, last_pos=last_pos,
-                           cache_offset=cache_offset)
+                           cache_offset=cache_offset, layer=layer)
         x, idx = L.moe_apply(cfg, p["moe"], x,
                              dropless=mode != "train")
         return x, _with_routes(nc, idx, mode)
@@ -537,6 +540,28 @@ def _embed_input(cfg, params, batch) -> jnp.ndarray:
 
 def _scan_blocks(cfg, apply, blocks_p, x, cache, pos, mode, cache_len,
                  last_pos=None, block_tab=None, cache_offset=None):
+    n = jax.tree.leaves(blocks_p)[0].shape[0]
+    if block_tab is not None and mode in SERVING_MODES:
+        # The stacked pools ride in the carry, whole, and each layer
+        # writes and reads its own pool inside the stack at its index,
+        # in place.  Scanning over them would slice each layer's pool
+        # out, write it back into a fresh stack and copy the donated
+        # stack once a call: traffic in proportion to the pool.
+        def paged_body(carry, xs):
+            x, pools = carry
+            p_i, l = xs
+            x, nc = apply(cfg, p_i, x, pools, pos, mode, cache_len=cache_len,
+                          last_pos=last_pos, block_tab=block_tab,
+                          cache_offset=cache_offset, layer=(l,))
+            # a MoE layer hands back its experts beside the pools
+            # (``_with_routes``): they stack over the layers.
+            pools, routes = nc if isinstance(nc, tuple) else (nc, None)
+            return (x, pools), routes
+
+        (x, pools), routes = lax.scan(_remat(cfg, paged_body), (x, cache),
+                                      (blocks_p, jnp.arange(n)))
+        return x, pools if routes is None else (pools, routes)
+
     def body(carry, xs):
         x = carry
         p_i, c_i = xs
@@ -546,7 +571,6 @@ def _scan_blocks(cfg, apply, blocks_p, x, cache, pos, mode, cache_len,
         return x, nc
 
     body = _remat(cfg, body)
-    n = jax.tree.leaves(blocks_p)[0].shape[0]
     caches = cache if (cache is not None
                        and mode in ("decode", "chunk", "verify")) \
         else jnp.zeros((n, 1))

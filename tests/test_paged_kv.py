@@ -9,6 +9,7 @@ consistency across spill/restore, dense-equivalent page budget).
 """
 
 import dataclasses
+import functools
 import math
 import threading
 
@@ -632,26 +633,45 @@ _KERNEL_CASES = {
 }
 
 
+# Pools stacked over layers, as the layer loop carries them, read at a
+# layer with other layers' pools on both sides: (case above, stack,
+# layer), one stack axis and two (gemma3's (group, layer)).
+_STACKED_CASES = {
+    "gqa3_layer_1": ("gqa3", (3,), (1,)),
+    "mqa48_blocks_bf16_layer_1_2": ("mqa48_blocks_bf16", (2, 4), (1, 2)),
+}
+
+
+def _stacked(pool, stack, layer, rng):
+    """``pool`` at ``layer`` of a ``stack`` of random pools."""
+    full = jnp.asarray(rng.standard_normal(stack + pool.shape), pool.dtype)
+    return full.at[layer].set(pool)
+
+
 @pytest.mark.parametrize("sq", [1, 3])
 @pytest.mark.parametrize("window", [None, 24])
-@pytest.mark.parametrize("case", sorted(_KERNEL_CASES))
+@pytest.mark.parametrize("case", sorted(_KERNEL_CASES)
+                         + sorted(_STACKED_CASES))
 def test_paged_flash_kernel_matches_ref(case, window, sq):
+    base, stack, layer = _STACKED_CASES.get(case, (case, (), ()))
     (hq, hkv, d, page, n_blocks, n_pages, pos, ungrown,
-     dtype) = _KERNEL_CASES[case]
+     dtype) = _KERNEL_CASES[base]
     ppb = pages_per_block(hkv * page * d * jnp.dtype(dtype).itemsize, page,
                           n_blocks)
-    if case == "blocks_not_dividing":
+    if base == "blocks_not_dividing":
         assert n_blocks % ppb and pos[0] // page >= ppb
-    if case.endswith(("_blocks", "_blocks_bf16")):
+    if base.endswith(("_blocks", "_blocks_bf16")):
         assert max(-(-(p + sq) // page) for p in pos if p is not None) > ppb
     last = n_blocks * page - sq                  # the span stays in the table
     live = [p is not None for p in pos]
     pos = [p if p is None else min(p, last) for p in pos]
-    q, kp, vp, bt, pos = _paged_case(np.random.default_rng(5), pos, hq,
-                                     hkv, d, page, n_blocks, n_pages, sq,
-                                     ungrown, dtype)
-    out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window)
+    rng = np.random.default_rng(5)
+    q, kp, vp, bt, pos = _paged_case(rng, pos, hq, hkv, d, page, n_blocks,
+                                     n_pages, sq, ungrown, dtype)
     gold = ref.paged_attention_ref(q, kp, vp, bt, pos, window=window)
+    kp, vp = (_stacked(p, stack, layer, rng) for p in (kp, vp))
+    out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window,
+                                       layer=layer)
     live = np.asarray(live)
     assert out.dtype == dtype
     err = jnp.abs(out.astype(jnp.float32) - gold)[live].max()
@@ -687,13 +707,14 @@ def test_paged_flash_kernel_ring_page_base_matches_ref(window, sq):
     assert float(jnp.abs(out - gold).max()) <= 1e-3
 
 
+@pytest.mark.parametrize("layer", [(), (2,)], ids=["one", "stacked"])
 @pytest.mark.parametrize("qdtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("window", [None, 24])
-def test_paged_flash_kernel_int8_scales_match_ref(window, qdtype):
+def test_paged_flash_kernel_int8_scales_match_ref(window, qdtype, layer):
     """int8 pools with per-position scale pages: the kernel dequantizes
     in VMEM and must match the dense dequantize-then-attend oracle, with
     the pages entering the matmuls in the query's type (bfloat16 on
-    the chip)."""
+    the chip), also for pools and scales read at a layer of a stack."""
     rng = np.random.default_rng(9)
     b, hq, hkv, d = 2, 4, 2, 32
     n_pages, page, n_blocks = 6, 16, 3
@@ -708,12 +729,17 @@ def test_paged_flash_kernel_int8_scales_match_ref(window, qdtype):
     q = jnp.asarray(rng.standard_normal((b, hq, 1, d)), qdtype)
     bt = jnp.asarray([[0, 2, 4], [5, 1, 99]], jnp.int32)
     pos = jnp.asarray([40, 20], jnp.int32)
-    out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window,
-                                       k_scale_pages=ks, v_scale_pages=vs)
     gold = ref.paged_attention_ref(q, kp, vp, bt, pos, window=window,
                                    k_scale_pages=ks, v_scale_pages=vs)
+    err_scale = vp.astype(jnp.float32) * vs
+    if layer:                     # other layers' pages and scales around
+        kp, vp, ks, vs = (jnp.stack([a[::-1]] * 3).at[layer].set(a)
+                          for a in (kp, vp, ks, vs))
+    out = flash_attention_decode_paged(q, kp, vp, bt, pos, window=window,
+                                       k_scale_pages=ks, v_scale_pages=vs,
+                                       layer=layer)
     err = float(jnp.abs(out.astype(jnp.float32) - gold).max())
-    assert err <= _kernel_tol(out, vp.astype(jnp.float32) * vs)
+    assert err <= _kernel_tol(out, err_scale)
 
 
 @pytest.mark.parametrize("window", [None, 24])
@@ -752,3 +778,174 @@ def test_paged_pool_memory_smaller_than_dense(model):
     paged_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(paged.pools))
     assert paged_bytes * 3 < dense_bytes
+
+
+# --- the layer loop writes the stacked pools in place ---------------------------------
+
+
+def _per_layer_scan(cfg, apply, blocks_p, x, cache, pos, mode, cache_len,
+                    last_pos=None, block_tab=None, cache_offset=None):
+    """The reference layer loop: each layer's pools sliced out of the
+    stack, the layer applied to them alone (``layer=()``), and the
+    result written back into the stack."""
+    routes = []
+    for l in range(jax.tree.leaves(blocks_p)[0].shape[0]):
+        x, nc = apply(cfg, _at(blocks_p, l), x, _at(cache, l), pos, mode,
+                      cache_len=cache_len, last_pos=last_pos,
+                      block_tab=block_tab, cache_offset=cache_offset)
+        if isinstance(nc, tuple):                 # a MoE layer's experts
+            nc, r = nc
+            routes.append(r)
+        cache = jax.tree.map(lambda a, u, l=l: a.at[l].set(u), cache, nc)
+    return x, (cache, jnp.stack(routes)) if routes else cache
+
+
+def _at(tree, idx):
+    return jax.tree.map(lambda a: a[idx], tree)
+
+
+def _random_pools(cfg, page, n_pages, seed):
+    """Every pool leaf filled with random values (int8 pages with random
+    codes, scale pages positive), so a read of the wrong layer or of a
+    stale entry shows."""
+    rng = np.random.default_rng(seed)
+    decls = registry.paged_cache_decls(cfg, n_pages, page)
+    from repro.models.params import abstract_params
+
+    def fill(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        v = rng.standard_normal(a.shape)
+        return jnp.asarray(np.abs(v) * 0.01 if a.shape[-1] == 1 else v,
+                           a.dtype)
+    return jax.tree.map(fill, abstract_params(decls))
+
+
+def _tables(layout, rows, max_seq, n_pages, rng):
+    """Block tables per page group: each row's live entries hold distinct
+    pages, the rest are unallocated (n_pages); a row of None is idle."""
+    out = {}
+    for g in layout.groups:
+        nb = layout.n_blocks(g.name, max_seq)
+        free = list(rng.permutation(n_pages))
+        bt = np.full((len(rows), nb), n_pages, np.int32)
+        for i, last in enumerate(rows):
+            if last is not None:
+                n = min(last // layout.page + 1, nb)
+                bt[i, :n] = [free.pop() for _ in range(n)]
+        out[g.name] = jnp.asarray(bt)
+    return out
+
+
+# (architecture, ModelConfig changes, mode of the first call): flat bf16
+# pools, read by the gather and by the block-table kernel (interpreted);
+# int8 pools with scale pages; gemma3's ring local and flat global
+# groups indexed (group, layer); MLA's latent pages in two stacks; a
+# speculative verify span; a chunk whose first positions sit below the
+# prefix cache's read-only offset.  Each case also runs a prefill chunk.
+_IN_PLACE_CASES = {
+    "flat_bf16": ("minitron-4b", {"n_layers": 3}, "decode"),
+    "flat_kernel": ("minitron-4b", {"n_layers": 3, "decode_flash": True},
+                    "decode"),
+    "int8_scales": ("minitron-4b", {"n_layers": 3,
+                                    "kv_cache_dtype": "int8"}, "decode"),
+    "gemma3_ring": ("gemma3-12b", {}, "decode"),
+    "mla_latent": ("deepseek-v2-lite-16b", {"n_layers": 4}, "decode"),
+    "verify_span": ("minitron-4b", {"n_layers": 3}, "verify"),
+    "chunk_cache_offset": ("minitron-4b", {"n_layers": 3}, "decode"),
+}
+
+
+def _layer_stack(cfg, params, pools, bt):
+    """One attention layer's function, the pool stack it writes, the
+    index of a layer past the first and that layer's parameters."""
+    from repro.models import layers as L
+    blocks = params["blocks"]
+    if cfg.mla:
+        layer = (cfg.n_layers - cfg.first_dense_layers - 1,)
+        fn = functools.partial(L.mla_apply_paged, cfg,
+                               block_tab=bt["latent"])
+        return fn, pools["latent"]["rest"], layer, _at(
+            blocks["rest"]["attn"], layer)
+    if cfg.local_global_pattern:
+        layer = (1, cfg.local_global_pattern - 1)
+        fn = functools.partial(L.attention_apply_paged, cfg,
+                               window=cfg.sliding_window, ring=True,
+                               block_tab=bt["local"])
+        return fn, pools["local"], layer, _at(blocks["attn"], layer)
+    layer = (1,)
+    fn = functools.partial(L.attention_apply_paged, cfg,
+                           window=cfg.sliding_window, block_tab=bt["kv"])
+    return fn, pools["kv"], layer, _at(blocks["attn"], layer)
+
+
+@pytest.mark.parametrize("case", sorted(_IN_PLACE_CASES))
+def test_layer_loop_updates_pools_in_place(case, monkeypatch):
+    """The layer loop carries the stacked pools and each layer writes and
+    reads its own pool inside the stack.  A decode (or verify) call and
+    a chunk give the logits and pools of the per-layer loop bit for bit,
+    and one layer's write changes only its own index of the stack."""
+    from repro.models import transformer
+    from repro.models.cache_layouts import get_layout
+    arch, change, first = _IN_PLACE_CASES[case]
+    page, max_seq, n_pages = 8, 64, 48
+    cfg = dataclasses.replace(smoke_variant(configs.get(arch)),
+                              kv_page_size=page, **change)
+    params = registry.init(cfg, 0)
+    layout = get_layout(cfg, page)
+    rng = np.random.default_rng(3)
+    pools = _random_pools(cfg, page, n_pages, 4)
+    k = 3 if first == "verify" else 1
+    # (rows' last positions, first positions, tokens per row) per call:
+    # a batch of slots (one idle) then one slot's 8-token chunk holding
+    # 6 prompt tokens.
+    calls = [(first, [12 + k, None, 37 + k, 5 + k], [12, 0, 37, 5], k),
+             ("chunk", [29], [22], 8)]
+    for mode, rows, pos, s in calls:
+        bt = _tables(layout, rows, max_seq, n_pages, rng)
+        pos = jnp.asarray(pos, jnp.int32)
+        toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (len(rows), s)),
+                           jnp.int32)
+        kw = {}
+        if mode == "chunk":
+            kw["last_pos"] = jnp.asarray([5], jnp.int32)
+            if case == "chunk_cache_offset":
+                kw["cache_offset"] = pos + 3
+
+        def run(pools, bt=bt, pos=pos, toks=toks, mode=mode, kw=kw):
+            return registry.forward(cfg, params, {"tokens": toks},
+                                    mode=mode, pos=pos,
+                                    cache={"pages": pools, "block_tab": bt},
+                                    **kw)
+
+        logits, new = jax.jit(run)(pools)
+        with monkeypatch.context() as m:
+            m.setattr(transformer, "_scan_blocks", _per_layer_scan)
+            ref_logits, ref_new = jax.jit(run)(pools)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(ref_logits))
+        jax.tree.map(np.testing.assert_array_equal, new, ref_new)
+
+        fn, stack, layer, p = _layer_stack(cfg, params, pools, bt)
+        x = jnp.asarray(rng.standard_normal((len(rows), s, cfg.d_model)),
+                        jnp.float32)
+        lkw = {"verify": True} if mode == "verify" else {}
+        if mode == "chunk":
+            lkw = {"last_idx": kw["last_pos"],
+                   "cache_offset": kw.get("cache_offset")}
+        y, out = jax.jit(lambda st: fn(p, x, pages=st, pos=pos, layer=layer,
+                                       **lkw))(stack)
+        y1, own = jax.jit(lambda st: fn(p, x, pages=st, pos=pos,
+                                        **lkw))(_at(stack, layer))
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y1))
+        jax.tree.map(lambda o, w: np.testing.assert_array_equal(
+            np.asarray(o[layer]), np.asarray(w)), out, own)
+        # every other layer's pool is untouched, and this one was written
+        jax.tree.map(lambda o, a, w: np.testing.assert_array_equal(
+            np.asarray(o.at[layer].set(a[layer])), np.asarray(a)),
+            out, stack, own)
+        assert any(not np.array_equal(np.asarray(o[layer]),
+                                      np.asarray(a[layer]))
+                   for o, a in zip(jax.tree.leaves(out),
+                                   jax.tree.leaves(stack)))
+        pools = new

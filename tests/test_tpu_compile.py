@@ -54,25 +54,30 @@ def _compile(fn, one_chip, *shapes):
 
 
 def _paged(one_chip, hq, hkv, d, kv_dtype, *, window=None, ring=False,
-           scales=False, slots=SLOTS, n_blocks=None, n_pages=None):
+           scales=False, slots=SLOTS, n_blocks=None, n_pages=None,
+           layers=None):
+    """The kernel over one layer's pools, or with ``layers`` over the
+    stacked pools the layer loop carries, read at a traced layer."""
     n_blocks = n_blocks or (ring_blocks(window, PAGE) if ring
                             else MAX_SEQ // PAGE)
     n_pages = n_pages or slots * n_blocks
-    pool = (n_pages, hkv, PAGE, d)
+    stack = (layers,) if layers else ()
+    pool = stack + (n_pages, hkv, PAGE, d)
     shapes = [((slots, hq, 1, d), jnp.bfloat16), (pool, kv_dtype),
               (pool, kv_dtype), ((slots, n_blocks), jnp.int32),
-              ((slots,), jnp.int32)]
+              ((slots,), jnp.int32), ((), jnp.int32)]
     if ring:
         shapes.append(((slots, n_blocks), jnp.int32))
     if scales:
-        shapes += [((n_pages, hkv, PAGE, 1), jnp.bfloat16)] * 2
+        shapes += [(stack + (n_pages, hkv, PAGE, 1), jnp.bfloat16)] * 2
 
-    def fn(q, kp, vp, bt, pos, *rest):
+    def fn(q, kp, vp, bt, pos, layer, *rest):
         base = rest[0] if ring else None
         ks, vs = rest[-2:] if scales else (None, None)
         return flash_attention_decode_paged(
             q, kp, vp, bt, pos, window=window, page_base=base,
-            k_scale_pages=ks, v_scale_pages=vs, interpret=False)
+            k_scale_pages=ks, v_scale_pages=vs, interpret=False,
+            layer=(layer,) if layers else ())
 
     _compile(fn, one_chip, *shapes)
 
@@ -86,16 +91,17 @@ def test_paged_decode_compiles_at_minitron_widths(one_chip, kv):
         _paged(one_chip, 24, 8, 128, jnp.int8, scales=True)
 
 
-@pytest.mark.parametrize("slots, n_blocks, n_pages, hq, hkv", [
-    (16, 256, 1664, 24, 8),       # minitron-4b: max_seq 4096, page 16
-    (64, 128, 8192, 48, 1),       # granite-34b: max_seq 2048, one KV head
+@pytest.mark.parametrize("slots, n_blocks, n_pages, hq, hkv, layers", [
+    (16, 256, 1664, 24, 8, 32),   # minitron-4b: max_seq 4096, page 16
+    (64, 128, 8192, 48, 1, 11),   # granite-34b: max_seq 2048, one KV head
 ], ids=["minitron", "granite"])
 def test_paged_decode_compiles_at_bench_shapes(one_chip, slots, n_blocks,
-                                               n_pages, hq, hkv):
+                                               n_pages, hq, hkv, layers):
     """The benchmark's serving shapes: every slot's block table and
-    position in scalar memory, the pools in HBM."""
+    position in scalar memory, every layer's pools stacked in HBM as the
+    layer loop carries them, read at a traced layer."""
     _paged(one_chip, hq, hkv, 128, jnp.bfloat16, slots=slots,
-           n_blocks=n_blocks, n_pages=n_pages)
+           n_blocks=n_blocks, n_pages=n_pages, layers=layers)
 
 
 def test_paged_decode_step_takes_the_kernel_on_tpu(one_chip, monkeypatch):
@@ -159,8 +165,9 @@ def test_docqa_programs_fit_the_chip(one_chip, program):
     rope 64, vocabulary 102,400), bf16 weights, with the cell's
     ``n_slots`` and ``n_pages``: the TPU compiler accepts them, so they
     fit the chip's HBM (it refuses a program that does not, as it does
-    the decode program over twice the pages), and the expert layers and
-    the latent read keep their scopes."""
+    the decode program over three times the pages, whose pools and
+    weights alone exceed the chip), and the expert layers and the latent
+    read keep their scopes."""
     from bench import cell
     from repro.serve.serve_loop import make_chunk_prefill_step, moe_load_init
     conf = cell.config("deepseek-v2-lite")
@@ -190,7 +197,7 @@ def test_docqa_programs_fit_the_chip(one_chip, program):
         fn = make_paged_decode_step(cfg, max_seq, page, count_moe=True)
         args = state(sv["n_pages"]) + (load,)
         with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
-            fn.lower(*state(2 * sv["n_pages"]), load).compile()
+            fn.lower(*state(3 * sv["n_pages"]), load).compile()
     else:
         chunk = sv["prefill_chunk"]
         fn = make_chunk_prefill_step(cfg, chunk, max_seq, page,
